@@ -33,10 +33,11 @@ module is loaded outside the package.
 
 Size semantics: an op's "size" is the LEADING dimension of its first
 operand — the row axis of every dj_tpu module — parsed from compiled
-HLO text (``sort(s64[512]{0} ...)``). Lowered StableHLO is also
-parsed (op counts exact; sizes best-effort from the trailing
-functional type), but the canonical audit surface is the compiled
-text, which is what both the tests and the runtime auditor use.
+HLO text (``%s = s64[512]{0} sort(%k)`` is sized from ``%k``'s
+definition). Lowered StableHLO is also parsed (op counts exact; sizes
+best-effort from the trailing functional type), but the canonical
+audit surface is the compiled text, which is what both the tests and
+the runtime auditor use.
 """
 
 from __future__ import annotations
@@ -74,10 +75,19 @@ __all__ = [
 OPS = ("sort", "all-to-all", "all-gather", "all-reduce",
        "collective-permute")
 
+# A compiled op line: ``%x = <result> op(<first operand>...``. The
+# installed JAX prints operands untyped (``sort(%a.1, %b.1)``), older
+# ones typed (``sort(s32[16]{0} %a.1``); both are read.
 _COMPILED_RE = re.compile(
-    r"\b(sort|all-to-all|all-gather|all-reduce|collective-permute)"
-    r"(?:-start)?\(\s*(?:[a-z][a-z0-9]*)\[(\d*)"
+    r"=\s*(?P<result>\(?[a-z][a-z0-9]*\[[^\n]*?)\s"
+    r"(?P<op>sort|all-to-all|all-gather|all-reduce|collective-permute)"
+    r"(?:-start)?\(\s*(?:[a-z][a-z0-9]*\[(?P<typed>\d*)[^\]]*\]\S*\s+)?"
+    r"%(?P<operand>[\w.\-]+)"
 )
+# ``%name = s32[16]{0} ...``: the leading dim of every array-typed
+# instruction, so an untyped operand can be sized from its definition.
+_DEF_RE = re.compile(r"%([\w.\-]+) = [a-z][a-z0-9]*\[(\d*)")
+_DIM_RE = re.compile(r"[a-z][a-z0-9]*\[(\d*)")
 _STABLEHLO_RE = re.compile(
     r"\bstablehlo\.(sort|all_to_all|all_gather|all_reduce|"
     r"collective_permute)\b"
@@ -100,10 +110,19 @@ def parse_ops(text: str) -> list[tuple[str, Optional[int]]]:
                  int(dim.group(1)) if dim else None)
             )
         return out
-    return [
-        (m.group(1), int(m.group(2)) if m.group(2) else None)
-        for m in _COMPILED_RE.finditer(text)
-    ]
+    defs = dict(_DEF_RE.findall(text))
+    out = []
+    for m in _COMPILED_RE.finditer(text):
+        # First operand's leading dim: typed in place, else from its
+        # definition, else (an operand defined as a tuple) from the
+        # op's own first result shape.
+        dim = m.group("typed")
+        if dim is None:
+            dim = defs.get(m.group("operand"))
+        if dim is None:
+            dim = _DIM_RE.match(m.group("result").lstrip("(")).group(1)
+        out.append((m.group("op"), int(dim) if dim else None))
+    return out
 
 
 def op_sizes(text: str, op: str) -> list[int]:

@@ -31,7 +31,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..core import dtypes as dt
-from ..utils import compat
 from ..core.search import count_lt_arange
 from ..core.table import Column, Table
 from ..parallel.communicator import XlaCommunicator
@@ -247,7 +246,7 @@ def generate_tables_distributed(
         return build, counts_b, probe, counts_p
 
     run = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             body, mesh=mesh, in_specs=(P(),), out_specs=(spec, spec, spec, spec)
         )
     )

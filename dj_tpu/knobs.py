@@ -372,11 +372,13 @@ KNOBS: tuple[Knob, ...] = (
     _k("DJ_SLO_BURN_RATE", 0.1, "float",
        "burn-rate alert threshold: deadline-miss/shed share of a "
        "window at which slo_alert fires", "ambient"),
-    _k("DJ_PEAK_HBM_GBPS", 819.0, "float",
-       "HBM roofline peak for phase attribution (v5e default)",
+    _k("DJ_PEAK_HBM_GBPS", None, "float",
+       "HBM roofline peak override (unset: the device kind's published "
+       "peak, obs.roofline.DEVICE_PEAKS_GBPS)",
        "ambient", aliases=("DJ_HBM_PEAK_GBPS",)),
-    _k("DJ_PEAK_WIRE_GBPS", 100.0, "float",
-       "per-link wire roofline peak", "ambient"),
+    _k("DJ_PEAK_WIRE_GBPS", None, "float",
+       "per-chip interconnect roofline peak override (unset: the device "
+       "kind's published peak)", "ambient"),
     # --- bootstrap / backend infrastructure -----------------------------
     _k("DJ_COORDINATOR_ADDRESS", None, "str",
        "multi-process coordinator address (alias of "
@@ -390,8 +392,6 @@ KNOBS: tuple[Knob, ...] = (
        "distributed-init retry attempts", "ambient"),
     _k("DJ_INIT_BACKOFF_S", 1.0, "float",
        "distributed-init backoff base seconds", "ambient"),
-    _k("DJ_COMPILE_CACHE", None, "path",
-       "persistent XLA compilation cache directory", "ambient"),
     _k("DJ_TPU_NO_X64", None, "bool",
        "skip the import-time jax_enable_x64 flip", "ambient"),
 )

@@ -434,7 +434,7 @@ def _timed_first_call(fn, builder_name: str):
     penalty a warm call does not pay", and compare against
     ``dj_query_dispatch_seconds``' warm band rather than treating it
     as pure-compile. With jax's persistent compilation cache wired
-    (``DJ_COMPILE_CACHE`` — bootstrap.setup_compile_cache) the
+    (bootstrap.setup_compile_cache) the
     cold-vs-warm delta collapses toward trace+execute on a disk hit."""
     state = {"cold": True}
 

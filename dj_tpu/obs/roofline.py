@@ -13,11 +13,13 @@ ran at what fraction of peak":
   recorded event), observe ``dj_phase_seconds{phase}``, and — when the
   caller supplies modeled bytes — ``dj_roofline_frac{phase,kind}``
   with ``roofline_frac = model_bytes / (seconds x peak_GBps x 1e9)``.
-  Peaks come from ``DJ_PEAK_HBM_GBPS`` (the knob registry resolves
-  the bench's legacy ``DJ_HBM_PEAK_GBPS`` spelling with a
-  once-per-process DeprecationWarning; default 819 — v5e HBM) and
-  ``DJ_PEAK_WIRE_GBPS`` (default 100 — per-link ICI order; calibrate
-  per deployment).
+  Peaks are the published figures of the device's kind
+  (:data:`DEVICE_PEAKS_GBPS`); ``DJ_PEAK_HBM_GBPS`` /
+  ``DJ_PEAK_WIRE_GBPS`` override them when set (the knob registry
+  resolves the bench's legacy ``DJ_HBM_PEAK_GBPS`` spelling with a
+  once-per-process DeprecationWarning). A kind not in the table has
+  no peak, and its phases carry no fraction rather than one computed
+  against a guess.
 - The phase inventory the pipeline emits: ``probe`` (host key-range
   probe), ``build`` (module build; trace+compile on a cache miss),
   ``dispatch`` (the jit invocation — async on a warm module; its
@@ -45,7 +47,6 @@ phase either way.
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
 import time
 from typing import Optional
@@ -82,20 +83,38 @@ _timer = PhaseTimer()
 _lock = threading.Lock()
 
 
-def hbm_peak_gbps() -> float:
-    """``DJ_PEAK_HBM_GBPS``, default 819.0 — v5e HBM peak. The knob
-    registry (dj_tpu.knobs) resolves the bench's legacy
-    ``DJ_HBM_PEAK_GBPS`` alias, warning once per process."""
-    return knobs.read_float("DJ_PEAK_HBM_GBPS")
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" system architecture:
+# 16 GB of HBM2 at 819 GB/s, and 1,600 Gbit/s (200 GB/s) of inter-chip
+# interconnect per chip.
+DEVICE_PEAKS_GBPS = {
+    "TPU v5 lite": {"hbm": 819.0, "wire": 200.0},
+}
 
 
-def wire_peak_gbps() -> float:
-    """``DJ_PEAK_WIRE_GBPS``, default 100.0 (per-link ICI order of
-    magnitude; the CPU-mesh trend only needs a consistent denominator
-    — calibrate per deployment). Read through the knob registry like
-    its HBM sibling, so default and malformed-value semantics have
-    one owner."""
-    return knobs.read_float("DJ_PEAK_WIRE_GBPS")
+def _peak_gbps(knob: str, kind: str) -> Optional[float]:
+    raw = knobs.read(knob)
+    if raw is not None:
+        try:
+            return float(raw)
+        except ValueError:
+            pass  # malformed: the published peak, as if unset
+    import jax
+
+    peaks = DEVICE_PEAKS_GBPS.get(jax.devices()[0].device_kind)
+    return None if peaks is None else peaks[kind]
+
+
+def hbm_peak_gbps() -> Optional[float]:
+    """``DJ_PEAK_HBM_GBPS`` when set, else the device kind's published
+    HBM peak; None for a kind without one."""
+    return _peak_gbps("DJ_PEAK_HBM_GBPS", "hbm")
+
+
+def wire_peak_gbps() -> Optional[float]:
+    """``DJ_PEAK_WIRE_GBPS`` when set, else the device kind's published
+    per-chip interconnect peak; None for a kind without one."""
+    return _peak_gbps("DJ_PEAK_WIRE_GBPS", "wire")
 
 
 def observe_phase(
@@ -121,10 +140,11 @@ def observe_phase(
     frac = None
     if model_bytes and seconds > 0:
         peak = hbm_peak_gbps() if kind == "hbm" else wire_peak_gbps()
-        # peak <= 0 (an operator "disabling" a roofline with =0) means
-        # no fraction, not a ZeroDivisionError out of a phase() finally
-        # — observation must never fail the query it observes.
-        if peak > 0:
+        # No published peak, or peak <= 0 (an operator "disabling" a
+        # roofline with =0), means no fraction, not a ZeroDivisionError
+        # out of a phase() finally — observation must never fail the
+        # query it observes.
+        if peak is not None and peak > 0:
             frac = float(model_bytes) / (seconds * peak * 1e9)
     _metrics.observe("dj_phase_seconds", seconds, phase=name)
     if frac is not None:

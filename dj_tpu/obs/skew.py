@@ -8,7 +8,7 @@ per-process while the engine is SPMD. Three answers live here:
 
 **Per-link wire matrix** (``dj_wire_bytes_total{src,dst,width}``).
 The pad-to-bucket shuffle is LINK-UNIFORM by construction: every
-``[n, B, k]`` bucketed buffer sends exactly bucket capacity to each
+``[n, k, B]`` bucketed buffer sends exactly bucket capacity to each
 peer regardless of how many rows are valid, so each epoch's
 trace-time static bytes divide evenly over the n destinations. The
 matrix is fed from the same per-signature epoch memo the

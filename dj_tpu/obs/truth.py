@@ -111,14 +111,12 @@ def current_forecast() -> Optional[float]:
 
 
 def _cost_dict(compiled) -> Optional[dict]:
-    """``Compiled.cost_analysis()`` normalized: older jax returns a
-    one-element list of dicts, newer a dict; anything else is None."""
+    """``Compiled.cost_analysis()`` as a dict, or None where the backend
+    has none."""
     try:
         ca = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 - None-tolerant by contract
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     return ca if isinstance(ca, dict) else None
 
 

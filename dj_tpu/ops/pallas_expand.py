@@ -79,7 +79,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils import compat
 
 # Tile geometry. T_J output slots per program; SPAN window entries
 # resident per program; BLK entries per compare block; LANE j's per
@@ -393,7 +392,7 @@ def _run_pallas(
     # Inside shard_map (the production pipeline) avals carry a `vma`
     # (varying-over-mesh-axes) set and check_vma=True requires outputs
     # to declare theirs; inherit the inputs'.
-    vma = compat.varying_mesh_axes(arrays_padded[0])
+    vma = jax.typeof(arrays_padded[0]).vma
     out_block = pl.BlockSpec((t_j,), lambda p, starts: (p,))
     if mode == "ranks":
         # Mosaic-lowerable kernel: aligned window + 2-D accumulator
@@ -418,7 +417,7 @@ def _run_pallas(
         else out_block,
         scratch_shapes=scratch,
     )
-    out_shape = compat.shape_dtype_struct((n_pad,), jnp.int32, vma=vma)
+    out_shape = jax.ShapeDtypeStruct((n_pad,), jnp.int32, vma=vma)
     return pl.pallas_call(
         kernel,
         out_shape=tuple([out_shape] * n_out_arrays)
@@ -818,7 +817,7 @@ def _run_vexpand(
         _pad32(valp, span + blk, 0),
     ) + tuple(_pad32(v, span + blk, 0) for v in vals)
     n_val = 1 + len(vals)
-    vma = compat.varying_mesh_axes(csum32)
+    vma = jax.typeof(csum32).vma
     out_block = pl.BlockSpec((t_j,), lambda p, starts: (p,))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -828,7 +827,7 @@ def _run_vexpand(
         scratch_shapes=[pltpu.VMEM((span + blk,), jnp.int32)] * (2 + n_val)
         + [pltpu.SemaphoreType.DMA] * (2 + n_val),
     )
-    out_shape = compat.shape_dtype_struct((n_pad,), jnp.int32, vma=vma)
+    out_shape = jax.ShapeDtypeStruct((n_pad,), jnp.int32, vma=vma)
     outs = pl.pallas_call(
         _make_vexpand_kernel(t_j, span, blk, lane, n_val, precision),
         out_shape=tuple([out_shape] * n_val),
@@ -1334,7 +1333,7 @@ def _expand_vfull_jit(
             _pad32(key_hi, pad, 0),
         )
         n_pay = n_pay2 // 2
-        vma = compat.varying_mesh_axes(csum32)
+        vma = jax.typeof(csum32).vma
         out_block = pl.BlockSpec((t_j,), lambda p, starts: (p,))
         n_outs = 2 + 2 * n_pay2  # lpay*, klo, khi, rpay*
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1345,7 +1344,7 @@ def _expand_vfull_jit(
             scratch_shapes=[pltpu.VMEM((pad,), jnp.int32)] * len(arrays)
             + [pltpu.SemaphoreType.DMA] * len(arrays),
         )
-        out_shape = compat.shape_dtype_struct((n_pad,), jnp.int32, vma=vma)
+        out_shape = jax.ShapeDtypeStruct((n_pad,), jnp.int32, vma=vma)
         outs = pl.pallas_call(
             _make_vfull_kernel(
                 t_j, span, blk, lane, n_pay, margin_blocks, precision

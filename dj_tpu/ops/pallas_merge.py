@@ -20,29 +20,29 @@ operand); this kernel does it in ONE HBM read + ONE write:
   and no fallback branch — the kernel is exact on every input, and
   the traced module carries zero S-sized sorts (the hlo_count guard in
   tests/test_prepared.py pins this).
-- Each program DMAs its two windows (≤ T words each, as u32 hi/lo
-  planes — Mosaic has no 64-bit types), masks the unconsumed tails to
-  the all-ones sentinel, and bitonic-MERGES them on the VPU:
-  [a ascending | b reversed] is a bitonic sequence of 2T, so
-  log2(2T) compare-exchange stages (roll + two-plane lexicographic
-  u32 compares, no gathers) sort it; the first T words are the tile's
-  merged output. Sentinels sort to the tail of the 2T buffer and are
-  overwritten by the next tile (or sliced off at [:S]) — and genuine
-  all-ones padding words in the operands are value-identical to the
-  fill sentinel, so they merge exactly like the monolithic sort's
-  padding tail.
+- Each program reads the two ALIGNED tile blocks holding its a window
+  and the two holding its b window (u32 hi/lo planes — Mosaic has no
+  64-bit types; b from a reversed copy, because Mosaic cannot lower a
+  reversal), masks the words outside the windows to the extremes so
+  that [a ascending | b descending] is a bitonic 4T sequence, and
+  bitonic-MERGES it on the VPU: log2(4T) compare-exchange stages (roll
+  + two-plane lexicographic u32 compares, no gathers). The tile's
+  merged words then sit after the masked-out MIN words; a dynamic
+  sublane load plus a dynamic lane roll moves them to the front of the
+  output tile. Genuine all-ones padding words in the operands are
+  value-identical to the MAX mask (and genuine zeros to the MIN
+  mask), so they merge exactly like the monolithic sort's words.
 
-Cost model: HBM traffic = 8 B/word read + 8 B/word written (vs the
-XLA tier's ~log2(S) read+write passes); VPU work = log2(2T) full-tile
-stages per tile — the same compute-vs-bandwidth trade the round-5
-Batcher-network sort lost at FULL sort depth, here at merge depth 1.
+Cost model: HBM traffic = 16 B/word read (each block is read by up to
+two tiles) + 8 B/word written (vs the XLA tier's ~log2(S) read+write
+passes); VPU work = log2(4T) stages over 4T words per tile — the same
+compute-vs-bandwidth trade the round-5 Batcher-network sort lost at
+FULL sort depth, here at merge depth 1.
 Whether that wins on the chip is an open A/B
 (scripts/hw/merge_crossover.py, gate: speedup > 1.02 AND bit-exact);
 this tier is ARMED for that study, not promoted from CPU — CPU proves
-bit-exactness only (tests/test_prepared.py). Compiled-Mosaic lowering
-status is part of the A/B (the kernel uses unaligned dynamic DMA
-starts like the interpret-only expand modes; merge_crossover.py
-records a lowering failure as an honest error case).
+bit-exactness only (tests/test_prepared.py), and
+tests/test_chip_compile.py proves it compiles for v5e.
 """
 
 from __future__ import annotations
@@ -55,7 +55,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..resilience import faults
-from ..utils import compat
 
 LANE = 128
 TILE_M = 32_768  # merged output words per program (power of two)
@@ -149,50 +148,74 @@ def _make_merge_kernel(S: int, tile: int):
     i32 = jnp.int32
 
     def kernel(
-        ia_ref,  # SMEM prefetch: int32[P+1] diagonal splits
-        a_hi_hbm, a_lo_hbm, b_hi_hbm, b_lo_hbm,  # sentinel-padded planes
+        ia_ref, e_ref,  # SMEM prefetch: int32[P+1] splits, int32[P] b starts
+        a_hi0, a_hi1, a_lo0, a_lo1,  # a's two aligned blocks per plane
+        b_hi0, b_hi1, b_lo0, b_lo1,  # reversed b's two aligned blocks
         out_hi_ref, out_lo_ref,  # (tile,) u32 blocked outputs
-        a_hi_buf, a_lo_buf, b_hi_buf, b_lo_buf,  # (tile,) u32 VMEM
-        sems,
+        buf_hi, buf_lo,  # (4*rows + 8, LANE) u32 VMEM: the sorted 4T
     ):
         p = pl.program_id(0)
         astart = ia_ref[p]
         acnt = ia_ref[p + 1] - astart
         k0 = jnp.minimum(p * i32(tile), i32(S))
         k1 = jnp.minimum((p + 1) * i32(tile), i32(S))
-        bstart = k0 - astart
         bcnt = (k1 - k0) - acnt
+        # Offsets of the windows inside their two-block buffers.
+        da = astart & i32(tile - 1)
+        db = e_ref[p] & i32(tile - 1)
 
-        copies = []
-        for src, buf, j in (
-            (a_hi_hbm, a_hi_buf, 0),
-            (a_lo_hbm, a_lo_buf, 1),
-            (b_hi_hbm, b_hi_buf, 2),
-            (b_lo_hbm, b_lo_buf, 3),
-        ):
-            start = astart if j < 2 else bstart
-            d = pltpu.make_async_copy(
-                src.at[pl.ds(start, tile)], buf, sems.at[j]
+        def two_blocks(r0, r1):
+            return jnp.concatenate(
+                [r0[:].reshape(rows, LANE), r1[:].reshape(rows, LANE)],
+                axis=0,
             )
-            d.start()
-            copies.append(d)
-        for d in copies:
-            d.wait()
 
-        idx = _iota2(rows)
+        idx = _iota2(2 * rows)
+        ZERO = jnp.uint32(0)
         ONES = jnp.uint32(_ONES32)
-        a_hi = jnp.where(idx < acnt, a_hi_buf[:].reshape(rows, LANE), ONES)
-        a_lo = jnp.where(idx < acnt, a_lo_buf[:].reshape(rows, LANE), ONES)
-        b_hi = jnp.where(idx < bcnt, b_hi_buf[:].reshape(rows, LANE), ONES)
-        b_lo = jnp.where(idx < bcnt, b_lo_buf[:].reshape(rows, LANE), ONES)
-        # [a ascending | b descending] is bitonic; its sorted first
-        # `tile` words are the tile's merged output (real words <
-        # sentinel, and the windows hold exactly k1 - k0 real words).
-        x_hi = jnp.concatenate([a_hi, b_hi[::-1, ::-1]], axis=0)
-        x_lo = jnp.concatenate([a_lo, b_lo[::-1, ::-1]], axis=0)
-        x_hi, x_lo = _bitonic_merge_planes(x_hi, x_lo, tile)
-        out_hi_ref[:] = x_hi[:rows].reshape(tile)
-        out_lo_ref[:] = x_lo[:rows].reshape(tile)
+        # a ascending: [MIN x da | window | MAX...]; b (from reversed
+        # HBM planes) descending: [MAX x db | window | MIN...]. Words
+        # outside the windows belong to other tiles; masking them to
+        # the extremes keeps each buffer monotone, so the 4T
+        # concatenation is bitonic (Mosaic cannot lower a reversal, so
+        # b arrives reversed).
+        a_pre, a_win = idx < da, idx < da + acnt
+        b_pre, b_win = idx < db, idx < db + bcnt
+
+        def a_side(r0, r1):
+            x = two_blocks(r0, r1)
+            return jnp.where(a_pre, ZERO, jnp.where(a_win, x, ONES))
+
+        def b_side(r0, r1):
+            x = two_blocks(r0, r1)
+            return jnp.where(b_pre, ONES, jnp.where(b_win, x, ZERO))
+
+        x_hi = jnp.concatenate(
+            [a_side(a_hi0, a_hi1), b_side(b_hi0, b_hi1)], axis=0
+        )
+        x_lo = jnp.concatenate(
+            [a_side(a_lo0, a_lo1), b_side(b_lo0, b_lo1)], axis=0
+        )
+        x_hi, x_lo = _bitonic_merge_planes(x_hi, x_lo, 2 * tile)
+        # The tile's merged words follow the nmin masked-out MIN words
+        # (a's prefix, b's suffix): shift them to the front — a dynamic
+        # sublane load, a dynamic lane roll, and the next row's head
+        # for the lanes that wrapped.
+        nmin = da + (i32(2 * tile) - db - bcnt)
+        q = nmin >> i32(LANE.bit_length() - 1)
+        r = nmin & i32(LANE - 1)
+        tail = jnp.full((8, LANE), ONES)
+        out = []
+        for x, buf in ((x_hi, buf_hi), (x_lo, buf_lo)):
+            buf[pl.ds(0, 4 * rows), :] = x
+            buf[pl.ds(4 * rows, 8), :] = tail
+            win = buf[pl.ds(q, rows + 8), :]
+            y = pltpu.roll(win, (i32(LANE) - r) & i32(LANE - 1), 1)
+            y_next = jnp.roll(y, -1, 0)
+            lane = jax.lax.broadcasted_iota(i32, (rows + 8, LANE), 1)
+            out.append(jnp.where(lane < i32(LANE) - r, y, y_next)[:rows])
+        out_hi_ref[:] = out[0].reshape(tile)
+        out_lo_ref[:] = out[1].reshape(tile)
 
     return kernel
 
@@ -231,11 +254,22 @@ def _merge_jit(a, b, tile, interpret):
     n_pad = (-(-S // tile)) * tile
     P = n_pad // tile
     splits = merge_splits(a, b, tile)
+    # Tile p's b window, b[k1 - ia[p+1] - bcnt, k1 - ia[p+1]), starts at
+    # e[p] = tile + L - (k1 - ia[p+1]) of the front-padded reversed b.
+    k1 = jnp.minimum(
+        jnp.arange(1, P + 1, dtype=jnp.int32) * jnp.int32(tile),
+        jnp.int32(S),
+    )
+    e = jnp.int32(tile + L) - (k1 - splits[1:])
     ones64 = ~jnp.uint64(0)
-    # Sentinel tails cover each window's full-tile DMA (astart <= R,
-    # bstart <= L by the split bounds, so start + tile <= len + tile).
-    a_pad = jnp.concatenate([a, jnp.full((tile,), ones64)])
-    b_pad = jnp.concatenate([b, jnp.full((tile,), ones64)])
+
+    def padded(x, front):
+        # Sentinels so both aligned blocks of every window exist.
+        n = front + x.shape[0]
+        back = (-(-n // tile) + 1) * tile - n
+        return jnp.concatenate([
+            jnp.full((front,), ones64), x, jnp.full((back,), ones64)
+        ])
 
     def planes(x):
         return (
@@ -243,25 +277,36 @@ def _merge_jit(a, b, tile, interpret):
             (x & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
         )
 
-    a_hi, a_lo = planes(a_pad)
-    b_hi, b_lo = planes(b_pad)
-    vma = compat.varying_mesh_axes(a)
-    spec = pl.BlockSpec((tile,), lambda p, ia: (p,))
-    out = compat.shape_dtype_struct((n_pad,), jnp.uint32, vma=vma)
+    a_hi, a_lo = planes(padded(a, 0))
+    b_hi, b_lo = planes(padded(b[::-1], tile))
+    vma = jax.typeof(a).vma
+    # Block index = offset >> log2(tile): Mosaic lowers no scalar
+    # floor division.
+    shift = tile.bit_length() - 1
+    a_specs = [
+        pl.BlockSpec((tile,), lambda p, ia, e: (ia[p] >> shift,)),
+        pl.BlockSpec((tile,), lambda p, ia, e: ((ia[p] >> shift) + 1,)),
+    ]
+    b_specs = [
+        pl.BlockSpec((tile,), lambda p, ia, e: (e[p] >> shift,)),
+        pl.BlockSpec((tile,), lambda p, ia, e: ((e[p] >> shift) + 1,)),
+    ]
+    spec = pl.BlockSpec((tile,), lambda p, ia, e: (p,))
+    out = jax.ShapeDtypeStruct((n_pad,), jnp.uint32, vma=vma)
+    rows = tile // LANE
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(P,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
+        in_specs=a_specs * 2 + b_specs * 2,
         out_specs=(spec, spec),
-        scratch_shapes=[pltpu.VMEM((tile,), jnp.uint32)] * 4
-        + [pltpu.SemaphoreType.DMA((4,))],
+        scratch_shapes=[pltpu.VMEM((4 * rows + 8, LANE), jnp.uint32)] * 2,
     )
     out_hi, out_lo = pl.pallas_call(
         _make_merge_kernel(S, tile),
         out_shape=(out, out),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(splits, a_hi, a_lo, b_hi, b_lo)
+    )(splits, e, a_hi, a_hi, a_lo, a_lo, b_hi, b_hi, b_lo, b_lo)
     merged = out_hi.astype(jnp.uint64) << jnp.uint64(32) | out_lo.astype(
         jnp.uint64
     )

@@ -46,7 +46,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils import compat
 
 LANE = 128
 TILE = 32_768  # elements per grid step; rows = TILE // LANE
@@ -277,9 +276,9 @@ def _join_scans_jit(sp, l_count, r_count, tag_bits, L, R, tile, interpret):
     counts = jnp.stack(
         [l_count.astype(jnp.int32), r_count.astype(jnp.int32)]
     )
-    vma = compat.varying_mesh_axes(sp)
+    vma = jax.typeof(sp).vma
     spec = pl.BlockSpec((tile,), lambda p, counts: (p,))
-    out = compat.shape_dtype_struct((n_pad,), jnp.int32, vma=vma)
+    out = jax.ShapeDtypeStruct((n_pad,), jnp.int32, vma=vma)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_pad // tile,),

@@ -19,7 +19,7 @@ runs exactly ONCE per shuffle, cpp:54-111):
   column's per-peer char byte counts) ride a single batched int32
   `communicate_sizes` exchange;
 - row-aligned buffers of equal element width — across ALL tables —
-  bit-pack into `[n, B, k]` buffers that the communicator's `exchange`
+  bit-pack into `[n, k, B]` buffers that the communicator's `exchange`
   entry point moves with ONE collective per width class (fuse-capable
   backends) or one per buffer (Ring/Buffered, the reference's
   group_by_batch()==false backends);
@@ -276,7 +276,7 @@ def shuffle_tables(
        collective (the reference's single host-MPI size round per
        shuffle, cpp:54-111).
     2. ONE `Communicator.exchange` epoch for all data: per (width,
-       table) the equal-width buffers bit-pack into a [n, B, k] buffer;
+       table) the equal-width buffers bit-pack into a [n, k, B] buffer;
        fuse-capable backends then move each width class (across tables)
        with one collective, and all string char buffers (uint8) with
        one more. Compressed buffers ride the same epoch as their own
@@ -368,17 +368,23 @@ def shuffle_tables(
             for s in slots:
                 by_table.setdefault(s[0], []).append(s)
             for t, tslots in by_table.items():
-                stacked = jnp.stack(
+                # The k columns stack on a MAJOR axis, [n, k, B]: a
+                # trailing k axis is the TPU's lane dimension, padded to
+                # 128, so a [B, 2] u64 buffer takes 64x its bytes (the
+                # 4-chip odf=1 100M-row step planned 15.7 GB of temp
+                # per chip for it and did not load).
+                buffers.append(jnp.stack(
                     [
-                        jax.lax.bitcast_convert_type(_slot_data(tables, s), u)
+                        bucketize(
+                            jax.lax.bitcast_convert_type(
+                                _slot_data(tables, s), u
+                            ),
+                            part_starts[t], sent_counts[t], bucket_rows[t],
+                        )
                         for s in tslots
                     ],
-                    axis=-1,
-                )  # [cap, k]
-                buffers.append(
-                    bucketize(stacked, part_starts[t], sent_counts[t],
-                              bucket_rows[t])
-                )
+                    axis=1,
+                ))
                 metas.append(("width", (t, tuple(tslots))))
         for slot, copts in plan.compressed:
             t, kind, i = slot
@@ -467,17 +473,19 @@ def shuffle_tables(
         for buf, (kind, info) in zip(received[1:], metas[1:]):
             if kind == "width":
                 t, tslots = info
-                data, _ = compact(buf, recv_counts[t], out_capacity[t])
                 for k_slot, (_, skind, i) in enumerate(tslots):
+                    data, _ = compact(
+                        buf[:, k_slot], recv_counts[t], out_capacity[t]
+                    )
                     if skind == "sizes":
                         recv_sizes[(t, i)] = jax.lax.bitcast_convert_type(
-                            data[..., k_slot], jnp.int32
+                            data, jnp.int32
                         )
                     else:
                         col = tables[t].columns[i]
                         out_cols[t][i] = Column(
                             jax.lax.bitcast_convert_type(
-                                data[..., k_slot], jnp.dtype(col.dtype.physical)
+                                data, jnp.dtype(col.dtype.physical)
                             ),
                             col.dtype,
                         )

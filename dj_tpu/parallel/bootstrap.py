@@ -155,33 +155,39 @@ def retry_backoff(
     ) from last
 
 
-def setup_compile_cache() -> Optional[str]:
-    """Wire jax's persistent (on-disk) compilation cache from
-    ``DJ_COMPILE_CACHE=<dir>`` — the first slice of the ROADMAP's
-    compile-churn item: a serving fleet's restart (or a warm-restarted
-    join-index inventory) re-pays every module's XLA compile from
-    scratch unless the lowered artifacts persist somewhere keyed like
-    the ledger. The thresholds drop to zero so even the small CPU-mesh
-    test modules cache (the default floors skip sub-second compiles —
-    exactly the ones a warm restart replays hundreds of).
+# Where the persistent compilation cache lives when the environment
+# does not place it: one fixed directory inside the checkout (listed in
+# .gitignore). The path must not move between runs, or nothing cached
+# is ever found again.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
-    Returns the cache dir when wired, None when unset or when this jax
-    lacks the config knobs (best-effort: an old jaxlib must not break
-    bootstrap). Idempotent; called from :func:`init_distributed` so
-    every driver gets it with no extra line. ``dj_compile_seconds_total``
-    (obs.cached_build) is the companion metric — a populated cache
-    shows up as the compile share collapsing cold-to-warm."""
-    path = os.environ.get("DJ_COMPILE_CACHE")
-    if not path:
-        return None
+
+def setup_compile_cache() -> str:
+    """Wire jax's persistent (on-disk) compilation cache so a restart
+    (or a warm-restarted join-index inventory) does not re-pay every
+    module's XLA compile. ``JAX_COMPILATION_CACHE_DIR``, when set,
+    places it and jax reads it itself; otherwise the cache goes to
+    :data:`DEFAULT_COMPILE_CACHE_DIR`. The thresholds drop to zero so
+    even the small modules cache (the default floors skip sub-second
+    compiles — exactly the ones a warm restart replays hundreds of).
+
+    Returns the cache dir. Idempotent; called from
+    :func:`init_distributed` so every driver gets it with no extra
+    line. ``dj_compile_seconds_total`` (obs.cached_build) is the
+    companion metric — a populated cache shows up as the compile share
+    collapsing cold-to-warm."""
     import jax
 
-    try:
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_COMPILE_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except (AttributeError, ValueError):
-        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path
 
 
@@ -207,9 +213,8 @@ def init_distributed(
     # scripts/run_tpu.sh set it — a user calling the library directly
     # got serial shuffles).
     ensure_async_collectives()
-    # Persistent compilation cache (DJ_COMPILE_CACHE): wired at the
-    # same bootstrap moment for the same reason — it must be in place
-    # before the first trace.
+    # Persistent compilation cache: wired at the same bootstrap moment
+    # for the same reason — it must be in place before the first trace.
     setup_compile_cache()
     # Live telemetry endpoint (DJ_OBS_HTTP=<port>, off by default):
     # started here so a served fleet exposes /metrics /healthz /queryz

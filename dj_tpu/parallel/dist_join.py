@@ -54,7 +54,6 @@ from ..resilience import heal as heal_engine
 from ..resilience import ledger as dj_ledger
 from ..resilience.errors import PlanMismatch
 from ..resilience.heal import HealBudget
-from ..utils import compat
 from ..utils.timing import annotate
 from ..ops import hashing
 from ..ops.join import (
@@ -729,7 +728,7 @@ def _build_partition_count_fn(
     spec = topology.row_spec()
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec),
         out_specs=spec,
@@ -846,7 +845,7 @@ def _build_join_fn(
     spec = topology.row_spec()
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=(spec, spec, spec),
@@ -919,7 +918,7 @@ def _build_broadcast_join_fn(
     out_cap = max(1, int(config.join_out_factor * max(l_cap, n * r_cap)))
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=(spec, spec, spec),
@@ -1000,7 +999,7 @@ def _build_local_join_fn(
     out_cap = max(1, int(config.join_out_factor * max(l_cap, r_cap)))
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=(spec, spec, spec),
@@ -1080,7 +1079,7 @@ def _build_salted_join_fn(
     salt_set = frozenset(int(p) for p in salt)
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=(spec, spec, spec),
@@ -1573,7 +1572,7 @@ def _build_prepare_fn(
     sizing = batch_sizing(config, n, l_cap_m, r_cap_m)
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec),
         out_specs=spec,
@@ -1896,7 +1895,7 @@ def _build_bc_prepare_fn(
     n = topology.world_size
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec),
         out_specs=spec,
@@ -1960,7 +1959,7 @@ def _build_salted_prepare_fn(
     salt_set = frozenset(int(p) for p in salt)
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec),
         out_specs=spec,
@@ -2443,7 +2442,7 @@ def _build_prepared_query_fn(
     odf = config.over_decom_factor
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec, spec),
         out_specs=(spec, spec, spec),
@@ -2565,7 +2564,7 @@ def _build_bc_prepared_query_fn(
     spec = topology.row_spec()
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec, spec),
         out_specs=(spec, spec, spec),
@@ -2630,7 +2629,7 @@ def _build_salted_prepared_query_fn(
     odf = config.over_decom_factor
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec, spec),
         out_specs=(spec, spec, spec),
@@ -3069,7 +3068,7 @@ def _build_coalesced_query_fn(
     odf = config.over_decom_factor
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec, spec),
         out_specs=(spec, spec, spec),
@@ -3228,7 +3227,7 @@ def _build_bc_coalesced_query_fn(
     spec = topology.row_spec()
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec, spec),
         out_specs=(spec, spec, spec),
@@ -3531,7 +3530,7 @@ def _build_coalesced_join_fn(
     m, _, _, bl, br, batch_out_cap = batch_sizing(config, n, l_cap, r_cap)
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=(spec, spec, spec),
@@ -3844,7 +3843,7 @@ def _build_append_probe_fn(
     spec = topology.row_spec()
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec),
         out_specs=spec,
@@ -3890,7 +3889,7 @@ def _build_append_merge_fn(
     R = n * br
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec, spec, spec, spec),
         out_specs=spec,
@@ -3945,7 +3944,7 @@ def _build_append_source_fn(topology: Topology, env_key: tuple):
     spec = topology.row_spec()
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=spec,

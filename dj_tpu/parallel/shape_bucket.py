@@ -208,13 +208,12 @@ def _build_pad_fn(
     import jax
     import jax.numpy as jnp
 
-    from ..utils import compat
     from ..utils.timing import annotate
 
     spec = topology.row_spec()
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec,),
         out_specs=spec,

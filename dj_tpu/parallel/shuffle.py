@@ -28,7 +28,6 @@ from ..resilience import errors as resil
 from ..resilience import faults
 from ..resilience import heal as heal_engine
 from ..resilience import ledger as dj_ledger
-from ..utils import compat
 from ..ops.partition import hash_partition, partition_counts
 from .all_to_all import OVF_BUCKET, OVF_OUT, shuffle_table, shuffle_tables
 from .communicator import Communicator, XlaCommunicator, make_communicator
@@ -239,7 +238,7 @@ def _build_shuffle_fn(
     spec = topology.row_spec()
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topology.mesh,
         in_specs=(spec, spec),
         out_specs=(spec, spec, spec, spec, spec),

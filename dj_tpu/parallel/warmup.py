@@ -17,7 +17,6 @@ import jax.numpy as jnp
 
 from ..compress import cascaded as cz
 from ..obs import recorder as obs
-from ..utils import compat
 from .communicator import Communicator, XlaCommunicator
 from .topology import Topology
 
@@ -38,7 +37,7 @@ def warmup_all_to_all(
         bucket = max(1, per_shard // n)
 
         @functools.partial(
-            compat.shard_map, mesh=topology.mesh, in_specs=spec, out_specs=spec
+            jax.shard_map, mesh=topology.mesh, in_specs=spec, out_specs=spec
         )
         def run(x):
             buckets = x[: n * bucket].reshape(n, bucket)

@@ -22,6 +22,11 @@ byte threshold that broadcasts the small build side, a lowered salt
 ratio): the mix keeps one broadcast and one salted signature live
 every iteration, and the summary asserts both tiers actually engaged.
 
+One process per chip: this script imports JAX pinned to the CPU
+(``jax_platforms=cpu``) and then starts worker processes (the fleet
+drills). Neither the parent nor the workers touch a TPU, so they leave
+the chip alone.
+
 It also runs with the per-signature plan autotuner ARMED
 (DJ_AUTOTUNE=1, PR 16) and walks its two fault sites (autotune_probe
 / autotune_apply): every iteration asserts zero duplicate tunes per

@@ -41,7 +41,7 @@ def main():
     idx = jnp.asarray(rng.integers(0, N, N, dtype=np.int32))
     t0 = time.perf_counter()
     out = run(vals, idx)
-    np.asarray(out[:1])
+    jax.block_until_ready(out)
     print(f"compile+run OK in {time.perf_counter()-t0:.2f}s")
     want = np.asarray(vals)[np.asarray(idx)]
     np.testing.assert_array_equal(np.asarray(out), want)
@@ -69,12 +69,12 @@ def main():
 
         return jax.lax.fori_loop(0, k, body, (vals, idx))[1]
 
-    np.asarray(loop(vals, idx, 1)[:1])
+    jax.block_until_ready(loop(vals, idx, 1))
     t0 = time.perf_counter()
-    np.asarray(loop(vals, idx, 1)[:1])
+    jax.block_until_ready(loop(vals, idx, 1))
     t1 = time.perf_counter() - t0
     t0 = time.perf_counter()
-    np.asarray(loop(vals, idx, 17)[:1])
+    jax.block_until_ready(loop(vals, idx, 17))
     t17 = time.perf_counter() - t0
     per = (t17 - t1) / 16
     print(f"VMEM gather {N} elems: {per*1e6:.0f} us/iter "

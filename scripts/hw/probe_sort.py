@@ -34,10 +34,10 @@ def tile_sort(x):
 
 def main():
     x = jax.random.bits(jax.random.PRNGKey(0), (NT * TILE,), dtype=jnp.uint32)
-    np.asarray(x[:1])
+    jax.block_until_ready(x)
     t0 = time.perf_counter()
     out = tile_sort(x)
-    np.asarray(out[:1])
+    jax.block_until_ready(out)
     print(f"pallas tile-sort compile+run {time.perf_counter()-t0:.2f}s")
     o = np.asarray(out).reshape(NT, TILE)
     w = np.sort(np.asarray(x).reshape(NT, TILE), axis=1)
@@ -47,11 +47,11 @@ def main():
         ("pallas tile-sort", tile_sort),
         ("lax.sort flat", jax.jit(lambda v: jax.lax.sort(v))),
     ):
-        np.asarray(f(x)[:1])
+        jax.block_until_ready(f(x))
         best = None
         for _ in range(3):
             t0 = time.perf_counter()
-            np.asarray(f(x)[:1])
+            jax.block_until_ready(f(x))
             dt = time.perf_counter() - t0
             best = dt if best is None else min(best, dt)
         per = best / (NT * TILE) * 1e9

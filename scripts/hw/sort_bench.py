@@ -39,7 +39,7 @@ def main():
         x = jax.random.bits(
             jax.random.PRNGKey(0), (n,), dtype=jnp.uint32
         ).astype(jnp.uint64) << jnp.uint64(17)
-        np.asarray(x[:1])
+        jax.block_until_ready(x)
         fns = {}
         if "xla" in IMPLS:
             fns["xla"] = jax.jit(lambda v, k: jax.lax.sort(v + k))
@@ -54,7 +54,7 @@ def main():
                 compile_s = time.perf_counter() - t0
                 f = fc
                 out = f(x, jnp.uint64(0))
-                np.asarray(out[:1])
+                jax.block_until_ready(out)
                 # Correctness spot check on first run (uint64 diff
                 # wraps, so compare adjacent elements directly).
                 head = np.asarray(out[:1_000_000])
@@ -62,7 +62,7 @@ def main():
                 best = None
                 for k in range(1, 4):
                     t0 = time.perf_counter()
-                    np.asarray(f(x, jnp.uint64(k))[:1])
+                    jax.block_until_ready(f(x, jnp.uint64(k)))
                     dt = time.perf_counter() - t0
                     best = dt if best is None else min(best, dt)
                 print(

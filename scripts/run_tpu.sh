@@ -17,7 +17,8 @@ if [[ -n "${COORDINATOR:-}" ]]; then
   export JAX_NUM_PROCESSES="${NUM_PROC:?set NUM_PROC}"
   export JAX_PROCESS_ID="${PROC_ID:?set PROC_ID}"
 fi
-# CPU simulation fallback: DJ_SIM_DEVICES=8 runs without TPUs.
+# Explicit CPU opt-in: DJ_SIM_DEVICES=8 runs on 8 virtual CPU devices.
+# It never runs unasked: without it the driver runs on the host's TPU.
 if [[ -n "${DJ_SIM_DEVICES:-}" ]]; then
   export JAX_PLATFORMS=cpu
   export XLA_FLAGS="--xla_force_host_platform_device_count=${DJ_SIM_DEVICES} ${XLA_FLAGS:-}"

@@ -70,6 +70,29 @@ def test_parser_compiled_counts_and_sizes():
     assert contracts.op_count(_COMPILED, "all-reduce") == 0
 
 
+def _installed_jax_module(op):
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("x",))
+    body = {
+        "sort": lambda x: jax.lax.sort((x, -x), num_keys=1)[0],
+        "all-to-all": lambda x: jax.lax.all_to_all(x, "x", 0, 0, tiled=True),
+        "all-gather": lambda x: jax.lax.all_gather(x, "x", tiled=True),
+    }[op]
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("x"),
+                               out_specs=P("x")))
+    return fn.lower(np.arange(64, dtype=np.int32)).compile().as_text()
+
+
+@pytest.mark.parametrize("op", ["sort", "all-to-all", "all-gather"])
+def test_parser_reads_installed_jax_compiled_text(op):
+    # Regression: a JAX that prints operands untyped once left the
+    # parser with {} on every module, and every upper-bound contract
+    # passed without checking anything.
+    sizes = contracts.op_sizes(_installed_jax_module(op), op)
+    assert sizes and all(s > 0 for s in sizes), sizes
+
+
 def test_parser_stablehlo_counts():
     assert contracts.op_count(_STABLE, "sort") == 1
     assert contracts.op_count(_STABLE, "all-to-all") == 1

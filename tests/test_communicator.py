@@ -22,7 +22,6 @@ import numpy as np
 import pytest
 
 import dj_tpu
-from dj_tpu.utils import compat
 
 
 def _exchange(comm_cls, topo, bucket):
@@ -33,7 +32,7 @@ def _exchange(comm_cls, topo, bucket):
 
     @jax.jit
     @functools.partial(
-        compat.shard_map, mesh=topo.mesh, in_specs=spec, out_specs=spec
+        jax.shard_map, mesh=topo.mesh, in_specs=spec, out_specs=spec
     )
     def run(x):
         rank = comm.rank()

@@ -137,3 +137,20 @@ def test_gpubdb_driver(tmp_path, capsys):
     # 10 files, 8 shards, 2 files/rank max -> all files read.
     assert result["rows_shuffled"] == nrows_total
     assert result["devices"] == 8
+
+
+def test_chip_smoke_refuses_without_a_tpu():
+    """chip_smoke.py is the on-chip proof: on any other platform it
+    exits non-zero and never prints its ``"ok": true`` result line (no
+    fallback may pass for a chip run)."""
+    import os
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "chip_smoke.py", "--rows", "1000"],
+        cwd=_REPO, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode != 0
+    assert '"ok": true' not in out.stdout
+    assert "not a TPU" in out.stderr

@@ -40,7 +40,6 @@ from dj_tpu.core import table as T
 from dj_tpu.parallel.all_to_all import shuffle_table, shuffle_tables
 from dj_tpu.parallel.dist_join import _build_join_fn, _env_key
 from dj_tpu.ops.partition import hash_partition, partition_counts
-from dj_tpu.utils import compat
 
 
 def _small_buffered(group, fuse_columns=False):
@@ -117,7 +116,7 @@ def test_fused_matches_independent_shuffles(n, comm_cls):
 
     @jax.jit
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=topo.mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=spec,

@@ -414,6 +414,9 @@ def test_bench_metrics_out_snapshot(tmp_path):
         DJ_BENCH_ROWS="50000",
         DJ_BENCH_ODF="1",
         DJ_BENCH_WATCHDOG_S="600",
+        # The CPU has no published HBM peak; bench.py refuses to write
+        # roofline_frac against a guessed one.
+        DJ_PEAK_HBM_GBPS="819",
     )
     env.pop("DJ_OBS", None)
     env.pop("DJ_OBS_LOG", None)

@@ -101,6 +101,15 @@ def test_observe_phase_fraction_and_peak_knobs(obs_capture, monkeypatch):
     assert roofline.observe_phase(
         "t_zero", 0.5, model_bytes=1e9, kind="hbm"
     ) is None
+    # Unset knobs take the device kind's published peak; the CPU has
+    # none, so no fraction rather than one against a guessed peak.
+    monkeypatch.delenv("DJ_PEAK_HBM_GBPS")
+    monkeypatch.delenv("DJ_PEAK_WIRE_GBPS")
+    assert roofline.hbm_peak_gbps() is None
+    assert roofline.observe_phase(
+        "t_nopeak", 0.5, model_bytes=1e9, kind="wire"
+    ) is None
+    assert roofline.DEVICE_PEAKS_GBPS["TPU v5 lite"]["hbm"] == 819.0
 
 
 def test_phase_scope_records_on_exception(obs_capture):
@@ -536,6 +545,9 @@ def test_served_query_trace_has_phases_and_skew(obs_capture, monkeypatch):
 
     obs = obs_capture
     monkeypatch.setenv("DJ_OBS_SKEW", "1")
+    # The CPU has no published peaks: price against explicit ones.
+    monkeypatch.setenv("DJ_PEAK_HBM_GBPS", "819")
+    monkeypatch.setenv("DJ_PEAK_WIRE_GBPS", "200")
     n_rows = 2048
     topo, left, lc, right, rc = _mesh_tables(seed=37, n=n_rows)
     cfg = JoinConfig(
@@ -658,7 +670,8 @@ def test_bench_restart_ab_mode(tmp_path):
         DJ_BENCH_ROWS="30000",
         DJ_BENCH_ODF="1",
         DJ_BENCH_WATCHDOG_S="500",
-        DJ_COMPILE_CACHE=str(cache),
+        JAX_COMPILATION_CACHE_DIR=str(cache),
+        DJ_PEAK_HBM_GBPS="819",  # the CPU has no published peak
     )
     env.pop("DJ_OBS", None)
     env.pop("DJ_OBS_LOG", None)
@@ -674,7 +687,10 @@ def test_bench_restart_ab_mode(tmp_path):
     assert line["restart"]["cold_trace_s"] is not None
     assert line["first_boot"]["query_s"] > 0
     assert line["restart"]["query_s"] > 0
-    assert line["cache_dir"] == str(cache)
+    # The children's cache is a fixed directory under the cache root,
+    # emptied before the cold child and filled by it.
+    assert line["cache_dir"] == str(cache / "restart_ab")
+    assert any((cache / "restart_ab").iterdir())
     # The ratio is reported (the payoff itself is backend-dependent;
     # on backends the persistent cache does not serve it reports ~1).
     assert line["value"] is None or line["value"] > 0
