@@ -432,8 +432,8 @@ def _timed_first_call(fn, builder_name: str):
     would need AOT lower/compile, which bypasses the jit cache and
     would double-compile the module). Read it as "the cold-start
     penalty a warm call does not pay", and compare against
-    ``dj_query_dispatch_seconds``' warm band rather than treating it
-    as pure-compile. With jax's persistent compilation cache wired
+    ``dj_phase_seconds{phase="dispatch"}``' warm band rather than
+    treating it as pure-compile. With jax's persistent compilation cache wired
     (bootstrap.setup_compile_cache) the
     cold-vs-warm delta collapses toward trace+execute on a disk hit."""
     state = {"cold": True}

@@ -1,12 +1,13 @@
 """Per-query phase attribution and measured-vs-roofline fractions.
 
-The headline perf question — 5.90 s measured vs the reference's
-0.39 s, roofline_frac 0.022 — has been judged only at whole-run
-granularity (bench.py's one modeled-bytes scalar over one wall-clock
-number). This module attributes time to the HOST-VISIBLE phases of
-every query and prices each phase against a peak-bandwidth roofline,
-so ``obs.query_trace(query_id)`` answers "which phase of THIS query
-ran at what fraction of peak":
+Device time per layer is the benchmark's to measure (``BENCHMARK.json``:
+profiler traces reduced by ``djbench/trace.py`` to per-scope device
+seconds). This module attributes time to the HOST-VISIBLE phases of
+every query, puts each on the profiler's clock as the host span
+``dj.<phase>`` (``utils.timing.host_span``, entered whether or not obs
+is enabled), and, where the caller prices a phase, compares it with a
+peak-bandwidth roofline, so ``obs.query_trace(query_id)`` answers
+"which phase of THIS query ran at what fraction of peak":
 
 - :func:`phase` / :func:`observe_phase` — time one phase; emit a
   ``phase`` event (stamped with the ambient query identity like every
@@ -22,15 +23,16 @@ ran at what fraction of peak":
   against a guess.
 - The phase inventory the pipeline emits: ``probe`` (host key-range
   probe), ``build`` (module build; trace+compile on a cache miss),
-  ``dispatch`` (the jit invocation — async on a warm module; its
-  roofline is the WIRE model from the module's memoized epoch bytes),
+  ``dispatch`` (the jit invocation — async on a warm module, so it is
+  timed but never priced: it returns before the device moves a byte),
   ``sync`` (the heal engine's host flag materialization — where the
   device wait actually lands), ``prep`` (prepare_join_side's
-  build+run), and the scheduler's ``run`` (dispatch -> terminal wall,
-  priced against the admission forecast's HBM model — the honest
-  per-query headline fraction). Finer phases (per-batch exchange /
-  join / concat) are fused inside one XLA computation and live in
-  profiler traces (``timing.annotate``), not here.
+  build+run, priced against its WIRE bytes: it blocks), and the
+  scheduler's ``run`` (dispatch -> terminal wall, priced against the
+  admission forecast's HBM model). Finer phases (per-batch exchange /
+  join / concat, and the local join's pack / sort / scan / expand /
+  gather) are fused inside one XLA computation and live in profiler
+  traces (``timing.annotate``), not here.
 - Accumulated per-process phase totals ride a
   :class:`~..utils.timing.PhaseTimer` (the reference's per-rank
   report_timing store, threaded through the query context instead of a
@@ -54,7 +56,7 @@ from typing import Optional
 from . import metrics as _metrics
 from . import recorder as _recorder
 from .. import knobs
-from ..utils.timing import PhaseTimer
+from ..utils.timing import PhaseTimer, host_span
 
 __all__ = [
     "FRAC_BUCKETS",
@@ -69,7 +71,8 @@ __all__ = [
 ]
 
 # Bucket ladder for roofline fractions: most phases run far below peak
-# (the 0.022 headline), so the resolution concentrates at the low end;
+# (the local join reads 0.0841% of its HBM roofline on a v5e,
+# PERF.md), so the resolution concentrates at the low end;
 # >1 means the byte model under-counted (or the clock missed async
 # work) and deserves its own bucket rather than vanishing into +Inf.
 FRAC_BUCKETS = (
@@ -160,8 +163,8 @@ def observe_phase(
         model_bytes=None if model_bytes is None else int(model_bytes),
         kind=kind,
         # Significant digits, not decimal places: the fractions of
-        # interest live around 1e-2..1e-7 (the 0.022 headline), where
-        # round(frac, 6) collapses to 0.0.
+        # interest live around 1e-2..1e-7, where round(frac, 6)
+        # collapses to 0.0.
         roofline_frac=None if frac is None else float(f"{frac:.4g}"),
         **fields,
     )
@@ -179,14 +182,16 @@ def phase(
     **fields,
 ):
     """Bracket a body as one phase (observe_phase on exit — exception
-    or not, so a raised heal still attributes its wall time).
-    ``bytes_fn`` resolves the byte model AT EXIT (the dispatch phase's
+    or not, so a raised heal still attributes its wall time), inside
+    the host span ``dj.<name>`` on the profiler's clock.
+    ``bytes_fn`` resolves the byte model AT EXIT (the prep phase's
     wire bytes only exist after the module's first trace populates the
     epoch memo); a bytes_fn failure degrades to no fraction, never to
     a failed query."""
     t0 = time.perf_counter()
     try:
-        yield
+        with host_span(name):
+            yield
     finally:
         mb = model_bytes
         if bytes_fn is not None:

@@ -52,6 +52,7 @@ from ..core.dtypes import UINT_BY_SIZE
 from ..core.search import count_leq_arange
 from ..core.table import Column, StringColumn, Table
 from ..obs import recorder as obs
+from ..utils.timing import annotate
 from . import hashing
 
 
@@ -303,30 +304,33 @@ def _multi_key_merged_sort(
     downstream masks zero out exactly like the single-key path).
     """
     L, R = left.capacity, right.capacity
-    lvalid = jnp.arange(L, dtype=jnp.int32) < left.count()
-    rvalid = jnp.arange(R, dtype=jnp.int32) < right.count()
-    inv = jnp.concatenate([~rvalid, ~lvalid])
-    keys = []
-    for lc, rc in zip(left_on, right_on):
-        a = left.columns[lc]
-        b = right.columns[rc]
-        assert isinstance(a, Column) and isinstance(b, Column), (
-            "string keys reach the sort un-surrogated — inner_join "
-            "converts them via _surrogate_string_keys; call that first"
+    with annotate("dj_pack"):
+        lvalid = jnp.arange(L, dtype=jnp.int32) < left.count()
+        rvalid = jnp.arange(R, dtype=jnp.int32) < right.count()
+        inv = jnp.concatenate([~rvalid, ~lvalid])
+        keys = []
+        for lc, rc in zip(left_on, right_on):
+            a = left.columns[lc]
+            b = right.columns[rc]
+            assert isinstance(a, Column) and isinstance(b, Column), (
+                "string keys reach the sort un-surrogated — inner_join "
+                "converts them via _surrogate_string_keys; call that first"
+            )
+            keys.append(jnp.concatenate([b.data, a.data]))
+        # Concatenation position IS the refs-first tag (right rows
+        # occupy 0..R-1, left rows R..R+L-1).
+        tag2 = jnp.arange(L + R, dtype=jnp.int32)
+        operands = [inv.astype(jnp.uint8)] + keys + [tag2]
+    with annotate("dj_sort"):
+        sorted_ops = jax.lax.sort(
+            tuple(operands), num_keys=1 + len(keys), is_stable=True
         )
-        keys.append(jnp.concatenate([b.data, a.data]))
-    # Concatenation position IS the refs-first tag (right rows occupy
-    # 0..R-1, left rows R..R+L-1).
-    tag2 = jnp.arange(L + R, dtype=jnp.int32)
-    operands = [inv.astype(jnp.uint8)] + keys + [tag2]
-    sorted_ops = jax.lax.sort(
-        tuple(operands), num_keys=1 + len(keys), is_stable=True
-    )
-    raw = sorted_ops[-1]
-    boundary = _run_starts(sorted_ops[0])
-    for sk in sorted_ops[1 : 1 + len(keys)]:
-        boundary = boundary | _run_starts(sk)
-    stag = jnp.where(raw < R, raw + jnp.int32(L), raw - jnp.int32(R))
+    with annotate("dj_scan"):
+        raw = sorted_ops[-1]
+        boundary = _run_starts(sorted_ops[0])
+        for sk in sorted_ops[1 : 1 + len(keys)]:
+            boundary = boundary | _run_starts(sk)
+        stag = jnp.where(raw < R, raw + jnp.int32(L), raw - jnp.int32(R))
     return boundary, stag
 
 
@@ -485,16 +489,17 @@ def _sort_packed(p: jax.Array, word_bits: int = 64) -> jax.Array:
     (monolithic lax.sort, or the two-pass bucketed candidate).
     ``word_bits`` bounds the occupied word width — the bucketed sort
     range-partitions on the top OCCUPIED bits."""
-    if os.environ.get("DJ_JOIN_SORT", "monolithic") == "bucketed":
-        return _bucketed_sort(p, word_bits=word_bits)
-    # lax.sort IS the sort: a 560-LoC Pallas merge sort (bitonic
-    # tile pass + aligned dual-sentinel merge-path passes) was
-    # built, hardware-measured 26% SLOWER at 65M and 200M (1544 vs
-    # 1221 ms — VPU-compute-bound in the Batcher network, not
-    # HBM-bound), shown to be within ~13% of its own op floor, and
-    # deleted in round 5 (ARCHITECTURE.md "The sort floor" has the
-    # measurement + op-count argument; git history has the code).
-    return jax.lax.sort(p)
+    with annotate("dj_sort"):
+        if os.environ.get("DJ_JOIN_SORT", "monolithic") == "bucketed":
+            return _bucketed_sort(p, word_bits=word_bits)
+        # lax.sort IS the sort: a 560-LoC Pallas merge sort (bitonic
+        # tile pass + aligned dual-sentinel merge-path passes) was
+        # built, hardware-measured 26% SLOWER at 65M and 200M (1544 vs
+        # 1221 ms — VPU-compute-bound in the Batcher network, not
+        # HBM-bound), shown to be within ~13% of its own op floor, and
+        # deleted in round 5 (ARCHITECTURE.md "The sort floor" has the
+        # measurement + op-count argument; git history has the code).
+        return jax.lax.sort(p)
 
 
 def _pack_sort_core(
@@ -543,38 +548,42 @@ def _pack_sort_core(
         )
 
     def _scans_from_sp(sp):
-        if scans_impl.startswith("pallas"):
-            from .pallas_scan import join_scans
+        with annotate("dj_scan"):
+            if scans_impl.startswith("pallas"):
+                from .pallas_scan import join_scans
 
-            return join_scans(
-                sp,
-                l_count,
-                r_count,
-                tag_bits=tag_bits,
-                L=L,
-                R=R,
-                interpret=scans_impl.endswith("-interpret"),
+                return join_scans(
+                    sp,
+                    l_count,
+                    r_count,
+                    tag_bits=tag_bits,
+                    L=L,
+                    R=R,
+                    interpret=scans_impl.endswith("-interpret"),
+                )
+            stag = _decode(sp)
+            run_start, cnt, csum = _match_scans_xla(
+                _run_starts(sp >> tag_bits), stag, l_count, r_count, L, R
             )
-        stag = _decode(sp)
-        run_start, cnt, csum = _match_scans_xla(
-            _run_starts(sp >> tag_bits), stag, l_count, r_count, L, R
-        )
-        return stag, run_start, cnt, csum
+            return stag, run_start, cnt, csum
 
-    p = jnp.where(valid, (rel << tag_bits) | tag2, ones)
+    with annotate("dj_pack"):
+        p = jnp.where(valid, (rel << tag_bits) | tag2, ones)
     if carry_ops:
         # Variadic sort carrying the union operands; packed words
         # are distinct so no stability is required. The key in
         # unsigned-order image is recovered from the sorted word
         # (padding decodes to the all-ones image, masked later by
         # validity).
-        sorted_all = jax.lax.sort(
-            tuple([p]) + carry_ops, num_keys=1, is_stable=False
-        )
+        with annotate("dj_sort"):
+            sorted_all = jax.lax.sort(
+                tuple([p]) + carry_ops, num_keys=1, is_stable=False
+            )
         sp = sorted_all[0]
-        key_su64 = (sp >> tag_bits) + (
-            kmin if kmin is not None else jnp.uint64(0)
-        )
+        with annotate("dj_scan"):
+            key_su64 = (sp >> tag_bits) + (
+                kmin if kmin is not None else jnp.uint64(0)
+            )
         return _scans_from_sp(sp) + (
             key_su64,
             tuple(sorted_all[1:]),
@@ -585,8 +594,9 @@ def _pack_sort_core(
     sp = _sort_packed(p, word_bits)
     if scans_impl is not None:
         return _scans_from_sp(sp)
-    boundary = _run_starts(sp >> tag_bits)
-    return boundary, _decode(sp)
+    with annotate("dj_scan"):
+        boundary = _run_starts(sp >> tag_bits)
+        return boundary, _decode(sp)
 
 
 def _packed_merged_sort(
@@ -646,13 +656,14 @@ def _packed_merged_sort(
     tag_bits = max(1, int(S).bit_length())  # 2^tag_bits - 1 >= S
     assert tag_bits < 32, "int32 tag machinery caps capacities below 2^31"
     ones = ~jnp.uint64(0)
-    ukey = _to_unsigned_order(vals)
-    valid = jnp.concatenate(
-        [
-            jnp.arange(R, dtype=jnp.int32) < r_count,
-            jnp.arange(L, dtype=jnp.int32) < l_count,
-        ]
-    )
+    with annotate("dj_pack"):
+        ukey = _to_unsigned_order(vals)
+        valid = jnp.concatenate(
+            [
+                jnp.arange(R, dtype=jnp.int32) < r_count,
+                jnp.arange(L, dtype=jnp.int32) < l_count,
+            ]
+        )
 
     def packed(rel: jax.Array, kmin=None, rb: Optional[int] = None):
         return _pack_sort_core(
@@ -673,46 +684,52 @@ def _packed_merged_sort(
         # can only shrink the span), so a truthful declared RANGE is
         # not even required — only a truthful span bound; inner_join
         # raises the pack_range_overflow flag if even that is violated.
-        ukmin = jnp.min(jnp.where(valid, ukey, ones))
-        return packed(ukey - ukmin, ukmin, rb=rel_bits)
+        with annotate("dj_pack"):
+            ukmin = jnp.min(jnp.where(valid, ukey, ones))
+            rel = ukey - ukmin
+        return packed(rel, ukmin, rb=rel_bits)
 
     def fallback():
-        tag = jnp.concatenate(
-            [
-                jnp.arange(R, dtype=jnp.int32) + jnp.int32(L),
-                jnp.arange(L, dtype=jnp.int32),
-            ]
-        )
-        sorted_all = jax.lax.sort(
-            (vals, tag) + carry_ops, num_keys=1, is_stable=True
-        )
-        svals, stag = sorted_all[0], sorted_all[1]
-        boundary = _run_starts(svals)
-        if scans_impl is not None:
-            run_start, cnt, csum = _match_scans_xla(
-                boundary, stag, l_count, r_count, L, R
+        with annotate("dj_pack"):
+            tag = jnp.concatenate(
+                [
+                    jnp.arange(R, dtype=jnp.int32) + jnp.int32(L),
+                    jnp.arange(L, dtype=jnp.int32),
+                ]
             )
-            out = (stag, run_start, cnt, csum)
-            if carry_ops:
-                out = out + (
-                    _to_unsigned_order(svals),
-                    tuple(sorted_all[2:]),
+        with annotate("dj_sort"):
+            sorted_all = jax.lax.sort(
+                (vals, tag) + carry_ops, num_keys=1, is_stable=True
+            )
+        with annotate("dj_scan"):
+            svals, stag = sorted_all[0], sorted_all[1]
+            boundary = _run_starts(svals)
+            if scans_impl is not None:
+                run_start, cnt, csum = _match_scans_xla(
+                    boundary, stag, l_count, r_count, L, R
                 )
-            return out
-        return boundary, stag
+                out = (stag, run_start, cnt, csum)
+                if carry_ops:
+                    out = out + (
+                        _to_unsigned_order(svals),
+                        tuple(sorted_all[2:]),
+                    )
+                return out
+            return boundary, stag
 
     if static_fit is False:
         return fallback()
 
-    ukmin = jnp.min(jnp.where(valid, ukey, ones))
-    ukmax = jnp.max(jnp.where(valid, ukey, jnp.uint64(0)))
-    # Strictly below 2^(64-tag_bits) - 1, NOT <=: at range exactly
-    # 2^(64-tag_bits)-1 a max-key row with the top tag value would pack
-    # to the all-ones word — the padding sentinel — and padding would
-    # decode as that row. One range value falls to the fallback; no
-    # valid word can ever equal the sentinel.
-    span = jnp.uint64(1) << (64 - tag_bits)
-    fits = (ukmax - ukmin) < span - jnp.uint64(1)
+    with annotate("dj_pack"):
+        ukmin = jnp.min(jnp.where(valid, ukey, ones))
+        ukmax = jnp.max(jnp.where(valid, ukey, jnp.uint64(0)))
+        # Strictly below 2^(64-tag_bits) - 1, NOT <=: at range exactly
+        # 2^(64-tag_bits)-1 a max-key row with the top tag value would
+        # pack to the all-ones word — the padding sentinel — and
+        # padding would decode as that row. One range value falls to
+        # the fallback; no valid word can ever equal the sentinel.
+        span = jnp.uint64(1) << (64 - tag_bits)
+        fits = (ukmax - ukmin) < span - jnp.uint64(1)
     return jax.lax.cond(fits, lambda: packed(ukey - ukmin, ukmin), fallback)
 
 
@@ -1343,8 +1360,13 @@ def inner_join(
         lk = left.columns[left_on[0]].data
         rk = right.columns[right_on[0]].data
         maxv = jnp.iinfo(rk.dtype).max
-        key_l = jnp.where(jnp.arange(L, dtype=jnp.int32) < l_count, lk, maxv)
-        key_r = jnp.where(jnp.arange(R, dtype=jnp.int32) < r_count, rk, maxv)
+        with annotate("dj_pack"):
+            key_l = jnp.where(
+                jnp.arange(L, dtype=jnp.int32) < l_count, lk, maxv
+            )
+            key_r = jnp.where(
+                jnp.arange(R, dtype=jnp.int32) < r_count, rk, maxv
+            )
 
     right_on_set = set(right_on) | r_drop
     # Surrogate key columns (l_drop) are sort keys only — never output —
@@ -1374,13 +1396,14 @@ def inner_join(
     boundary = None
     run_start = None
     if single:
-        vals = jnp.concatenate([key_r, key_l])
-        tag = jnp.concatenate(
-            [
-                jnp.arange(R, dtype=jnp.int32) + jnp.int32(L),  # refs
-                jnp.arange(L, dtype=jnp.int32),  # left rows: row id
-            ]
-        )
+        with annotate("dj_pack"):
+            vals = jnp.concatenate([key_r, key_l])
+            tag = jnp.concatenate(
+                [
+                    jnp.arange(R, dtype=jnp.int32) + jnp.int32(L),  # refs
+                    jnp.arange(L, dtype=jnp.int32),  # left rows: row id
+                ]
+            )
     l_carry = [(i, c) for i, c in l_fixed if i != left_on[0]] if single else []
     n_pay = max(len(l_carry), len(r_fixed)) if single else 0
     # --- static key-pack planning (declared / probed key ranges) ------
@@ -1454,10 +1477,11 @@ def inner_join(
         # single-key packed machinery (sort core, fused scan kernel,
         # vmeta expansion) — the variadic multi-key sort is retired for
         # statically packable inputs.
-        rel, mvalid, mok = _multi_key_pack_word(
-            left, right, left_on, right_on, pack_plan, l_count, r_count
-        )
-        pack_ovf = ~mok
+        with annotate("dj_pack"):
+            rel, mvalid, mok = _multi_key_pack_word(
+                left, right, left_on, right_on, pack_plan, l_count, r_count
+            )
+            pack_ovf = ~mok
         mk_tag_bits = max(1, int(S).bit_length())
         mk_rel_bits = sum(pack_plan.widths)
         if scan_fused:
@@ -1478,14 +1502,17 @@ def inner_join(
         # Union slots: left fixed columns EXCLUDING the key (the key is
         # recovered from the sorted key vector itself) vs right payload
         # columns.
-        slots = _union_slots(l_carry, r_fixed, L, R)
-        sorted_ops = jax.lax.sort(
-            tuple([vals, tag] + slots), num_keys=1, is_stable=True
-        )
+        with annotate("dj_pack"):
+            slots = _union_slots(l_carry, r_fixed, L, R)
+        with annotate("dj_sort"):
+            sorted_ops = jax.lax.sort(
+                tuple([vals, tag] + slots), num_keys=1, is_stable=True
+            )
         svals, stag = sorted_ops[0], sorted_ops[1]
         spay = list(sorted_ops[2:])
     elif vcarry:
-        slots = _union_slots(l_carry, r_fixed, L, R)
+        with annotate("dj_pack"):
+            slots = _union_slots(l_carry, r_fixed, L, R)
         stag, run_start, cnt, csum, key_su64, sslots = _packed_merged_sort(
             vals, L, R, l_count, r_count,
             scans_impl=scans_impl, carry_ops=tuple(slots),
@@ -1502,7 +1529,10 @@ def inner_join(
             rel_bits=sk_rel_bits,
         )
     else:
-        svals, stag = jax.lax.sort((vals, tag), num_keys=1, is_stable=True)
+        with annotate("dj_sort"):
+            svals, stag = jax.lax.sort(
+                (vals, tag), num_keys=1, is_stable=True
+            )
     if single and use_pack and static_fit is True:
         tb = max(1, int(S).bit_length())
         if 8 * vals.dtype.itemsize + tb > 64:
@@ -1517,28 +1547,31 @@ def inner_join(
             # output is unspecified exactly like capacity overflow.
             # NOTE: mirrors _packed_merged_sort's legacy cond bound
             # and sentinel strictness — keep the two in sync.
-            ukey_c = _to_unsigned_order(vals)
-            uvalid = jnp.concatenate(
-                [
-                    jnp.arange(R, dtype=jnp.int32) < r_count,
-                    jnp.arange(L, dtype=jnp.int32) < l_count,
-                ]
-            )
-            ones64 = ~jnp.uint64(0)
-            ukmin = jnp.min(jnp.where(uvalid, ukey_c, ones64))
-            ukmax = jnp.max(jnp.where(uvalid, ukey_c, jnp.uint64(0)))
-            fits_dyn = (ukmax - ukmin) < jnp.uint64((1 << (64 - tb)) - 1)
-            pack_ovf = (~fits_dyn) & (l_count > 0) & (r_count > 0)
+            with annotate("dj_pack"):
+                ukey_c = _to_unsigned_order(vals)
+                uvalid = jnp.concatenate(
+                    [
+                        jnp.arange(R, dtype=jnp.int32) < r_count,
+                        jnp.arange(L, dtype=jnp.int32) < l_count,
+                    ]
+                )
+                ones64 = ~jnp.uint64(0)
+                ukmin = jnp.min(jnp.where(uvalid, ukey_c, ones64))
+                ukmax = jnp.max(jnp.where(uvalid, ukey_c, jnp.uint64(0)))
+                fits_dyn = (ukmax - ukmin) < jnp.uint64((1 << (64 - tb)) - 1)
+                pack_ovf = (~fits_dyn) & (l_count > 0) & (r_count > 0)
 
     # --- match ranges from scans (all in merged order, no scatters) ---
-    if run_start is None:
-        if boundary is None:
-            boundary = _run_starts(svals)
-        run_start, cnt, csum = _match_scans_xla(
-            boundary, stag, l_count, r_count, L, R
-        )
-    # Exact int64 total via pairwise reduction (csum is int32-clamped).
-    total = jnp.sum(cnt.astype(jnp.int64)) if S else jnp.int64(0)
+    with annotate("dj_scan"):
+        if run_start is None:
+            if boundary is None:
+                boundary = _run_starts(svals)
+            run_start, cnt, csum = _match_scans_xla(
+                boundary, stag, l_count, r_count, L, R
+            )
+        # Exact int64 total via pairwise reduction (csum is
+        # int32-clamped).
+        total = jnp.sum(cnt.astype(jnp.int64)) if S else jnp.int64(0)
 
     # --- expansion metadata: which merged position produces output j --
     # Three exact implementations of src[j] = #{csum <= j} (csum is
@@ -1563,294 +1596,296 @@ def inner_join(
     # are TPU-only). "pallas-vcarry" additionally rides the payloads
     # through the sort (see the pre-sort section; expand_impl was
     # resolved there because it changes what the sort carries).
-    fused = not carry and expand_impl.startswith("pallas-fused")
-    joinmode = not carry and expand_impl.startswith("pallas-join")
-    # "pallas-vmeta": the COMPILED fused expansion (delta-dot value
-    # expansion, pallas_expand.expand_values) — ranks, t, and the
-    # (stag, run_start) meta gather collapse into one kernel emitting
-    # (stag_j, rpos) with no output-sized gathers.
-    vmeta = not carry and expand_impl.startswith("pallas-vmeta")
+    with annotate("dj_expand"):
+        fused = not carry and expand_impl.startswith("pallas-fused")
+        joinmode = not carry and expand_impl.startswith("pallas-join")
+        # "pallas-vmeta": the COMPILED fused expansion (delta-dot value
+        # expansion, pallas_expand.expand_values) — ranks, t, and the
+        # (stag, run_start) meta gather collapse into one kernel emitting
+        # (stag_j, rpos) with no output-sized gathers.
+        vmeta = not carry and expand_impl.startswith("pallas-vmeta")
 
-    j32 = jnp.arange(out_capacity, dtype=jnp.int32)
-    valid_out = jnp.arange(out_capacity, dtype=jnp.int64) < total
+        j32 = jnp.arange(out_capacity, dtype=jnp.int32)
+        valid_out = jnp.arange(out_capacity, dtype=jnp.int64) < total
 
-    # One word gather resolves the per-slot metadata: (stag, run_start)
-    # as two packed int32. Carry mode widens the same gather with the
-    # sorted key + payload slots instead of issuing per-table gathers.
-    # The Pallas kernels gather the two int32 planes directly (Mosaic
-    # has no 64-bit types), so they skip the u64 packing entirely.
-    stag_j = rstart_j = rtag_direct = None
-    src = t = rpos_direct = None
-    lpay_planes = None
-    if vcarry:
-        pay_planes = []
-        for sl in sslots:
-            pay_planes.append(
-                jax.lax.bitcast_convert_type(
-                    (sl & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
+        # One word gather resolves the per-slot metadata: (stag, run_start)
+        # as two packed int32. Carry mode widens the same gather with the
+        # sorted key + payload slots instead of issuing per-table gathers.
+        # The Pallas kernels gather the two int32 planes directly (Mosaic
+        # has no 64-bit types), so they skip the u64 packing entirely.
+        stag_j = rstart_j = rtag_direct = None
+        src = t = rpos_direct = None
+        lpay_planes = None
+        if vcarry:
+            pay_planes = []
+            for sl in sslots:
+                pay_planes.append(
+                    jax.lax.bitcast_convert_type(
+                        (sl & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
+                        jnp.int32,
+                    )
+                )
+                pay_planes.append(
+                    jax.lax.bitcast_convert_type(
+                        (sl >> jnp.uint64(32)).astype(jnp.uint32), jnp.int32
+                    )
+                )
+            if vfull:
+                from .pallas_expand import expand_vfull
+
+                klo = jax.lax.bitcast_convert_type(
+                    (key_su64 & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
                     jnp.int32,
                 )
-            )
-            pay_planes.append(
-                jax.lax.bitcast_convert_type(
-                    (sl >> jnp.uint64(32)).astype(jnp.uint32), jnp.int32
+                khi = jax.lax.bitcast_convert_type(
+                    (key_su64 >> jnp.uint64(32)).astype(jnp.uint32), jnp.int32
                 )
-            )
-        if vfull:
-            from .pallas_expand import expand_vfull
-
-            klo = jax.lax.bitcast_convert_type(
-                (key_su64 & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
-                jnp.int32,
-            )
-            khi = jax.lax.bitcast_convert_type(
-                (key_su64 >> jnp.uint64(32)).astype(jnp.uint32), jnp.int32
-            )
-            vouts = expand_vfull(
-                csum, cnt, run_start, tuple(pay_planes), klo, khi,
-                _max_run(cnt, run_start, S), out_capacity,
-                interpret=interp,
-            )
-            np2 = len(pay_planes)
-            lpay_planes = vouts[:np2]
-            key_j_planes = vouts[np2 : np2 + 2]
-            rpay_planes = vouts[np2 + 2 :]
-        else:
-            from .pallas_expand import expand_carry
-
-            outs = expand_carry(
-                csum, cnt, run_start, tuple(pay_planes), out_capacity,
-                interpret=interp,
-            )
-            rpos_direct = outs[0]
-            lpay_planes = outs[1:]
-    elif vmeta:
-        from .pallas_expand import expand_values
-
-        stag_j, rpos_direct = expand_values(
-            csum, cnt, stag, run_start, out_capacity, interpret=interp
-        )
-    elif joinmode:
-        from .pallas_expand import expand_join
-
-        stag_j, rtag_direct = expand_join(
-            csum, stag, run_start, _max_run(cnt, run_start, S),
-            out_capacity, interpret=interp,
-        )
-    elif fused:
-        from .pallas_expand import expand_gather
-
-        src, stag_j, rstart_j = expand_gather(
-            csum, stag, run_start, out_capacity, interpret=interp
-        )
-        src = jnp.clip(src, 0, S - 1)
-    elif expand_impl.startswith("pallas"):
-        from .pallas_expand import expand_ranks
-
-        src = jnp.clip(
-            expand_ranks(csum, out_capacity, interpret=interp), 0, S - 1
-        )
-    else:
-        src = jnp.clip(count_leq_arange(csum, out_capacity), 0, S - 1)
-    if not joinmode and not vmeta and not vcarry:
-        # Which match within the run: output slots of one query are
-        # consecutive, so t = j - (first j with this src) — recovered
-        # from src's own run boundaries by one scan instead of
-        # gathering csum_ex.
-        t = j32 - jax.lax.cummax(jnp.where(_run_starts(src), j32, -1))
-
-    if carry:
-        meta = jax.lax.bitcast_convert_type(
-            jnp.stack([stag, run_start], axis=-1), jnp.uint64
-        )
-        packed = jnp.stack([meta, _to_u64(svals)] + spay, axis=-1)
-        rows = packed.at[src].get(mode="fill", fill_value=0)
-        m32 = jax.lax.bitcast_convert_type(rows[:, 0], jnp.int32)
-        stag_j, rstart_j = m32[:, 0], m32[:, 1]
-    elif not fused and not joinmode and not vmeta and not vcarry:
-        meta = jax.lax.bitcast_convert_type(
-            jnp.stack([stag, run_start], axis=-1), jnp.uint64
-        )
-        m32 = jax.lax.bitcast_convert_type(
-            meta.at[src].get(mode="fill", fill_value=0), jnp.int32
-        )
-        stag_j, rstart_j = m32[:, 0], m32[:, 1]
-    li = None if vcarry else jnp.where(valid_out, stag_j, L)
-    if joinmode or vfull:
-        rpos = None  # vfull resolved the right side in-kernel
-    elif vmeta or vcarry:
-        rpos = jnp.where(valid_out, rpos_direct, S)
-    else:
-        rpos = jnp.where(valid_out, rstart_j + t, S)
-
-    if vcarry:
-        # vcarry: ONE stacked gather at the matched refs' merged
-        # positions resolves the key AND every right payload (stacked
-        # multi-column gathers amortize the per-row latency — measured
-        # cheaper than two flats, ARCHITECTURE.md "gather economics");
-        # left payloads came out of the kernel. vfull: even that gather
-        # is gone — the kernel resolved key and right-payload planes at
-        # rpos via the margin eq-walk (expand_vfull).
-        if not vfull:
-            rstack = jnp.stack([key_su64] + list(sslots), axis=-1)
-            rrows = rstack.at[rpos].get(mode="fill", fill_value=0)
-        kcol = left.columns[left_on[0]]
-        # Pad with the unsigned-order image of 0 so invalid slots decode
-        # to 0 like every other mode (a raw-0 image would decode to the
-        # dtype minimum — an inconsistent padding convention).
-        kphys = jnp.dtype(kcol.dtype.physical)
-        kzero = (
-            jnp.uint64(1) << jnp.uint64(8 * kphys.itemsize - 1)
-            if jnp.issubdtype(kphys, jnp.signedinteger)
-            else jnp.uint64(0)
-        )
-        if vfull:
-            key_raw = _u64_from_planes(key_j_planes[0], key_j_planes[1])
-        else:
-            key_raw = rrows[:, 0]
-        key_bits = jnp.where(valid_out, key_raw, kzero)
-        left_out_v: dict[int, Column] = {
-            left_on[0]: Column(
-                _from_unsigned_order(key_bits, kcol.dtype.physical),
-                kcol.dtype,
-            )
-        }
-        for k, (ci, c) in enumerate(l_carry):
-            bits = _u64_from_planes(
-                lpay_planes[2 * k], lpay_planes[2 * k + 1]
-            )
-            bits = jnp.where(valid_out, bits, 0)
-            left_out_v[ci] = Column(
-                _from_u64(bits, c.dtype.physical), c.dtype
-            )
-        right_out_v: dict[int, Column] = {}
-        for k, (ci, c) in enumerate(r_fixed):
-            if vfull:
-                raw = _u64_from_planes(
-                    rpay_planes[2 * k], rpay_planes[2 * k + 1]
+                vouts = expand_vfull(
+                    csum, cnt, run_start, tuple(pay_planes), klo, khi,
+                    _max_run(cnt, run_start, S), out_capacity,
+                    interpret=interp,
                 )
+                np2 = len(pay_planes)
+                lpay_planes = vouts[:np2]
+                key_j_planes = vouts[np2 : np2 + 2]
+                rpay_planes = vouts[np2 + 2 :]
             else:
-                raw = rrows[:, 1 + k]
-            bits = jnp.where(valid_out, raw, 0)
-            right_out_v[ci] = Column(
-                _from_u64(bits, c.dtype.physical), c.dtype
+                from .pallas_expand import expand_carry
+
+                outs = expand_carry(
+                    csum, cnt, run_start, tuple(pay_planes), out_capacity,
+                    interpret=interp,
+                )
+                rpos_direct = outs[0]
+                lpay_planes = outs[1:]
+        elif vmeta:
+            from .pallas_expand import expand_values
+
+            stag_j, rpos_direct = expand_values(
+                csum, cnt, stag, run_start, out_capacity, interpret=interp
             )
-        out_cols_v: list = []
+        elif joinmode:
+            from .pallas_expand import expand_join
+
+            stag_j, rtag_direct = expand_join(
+                csum, stag, run_start, _max_run(cnt, run_start, S),
+                out_capacity, interpret=interp,
+            )
+        elif fused:
+            from .pallas_expand import expand_gather
+
+            src, stag_j, rstart_j = expand_gather(
+                csum, stag, run_start, out_capacity, interpret=interp
+            )
+            src = jnp.clip(src, 0, S - 1)
+        elif expand_impl.startswith("pallas"):
+            from .pallas_expand import expand_ranks
+
+            src = jnp.clip(
+                expand_ranks(csum, out_capacity, interpret=interp), 0, S - 1
+            )
+        else:
+            src = jnp.clip(count_leq_arange(csum, out_capacity), 0, S - 1)
+        if not joinmode and not vmeta and not vcarry:
+            # Which match within the run: output slots of one query are
+            # consecutive, so t = j - (first j with this src) — recovered
+            # from src's own run boundaries by one scan instead of
+            # gathering csum_ex.
+            t = j32 - jax.lax.cummax(jnp.where(_run_starts(src), j32, -1))
+
+        if carry:
+            meta = jax.lax.bitcast_convert_type(
+                jnp.stack([stag, run_start], axis=-1), jnp.uint64
+            )
+            packed = jnp.stack([meta, _to_u64(svals)] + spay, axis=-1)
+            rows = packed.at[src].get(mode="fill", fill_value=0)
+            m32 = jax.lax.bitcast_convert_type(rows[:, 0], jnp.int32)
+            stag_j, rstart_j = m32[:, 0], m32[:, 1]
+        elif not fused and not joinmode and not vmeta and not vcarry:
+            meta = jax.lax.bitcast_convert_type(
+                jnp.stack([stag, run_start], axis=-1), jnp.uint64
+            )
+            m32 = jax.lax.bitcast_convert_type(
+                meta.at[src].get(mode="fill", fill_value=0), jnp.int32
+            )
+            stag_j, rstart_j = m32[:, 0], m32[:, 1]
+    with annotate("dj_gather"):
+        li = None if vcarry else jnp.where(valid_out, stag_j, L)
+        if joinmode or vfull:
+            rpos = None  # vfull resolved the right side in-kernel
+        elif vmeta or vcarry:
+            rpos = jnp.where(valid_out, rpos_direct, S)
+        else:
+            rpos = jnp.where(valid_out, rstart_j + t, S)
+
+        if vcarry:
+            # vcarry: ONE stacked gather at the matched refs' merged
+            # positions resolves the key AND every right payload (stacked
+            # multi-column gathers amortize the per-row latency — measured
+            # cheaper than two flats, ARCHITECTURE.md "gather economics");
+            # left payloads came out of the kernel. vfull: even that gather
+            # is gone — the kernel resolved key and right-payload planes at
+            # rpos via the margin eq-walk (expand_vfull).
+            if not vfull:
+                rstack = jnp.stack([key_su64] + list(sslots), axis=-1)
+                rrows = rstack.at[rpos].get(mode="fill", fill_value=0)
+            kcol = left.columns[left_on[0]]
+            # Pad with the unsigned-order image of 0 so invalid slots decode
+            # to 0 like every other mode (a raw-0 image would decode to the
+            # dtype minimum — an inconsistent padding convention).
+            kphys = jnp.dtype(kcol.dtype.physical)
+            kzero = (
+                jnp.uint64(1) << jnp.uint64(8 * kphys.itemsize - 1)
+                if jnp.issubdtype(kphys, jnp.signedinteger)
+                else jnp.uint64(0)
+            )
+            if vfull:
+                key_raw = _u64_from_planes(key_j_planes[0], key_j_planes[1])
+            else:
+                key_raw = rrows[:, 0]
+            key_bits = jnp.where(valid_out, key_raw, kzero)
+            left_out_v: dict[int, Column] = {
+                left_on[0]: Column(
+                    _from_unsigned_order(key_bits, kcol.dtype.physical),
+                    kcol.dtype,
+                )
+            }
+            for k, (ci, c) in enumerate(l_carry):
+                bits = _u64_from_planes(
+                    lpay_planes[2 * k], lpay_planes[2 * k + 1]
+                )
+                bits = jnp.where(valid_out, bits, 0)
+                left_out_v[ci] = Column(
+                    _from_u64(bits, c.dtype.physical), c.dtype
+                )
+            right_out_v: dict[int, Column] = {}
+            for k, (ci, c) in enumerate(r_fixed):
+                if vfull:
+                    raw = _u64_from_planes(
+                        rpay_planes[2 * k], rpay_planes[2 * k + 1]
+                    )
+                else:
+                    raw = rrows[:, 1 + k]
+                bits = jnp.where(valid_out, raw, 0)
+                right_out_v[ci] = Column(
+                    _from_u64(bits, c.dtype.physical), c.dtype
+                )
+            out_cols_v: list = []
+            for i, c in enumerate(left.columns):
+                if i in l_drop:
+                    continue
+                out_cols_v.append(left_out_v[i])
+            for i, c in enumerate(right.columns):
+                if i in right_on_set:
+                    continue
+                out_cols_v.append(right_out_v[i])
+            count = jnp.minimum(total, out_capacity).astype(jnp.int32)
+            outv = Table(tuple(out_cols_v), count), total
+            # vcarry requires string-free tables; no collision possible.
+            flags_v = dict(no_collision, pack_range_overflow=pack_ovf)
+            return outv + (flags_v,) if return_flags else outv
+
+        out_cols: list[Optional[Column | StringColumn]] = []
+        left_out: dict[int, Column] = {}
+        right_out: dict[int, Column] = {}
+        li_str = li
+        rrow = None
+        if carry:
+            # Second gather of the SAME pack at the matched refs' merged
+            # positions: payload slots hold the right values there.
+            rrows = packed.at[rpos].get(mode="fill", fill_value=0)
+            key_bits = jnp.where(valid_out, rows[:, 1], 0)
+            kcol = left.columns[left_on[0]]
+            left_out[left_on[0]] = Column(
+                _from_u64(key_bits, kcol.dtype.physical), kcol.dtype
+            )
+            for k, (ci, c) in enumerate(l_carry):
+                bits = jnp.where(valid_out, rows[:, 2 + k], 0)
+                left_out[ci] = Column(_from_u64(bits, c.dtype.physical), c.dtype)
+            for k, (ci, c) in enumerate(r_fixed):
+                bits = jnp.where(valid_out, rrows[:, 2 + k], 0)
+                right_out[ci] = Column(_from_u64(bits, c.dtype.physical), c.dtype)
+            if has_strings:
+                rm32 = jax.lax.bitcast_convert_type(rrows[:, 0], jnp.int32)
+                rrow = jnp.where(valid_out, rm32[:, 0] - jnp.int32(L), R)
+        else:
+            # Right row id: the tag at the matched ref's merged position
+            # (already resolved in-kernel on the pallas-join path).
+            if joinmode:
+                rtag = rtag_direct
+            else:
+                rtag = stag.at[rpos].get(mode="fill", fill_value=L)
+            rrow = jnp.where(valid_out, rtag - jnp.int32(L), R)
+            # capacity-0 tables: gathers from a 0-row operand are
+            # structurally invalid in XLA; the join result is necessarily
+            # all-fill (total == 0), so emit zeros directly (cudf accepts
+            # empty tables: upstream src/distributed_join.cpp:76-82).
+            if l_fixed:
+                if L == 0:
+                    lrows = jnp.zeros((out_capacity, len(l_fixed)), jnp.uint64)
+                else:
+                    l_pack = jnp.stack(
+                        [_to_u64(c.data) for _, c in l_fixed], axis=-1
+                    )
+                    lrows = l_pack.at[li].get(mode="fill", fill_value=0)
+                for k, (ci, c) in enumerate(l_fixed):
+                    left_out[ci] = Column(
+                        _from_u64(lrows[:, k], c.dtype.physical), c.dtype
+                    )
+            if r_fixed:
+                if R == 0:
+                    rrows = jnp.zeros((out_capacity, len(r_fixed)), jnp.uint64)
+                else:
+                    r_pack = jnp.stack(
+                        [_to_u64(c.data) for _, c in r_fixed], axis=-1
+                    )
+                    rrows = r_pack.at[rrow].get(mode="fill", fill_value=0)
+                for k, (i, c) in enumerate(r_fixed):
+                    right_out[i] = Column(
+                        _from_u64(rrows[:, k], c.dtype.physical), c.dtype
+                    )
+
         for i, c in enumerate(left.columns):
             if i in l_drop:
                 continue
-            out_cols_v.append(left_out_v[i])
+            if isinstance(c, StringColumn):
+                # capacity-0 side: take() would gather from a 0-row offsets
+                # operand (structurally invalid in XLA, same as the fixed-
+                # column L==0/R==0 guards above); the join result is
+                # necessarily empty, so emit the all-fill column directly.
+                if L == 0:
+                    out_cols.append(_fill_column(c, out_capacity))
+                else:
+                    cap = max(1, int(c.chars.shape[0] * char_out_factor))
+                    out_cols.append(c.take(li_str, out_char_capacity=cap))
+            else:
+                out_cols.append(left_out[i])
         for i, c in enumerate(right.columns):
             if i in right_on_set:
                 continue
-            out_cols_v.append(right_out_v[i])
+            if isinstance(c, StringColumn):
+                if R == 0:
+                    out_cols.append(_fill_column(c, out_capacity))
+                else:
+                    cap = max(1, int(c.chars.shape[0] * char_out_factor))
+                    out_cols.append(c.take(rrow, out_char_capacity=cap))
+            else:
+                out_cols.append(right_out[i])
+
         count = jnp.minimum(total, out_capacity).astype(jnp.int32)
-        outv = Table(tuple(out_cols_v), count), total
-        # vcarry requires string-free tables; no collision possible.
-        flags_v = dict(no_collision, pack_range_overflow=pack_ovf)
-        return outv + (flags_v,) if return_flags else outv
-
-    out_cols: list[Optional[Column | StringColumn]] = []
-    left_out: dict[int, Column] = {}
-    right_out: dict[int, Column] = {}
-    li_str = li
-    rrow = None
-    if carry:
-        # Second gather of the SAME pack at the matched refs' merged
-        # positions: payload slots hold the right values there.
-        rrows = packed.at[rpos].get(mode="fill", fill_value=0)
-        key_bits = jnp.where(valid_out, rows[:, 1], 0)
-        kcol = left.columns[left_on[0]]
-        left_out[left_on[0]] = Column(
-            _from_u64(key_bits, kcol.dtype.physical), kcol.dtype
-        )
-        for k, (ci, c) in enumerate(l_carry):
-            bits = jnp.where(valid_out, rows[:, 2 + k], 0)
-            left_out[ci] = Column(_from_u64(bits, c.dtype.physical), c.dtype)
-        for k, (ci, c) in enumerate(r_fixed):
-            bits = jnp.where(valid_out, rrows[:, 2 + k], 0)
-            right_out[ci] = Column(_from_u64(bits, c.dtype.physical), c.dtype)
-        if has_strings:
-            rm32 = jax.lax.bitcast_convert_type(rrows[:, 0], jnp.int32)
-            rrow = jnp.where(valid_out, rm32[:, 0] - jnp.int32(L), R)
-    else:
-        # Right row id: the tag at the matched ref's merged position
-        # (already resolved in-kernel on the pallas-join path).
-        if joinmode:
-            rtag = rtag_direct
-        else:
-            rtag = stag.at[rpos].get(mode="fill", fill_value=L)
-        rrow = jnp.where(valid_out, rtag - jnp.int32(L), R)
-        # capacity-0 tables: gathers from a 0-row operand are
-        # structurally invalid in XLA; the join result is necessarily
-        # all-fill (total == 0), so emit zeros directly (cudf accepts
-        # empty tables, /root/reference/src/distributed_join.cpp:76-82).
-        if l_fixed:
-            if L == 0:
-                lrows = jnp.zeros((out_capacity, len(l_fixed)), jnp.uint64)
-            else:
-                l_pack = jnp.stack(
-                    [_to_u64(c.data) for _, c in l_fixed], axis=-1
-                )
-                lrows = l_pack.at[li].get(mode="fill", fill_value=0)
-            for k, (ci, c) in enumerate(l_fixed):
-                left_out[ci] = Column(
-                    _from_u64(lrows[:, k], c.dtype.physical), c.dtype
-                )
-        if r_fixed:
-            if R == 0:
-                rrows = jnp.zeros((out_capacity, len(r_fixed)), jnp.uint64)
-            else:
-                r_pack = jnp.stack(
-                    [_to_u64(c.data) for _, c in r_fixed], axis=-1
-                )
-                rrows = r_pack.at[rrow].get(mode="fill", fill_value=0)
-            for k, (i, c) in enumerate(r_fixed):
-                right_out[i] = Column(
-                    _from_u64(rrows[:, k], c.dtype.physical), c.dtype
-                )
-
-    for i, c in enumerate(left.columns):
-        if i in l_drop:
-            continue
-        if isinstance(c, StringColumn):
-            # capacity-0 side: take() would gather from a 0-row offsets
-            # operand (structurally invalid in XLA, same as the fixed-
-            # column L==0/R==0 guards above); the join result is
-            # necessarily empty, so emit the all-fill column directly.
-            if L == 0:
-                out_cols.append(_fill_column(c, out_capacity))
-            else:
-                cap = max(1, int(c.chars.shape[0] * char_out_factor))
-                out_cols.append(c.take(li_str, out_char_capacity=cap))
-        else:
-            out_cols.append(left_out[i])
-    for i, c in enumerate(right.columns):
-        if i in right_on_set:
-            continue
-        if isinstance(c, StringColumn):
-            if R == 0:
-                out_cols.append(_fill_column(c, out_capacity))
-            else:
-                cap = max(1, int(c.chars.shape[0] * char_out_factor))
-                out_cols.append(c.take(rrow, out_char_capacity=cap))
-        else:
-            out_cols.append(right_out[i])
-
-    count = jnp.minimum(total, out_capacity).astype(jnp.int32)
-    result = Table(tuple(out_cols), count), total
-    if not return_flags:
-        return result
-    flags = dict(no_collision, pack_range_overflow=pack_ovf)
-    if verify_strings:
-        # Window = exactly what the surrogate hashed (one shared
-        # constant): wider would flag documented prefix-equal matches,
-        # narrower would miss real collisions.
-        flags["surrogate_collision"] = _verify_string_pairs(
-            left, right, str_pairs, li_str, rrow,
-            hashing.SURROGATE_MAX_LEN,
-        )
-    return result + (flags,)
+        result = Table(tuple(out_cols), count), total
+        if not return_flags:
+            return result
+        flags = dict(no_collision, pack_range_overflow=pack_ovf)
+        if verify_strings:
+            # Window = exactly what the surrogate hashed (one shared
+            # constant): wider would flag documented prefix-equal matches,
+            # narrower would miss real collisions.
+            flags["surrogate_collision"] = _verify_string_pairs(
+                left, right, str_pairs, li_str, rrow,
+                hashing.SURROGATE_MAX_LEN,
+            )
+        return result + (flags,)
 
 
 # --- prepared build side ----------------------------------------------
@@ -2165,76 +2200,88 @@ def inner_join_prepared(
     )
     scans_impl, expand_impl = kplan.scans, kplan.expand
 
-    w_l, ok = _anchored_pack_word(left, left_on, plan, R)
-    ok = ok | (r_count == 0)  # an empty build side joins empty: never flag
-    flags = {"prepared_plan_mismatch": ~ok}
-
     word_bits = min(64, plan.rel_bits + plan.tag_bits)
     with_pallas_merge = merge_impl.startswith("pallas")
+    with annotate("dj_pack"):
+        w_l, ok = _anchored_pack_word(left, left_on, plan, R)
+        # An empty build side joins empty: never flag.
+        ok = ok | (r_count == 0)
+        flags = {"prepared_plan_mismatch": ~ok}
+        if not with_pallas_merge:
+            merged = jnp.concatenate([pwords, w_l])
     if with_pallas_merge:
         from .pallas_merge import merge_sorted_u64
 
         wl_sorted = _sort_packed(w_l, word_bits)
-        sp = merge_sorted_u64(
-            pwords, wl_sorted, interpret=merge_impl.endswith("-interpret")
-        )
+        with annotate("dj_sort"):
+            sp = merge_sorted_u64(
+                pwords, wl_sorted,
+                interpret=merge_impl.endswith("-interpret"),
+            )
     else:
-        sp = _sort_packed(jnp.concatenate([pwords, w_l]), word_bits)
+        sp = _sort_packed(merged, word_bits)
 
-    if scans_impl.startswith("pallas"):
-        from .pallas_scan import join_scans
+    with annotate("dj_scan"):
+        if scans_impl.startswith("pallas"):
+            from .pallas_scan import join_scans
 
-        stag, run_start, cnt, csum = join_scans(
-            sp, l_count, r_count,
-            tag_bits=plan.tag_bits, L=L, R=R,
-            interpret=scans_impl.endswith("-interpret"),
-        )
-    else:
-        stag = _decode_packed_tags(sp, plan.tag_bits, L, R)
-        run_start, cnt, csum = _match_scans_xla(
-            _run_starts(sp >> jnp.uint64(plan.tag_bits)),
-            stag, l_count, r_count, L, R,
-        )
-    total = jnp.sum(cnt.astype(jnp.int64))
-
-    interp = expand_impl.endswith("-interpret")
-    j32 = jnp.arange(out_capacity, dtype=jnp.int32)
-    valid_out = jnp.arange(out_capacity, dtype=jnp.int64) < total
-    if expand_impl.startswith("pallas-vmeta"):
-        from .pallas_expand import expand_values
-
-        stag_j, rpos_direct = expand_values(
-            csum, cnt, stag, run_start, out_capacity, interpret=interp
-        )
-        rpos = jnp.where(valid_out, rpos_direct, S)
-    else:
-        if expand_impl.startswith("pallas"):
-            from .pallas_expand import expand_ranks
-
-            src = jnp.clip(
-                expand_ranks(csum, out_capacity, interpret=interp), 0, S - 1
+            stag, run_start, cnt, csum = join_scans(
+                sp, l_count, r_count,
+                tag_bits=plan.tag_bits, L=L, R=R,
+                interpret=scans_impl.endswith("-interpret"),
             )
         else:
-            src = jnp.clip(count_leq_arange(csum, out_capacity), 0, S - 1)
-        t = j32 - jax.lax.cummax(jnp.where(_run_starts(src), j32, -1))
-        meta = jax.lax.bitcast_convert_type(
-            jnp.stack([stag, run_start], axis=-1), jnp.uint64
-        )
-        m32 = jax.lax.bitcast_convert_type(
-            meta.at[src].get(mode="fill", fill_value=0), jnp.int32
-        )
-        stag_j, rstart_j = m32[:, 0], m32[:, 1]
-        rpos = jnp.where(valid_out, rstart_j + t, S)
-    li = jnp.where(valid_out, stag_j, L)
-    # Matched ref's tag IS its sorted rank in the prepared payload
-    # table (prepare_packed_batch re-tagged by rank).
-    rtag = stag.at[rpos].get(mode="fill", fill_value=L)
-    rrow = jnp.where(valid_out, rtag - jnp.int32(L), R)
+            stag = _decode_packed_tags(sp, plan.tag_bits, L, R)
+            run_start, cnt, csum = _match_scans_xla(
+                _run_starts(sp >> jnp.uint64(plan.tag_bits)),
+                stag, l_count, r_count, L, R,
+            )
+        total = jnp.sum(cnt.astype(jnp.int64))
 
-    out_cols = _gather_prepared_output(
-        left, right_payload, li, rrow, L, R, out_capacity, char_out_factor
-    )
-    count = jnp.minimum(total, out_capacity).astype(jnp.int32)
+    interp = expand_impl.endswith("-interpret")
+    with annotate("dj_expand"):
+        j32 = jnp.arange(out_capacity, dtype=jnp.int32)
+        valid_out = jnp.arange(out_capacity, dtype=jnp.int64) < total
+        if expand_impl.startswith("pallas-vmeta"):
+            from .pallas_expand import expand_values
+
+            stag_j, rpos_direct = expand_values(
+                csum, cnt, stag, run_start, out_capacity, interpret=interp
+            )
+            rpos = jnp.where(valid_out, rpos_direct, S)
+        else:
+            if expand_impl.startswith("pallas"):
+                from .pallas_expand import expand_ranks
+
+                src = jnp.clip(
+                    expand_ranks(csum, out_capacity, interpret=interp),
+                    0, S - 1,
+                )
+            else:
+                src = jnp.clip(
+                    count_leq_arange(csum, out_capacity), 0, S - 1
+                )
+            t = j32 - jax.lax.cummax(jnp.where(_run_starts(src), j32, -1))
+            meta = jax.lax.bitcast_convert_type(
+                jnp.stack([stag, run_start], axis=-1), jnp.uint64
+            )
+            m32 = jax.lax.bitcast_convert_type(
+                meta.at[src].get(mode="fill", fill_value=0), jnp.int32
+            )
+            stag_j, rstart_j = m32[:, 0], m32[:, 1]
+            rpos = jnp.where(valid_out, rstart_j + t, S)
+    with annotate("dj_gather"):
+        li = jnp.where(valid_out, stag_j, L)
+        # Matched ref's tag IS its sorted rank in the prepared payload
+        # table (prepare_packed_batch re-tagged by rank).
+        rtag = stag.at[rpos].get(mode="fill", fill_value=L)
+        rrow = jnp.where(valid_out, rtag - jnp.int32(L), R)
+
+        out_cols = _gather_prepared_output(
+            left, right_payload, li, rrow, L, R, out_capacity,
+            char_out_factor,
+        )
+        count = jnp.minimum(total, out_capacity).astype(jnp.int32)
     return Table(tuple(out_cols), count), total, flags
 
 
@@ -2389,104 +2436,111 @@ def inner_join_probe(
         ),
     )
 
-    w_l, ok = _anchored_pack_word(left, left_on, plan, R)
-    ok = ok | (r_count == 0)  # an empty build side joins empty: never flag
-    flags = {"prepared_plan_mismatch": ~ok}
+    with annotate("dj_pack"):
+        w_l, ok = _anchored_pack_word(left, left_on, plan, R)
+        # An empty build side joins empty: never flag.
+        ok = ok | (r_count == 0)
+        flags = {"prepared_plan_mismatch": ~ok}
 
-    tb = jnp.uint64(plan.tag_bits)
-    if R == 0 or L == 0:
-        # A capacity-0 side joins empty, and the search/gather operands
-        # would be structurally invalid — synthesize the empty result.
-        cnt = jnp.zeros((max(L, 1),), jnp.int32)[:L]
-        lo = jnp.zeros((max(L, 1),), jnp.int32)[:L]
-    else:
-        # Key fields only: valid packed words sit strictly below the
-        # all-ones sentinel (plan_prepared_pack judges fit on the FULL
-        # canonical spans), so a valid query key can never reach the
-        # run's sentinel tail, and a padding query (sentinel field)
-        # would — its count is masked by l_count below.
-        lo, hi = run_bounds(pwords >> tb, w_l >> tb)
-        hi = jnp.minimum(hi, r_count.astype(jnp.int32))  # belt: the
-        # valid run prefix is all a match may come from
-        cnt = jnp.where(
-            jnp.arange(L, dtype=jnp.int32) < l_count,
-            jnp.maximum(hi - lo, 0),
-            0,
-        ).astype(jnp.int32)
-    # int32 cumsum: exact while total < 2^31; beyond, the expansion is
-    # wrapped garbage the join-overflow flag (exact int64 total below)
-    # already condemns — the same contract as every other tier.
-    csum = jnp.cumsum(cnt)
-    total = jnp.sum(cnt.astype(jnp.int64))
-
-    j32 = jnp.arange(out_capacity, dtype=jnp.int32)
-    valid_out = jnp.arange(out_capacity, dtype=jnp.int64) < total
-    interp = kplan.expand.endswith("-interpret")
-    probe_expand = resolve_probe_expand()
-    if probe_expand != "hist":
-        # Deterministic fault site for the segment/pallas expansion
-        # (resilience.faults): a trace-time failure pins
-        # DJ_PROBE_EXPAND=hist and retries (errors._SITE_TIER).
-        faults.check("probe_expand")
-    if L == 0 or R == 0:
-        src = jnp.zeros((out_capacity,), jnp.int32)
-        t = j32
-    elif probe_expand.startswith("pallas"):
-        from .pallas_expand import expand_values
-
-        # The fused offsets kernel: with stag = row ids and
-        # run_start = 0, expand_values' (stag_j, rpos) outputs ARE
-        # (src, t) — src and the segment offset in one merge-path
-        # pass, falling back to the exact XLA formulation under its
-        # own lax.cond on window overflow.
-        src, t = expand_values(
-            csum, cnt,
-            jnp.arange(L, dtype=jnp.int32),
-            jnp.zeros((L,), jnp.int32),
-            out_capacity,
-            interpret=probe_expand.endswith("-interpret"),
-        )
-        src = jnp.clip(src, 0, L - 1)
-    else:
-        if kplan.expand.startswith("pallas"):
-            from .pallas_expand import expand_ranks
-
-            src = jnp.clip(
-                expand_ranks(csum, out_capacity, interpret=interp),
-                0, L - 1,
-            )
-        elif probe_expand == "segment":
-            src = jnp.clip(
-                segment_index_arange(csum, out_capacity), 0, L - 1
-            )
+    # The run's binary search yields each row's match range: it
+    # stands in for the sort and the scans (no sort exists here).
+    with annotate("dj_scan"):
+        tb = jnp.uint64(plan.tag_bits)
+        if R == 0 or L == 0:
+            # A capacity-0 side joins empty, and the search/gather operands
+            # would be structurally invalid — synthesize the empty result.
+            cnt = jnp.zeros((max(L, 1),), jnp.int32)[:L]
+            lo = jnp.zeros((max(L, 1),), jnp.int32)[:L]
         else:
-            src = jnp.clip(_count_leq(csum, out_capacity), 0, L - 1)
-        if probe_expand == "segment":
-            # Which match within the query's run of output slots: the
-            # run's first slot IS the row's exclusive offset, one
-            # gather of starts = csum - cnt at src.
-            t = j32 - (csum - cnt).at[src].get(
-                mode="fill", fill_value=0
-            )
-        else:
-            # Legacy chain: t = j - (first j with this src).
-            t = j32 - jax.lax.cummax(
-                jnp.where(_run_starts(src), j32, -1)
-            )
-    li = jnp.where(valid_out, src, L)
-    if R == 0 or L == 0:
-        rrow = jnp.full((out_capacity,), R, jnp.int32)
-    else:
-        # lo[src] + t IS the matched ref's sorted rank in the resident
-        # payload table — no merged positions, no rpos gather chain.
-        rrow = jnp.where(
-            valid_out,
-            lo.at[src].get(mode="fill", fill_value=0) + t,
-            R,
-        )
+            # Key fields only: valid packed words sit strictly below the
+            # all-ones sentinel (plan_prepared_pack judges fit on the FULL
+            # canonical spans), so a valid query key can never reach the
+            # run's sentinel tail, and a padding query (sentinel field)
+            # would — its count is masked by l_count below.
+            lo, hi = run_bounds(pwords >> tb, w_l >> tb)
+            hi = jnp.minimum(hi, r_count.astype(jnp.int32))  # belt: the
+            # valid run prefix is all a match may come from
+            cnt = jnp.where(
+                jnp.arange(L, dtype=jnp.int32) < l_count,
+                jnp.maximum(hi - lo, 0),
+                0,
+            ).astype(jnp.int32)
+        # int32 cumsum: exact while total < 2^31; beyond, the expansion is
+        # wrapped garbage the join-overflow flag (exact int64 total below)
+        # already condemns — the same contract as every other tier.
+        csum = jnp.cumsum(cnt)
+        total = jnp.sum(cnt.astype(jnp.int64))
 
-    out_cols = _gather_prepared_output(
-        left, right_payload, li, rrow, L, R, out_capacity, char_out_factor
-    )
-    count = jnp.minimum(total, out_capacity).astype(jnp.int32)
+    with annotate("dj_expand"):
+        j32 = jnp.arange(out_capacity, dtype=jnp.int32)
+        valid_out = jnp.arange(out_capacity, dtype=jnp.int64) < total
+        interp = kplan.expand.endswith("-interpret")
+        probe_expand = resolve_probe_expand()
+        if probe_expand != "hist":
+            # Deterministic fault site for the segment/pallas expansion
+            # (resilience.faults): a trace-time failure pins
+            # DJ_PROBE_EXPAND=hist and retries (errors._SITE_TIER).
+            faults.check("probe_expand")
+        if L == 0 or R == 0:
+            src = jnp.zeros((out_capacity,), jnp.int32)
+            t = j32
+        elif probe_expand.startswith("pallas"):
+            from .pallas_expand import expand_values
+
+            # The fused offsets kernel: with stag = row ids and
+            # run_start = 0, expand_values' (stag_j, rpos) outputs ARE
+            # (src, t) — src and the segment offset in one merge-path
+            # pass, falling back to the exact XLA formulation under its
+            # own lax.cond on window overflow.
+            src, t = expand_values(
+                csum, cnt,
+                jnp.arange(L, dtype=jnp.int32),
+                jnp.zeros((L,), jnp.int32),
+                out_capacity,
+                interpret=probe_expand.endswith("-interpret"),
+            )
+            src = jnp.clip(src, 0, L - 1)
+        else:
+            if kplan.expand.startswith("pallas"):
+                from .pallas_expand import expand_ranks
+
+                src = jnp.clip(
+                    expand_ranks(csum, out_capacity, interpret=interp),
+                    0, L - 1,
+                )
+            elif probe_expand == "segment":
+                src = jnp.clip(
+                    segment_index_arange(csum, out_capacity), 0, L - 1
+                )
+            else:
+                src = jnp.clip(_count_leq(csum, out_capacity), 0, L - 1)
+            if probe_expand == "segment":
+                # Which match within the query's run of output slots: the
+                # run's first slot IS the row's exclusive offset, one
+                # gather of starts = csum - cnt at src.
+                t = j32 - (csum - cnt).at[src].get(
+                    mode="fill", fill_value=0
+                )
+            else:
+                # Legacy chain: t = j - (first j with this src).
+                t = j32 - jax.lax.cummax(
+                    jnp.where(_run_starts(src), j32, -1)
+                )
+    with annotate("dj_gather"):
+        li = jnp.where(valid_out, src, L)
+        if R == 0 or L == 0:
+            rrow = jnp.full((out_capacity,), R, jnp.int32)
+        else:
+            # lo[src] + t IS the matched ref's sorted rank in the resident
+            # payload table — no merged positions, no rpos gather chain.
+            rrow = jnp.where(
+                valid_out,
+                lo.at[src].get(mode="fill", fill_value=0) + t,
+                R,
+            )
+
+        out_cols = _gather_prepared_output(
+            left, right_payload, li, rrow, L, R, out_capacity, char_out_factor
+        )
+        count = jnp.minimum(total, out_capacity).astype(jnp.int32)
     return Table(tuple(out_cols), count), total, flags

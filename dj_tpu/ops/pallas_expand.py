@@ -79,6 +79,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.timing import annotate
+
 
 # Tile geometry. T_J output slots per program; SPAN window entries
 # resident per program; BLK entries per compare block; LANE j's per
@@ -104,6 +106,19 @@ LANE = 128
 # row-exact gate (the MXU default-precision lesson: interpret mode can
 # never catch a precision break).
 DEFAULT_PRECISION = "highest"
+
+
+def _fallback(xla_path):
+    """The XLA branch of a kernel's fits ``lax.cond``, under the device
+    scope ``dj_expand_fallback``: a profiler trace then shows which
+    branch ran."""
+
+    @functools.wraps(xla_path)
+    def run(operand):
+        with annotate("dj_expand_fallback"):
+            return xla_path(operand)
+
+    return run
 
 
 def _make_kernel(
@@ -425,6 +440,7 @@ def _run_pallas(
         else out_shape,
         grid_spec=grid_spec,
         interpret=interpret,
+        name=f"expand_{mode}",
     )(starts, *arrays_padded)
 
 
@@ -498,6 +514,7 @@ def _expand_ranks_jit(csum, n_out, t_j, span, blk, lane, interpret):
         )
         return out[:n_out]
 
+    @_fallback
     def xla_path(_):
         return count_leq_arange(csum, n_out)
 
@@ -566,6 +583,7 @@ def _expand_gather_jit(
         )
         return src[:n_out], lo[:n_out], hi[:n_out]
 
+    @_fallback
     def xla_path(_):
         src = count_leq_arange(csum, n_out)
         clipped = jnp.clip(src, 0, S - 1)
@@ -833,6 +851,7 @@ def _run_vexpand(
         out_shape=tuple([out_shape] * n_val),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="vexpand",
     )(starts, *arrays)
     return tuple(o[:n_out] for o in outs)
 
@@ -908,6 +927,7 @@ def _expand_values_jit(
         )
         return stag_j, rpos
 
+    @_fallback
     def xla_path(_):
         src = jnp.clip(count_leq_arange(csum32, n_out), 0, S - 1)
         stag_j = stag.at[src].get(mode="fill", fill_value=0)
@@ -990,6 +1010,7 @@ def _expand_carry_jit(
             starts, t_j, span, blk, lane, precision, interpret,
         )
 
+    @_fallback
     def xla_path(_):
         src = jnp.clip(count_leq_arange(csum32, n_out), 0, S - 1)
         rstart_j = run_start.at[src].get(mode="fill", fill_value=0)
@@ -1352,9 +1373,11 @@ def _expand_vfull_jit(
             out_shape=tuple([out_shape] * n_outs),
             grid_spec=grid_spec,
             interpret=interpret,
+            name="expand_vfull",
         )(starts, *arrays)
         return tuple(o[:n_out] for o in outs)
 
+    @_fallback
     def xla_path(_):
         src = jnp.clip(count_leq_arange(csum32, n_out), 0, S - 1)
         rstart_j = run_start.at[src].get(mode="fill", fill_value=0)
@@ -1448,6 +1471,7 @@ def _expand_join_jit(
         )
         return stag_j[:n_out], rtag[:n_out]
 
+    @_fallback
     def xla_path(_):
         src = jnp.clip(count_leq_arange(csum, n_out), 0, S - 1)
         j32 = jnp.arange(n_out, dtype=jnp.int32)

@@ -306,6 +306,7 @@ def _merge_jit(a, b, tile, interpret):
         out_shape=(out, out),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="merge_path",
     )(splits, e, a_hi, a_hi, a_lo, a_lo, b_hi, b_hi, b_lo, b_lo)
     merged = out_hi.astype(jnp.uint64) << jnp.uint64(32) | out_lo.astype(
         jnp.uint64

@@ -291,5 +291,6 @@ def _join_scans_jit(sp, l_count, r_count, tag_bits, L, R, tile, interpret):
         out_shape=(out, out, out, out),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="join_scans",
     )(counts, hi, lo)
     return stag[:S], rstart[:S], cnt[:S], csum[:S]

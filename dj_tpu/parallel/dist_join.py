@@ -505,27 +505,16 @@ def distributed_inner_join(
         acct_key = (
             (kind,) + build_args + (_table_sig(left), _table_sig(right))
         )
-        t0 = time.perf_counter()
-        # The dispatch phase's roofline is the WIRE model: the module's
-        # memoized per-shard send bytes vs DJ_PEAK_WIRE_GBPS (resolved
-        # AT EXIT — a first trace populates the memo inside the body).
-        with obs_roofline.phase(
-            "dispatch", stage="join", kind="wire",
-            bytes_fn=lambda: obs.epoch_total_bytes(acct_key),
-        ):
+        # Dispatch wall (host-side, dj_phase_seconds{phase="dispatch"}):
+        # trace+compile on a cache miss, async dispatch on a hit — NOT
+        # device time, so it carries no roofline fraction (the device
+        # time lives in profiler traces). A serving loop whose dispatch
+        # tail jumps into the compile band is retracing.
+        with obs_roofline.phase("dispatch", stage="join"):
             out, out_counts, flag_mat = _run_accounted(
                 acct_key, run, left, left_counts, right, right_counts,
             )
         obs.inc("dj_join_queries_total", path="unprepared")
-        # Dispatch wall (host-side): covers trace+compile on a cache
-        # miss, async dispatch on a hit — NOT device time (that lives
-        # in profiler traces). The histogram's value is the tail shape:
-        # a serving loop whose p99 jumps from the dispatch band into
-        # the compile band is retracing.
-        obs.observe(
-            "dj_query_dispatch_seconds", time.perf_counter() - t0,
-            path="unprepared",
-        )
         # Overflow/collision entries keep their bool contract; stat
         # entries are float.
         info = {
@@ -2818,19 +2807,11 @@ def _distributed_inner_join_prepared(
             ("prepared_query", prepared.tier) + build_args
             + (_table_sig(left),)
         )
-        t0 = time.perf_counter()
-        with obs_roofline.phase(
-            "dispatch", stage="prepared_query", kind="wire",
-            bytes_fn=lambda: obs.epoch_total_bytes(acct_key),
-        ):
+        with obs_roofline.phase("dispatch", stage="prepared_query"):
             out, out_counts, flag_mat = _run_accounted(
                 acct_key, run, left, left_counts, prepared.batches,
             )
         obs.inc("dj_join_queries_total", path="prepared")
-        obs.observe(
-            "dj_query_dispatch_seconds", time.perf_counter() - t0,
-            path="prepared",
-        )
         info = {
             k: (
                 (flag_mat[:, i] != 0)
@@ -3422,20 +3403,12 @@ def distributed_inner_join_coalesced(
         acct_key = (
             ("coalesced_query", prepared.tier) + build_args + (sig0,)
         )
-        t0 = time.perf_counter()
-        with obs_roofline.phase(
-            "dispatch", stage="coalesced_query", kind="wire",
-            bytes_fn=lambda: obs.epoch_total_bytes(acct_key),
-        ):
+        with obs_roofline.phase("dispatch", stage="coalesced_query"):
             outs, counts, flag_mats = _run_accounted(
                 acct_key, run, tuple(lefts), tuple(left_counts),
                 prepared.batches,
             )
         obs.inc("dj_join_queries_total", k_queries, path="coalesced")
-        obs.observe(
-            "dj_query_dispatch_seconds", time.perf_counter() - t0,
-            path="coalesced",
-        )
         keys = _prepared_flag_keys(cfg)
         per_query = []
         for q in range(k_queries):
@@ -3759,21 +3732,13 @@ def distributed_inner_join_coalesced_unprepared(
         with obs_roofline.phase("build", stage="coalesced_join"):
             run = _cached_build(_build_coalesced_join_fn, *build_args)
         acct_key = ("coalesced_join",) + build_args + (sig_l, sig_r)
-        t0 = time.perf_counter()
-        with obs_roofline.phase(
-            "dispatch", stage="coalesced_join", kind="wire",
-            bytes_fn=lambda: obs.epoch_total_bytes(acct_key),
-        ):
+        with obs_roofline.phase("dispatch", stage="coalesced_join"):
             outs, counts, flag_mats = _run_accounted(
                 acct_key, run, tuple(lefts), tuple(left_counts),
                 tuple(rights), tuple(right_counts),
             )
         obs.inc(
             "dj_join_queries_total", k_queries, path="coalesced_unprepared"
-        )
-        obs.observe(
-            "dj_query_dispatch_seconds", time.perf_counter() - t0,
-            path="coalesced_unprepared",
         )
         keys = _flag_keys(cfg)
         per_query = []

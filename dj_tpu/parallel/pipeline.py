@@ -79,7 +79,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 from typing import Optional, Sequence
 
 import jax
@@ -581,19 +580,11 @@ def _dispatch_stage(
             (kind,) + base_args
             + (dj._table_sig(cur), dj._table_sig(sp.right))
         )
-        t0 = time.perf_counter()
-        with obs_roofline.phase(
-            "dispatch", stage=stage_tag, kind="wire",
-            bytes_fn=lambda: obs.epoch_total_bytes(acct_key),
-        ):
+        with obs_roofline.phase("dispatch", stage=stage_tag):
             out, out_counts, flag_mat = dj._run_accounted(
                 acct_key, run, cur, cur_counts,
                 sp.right, sp.right_counts,
             )
-        obs.observe(
-            "dj_query_dispatch_seconds", time.perf_counter() - t0,
-            path="pipeline",
-        )
         obs.inc("dj_pipeline_stage_total", mode=mode)
         obs.record(
             "pipeline",

@@ -118,6 +118,7 @@ from ..resilience.errors import (
     Draining,
     QueueFull,
 )
+from ..utils.timing import host_span
 from . import admission
 
 # Live schedulers, so the test fixture (and an operator's "drain
@@ -608,9 +609,11 @@ class QueryScheduler:
         admission decision, a door reject — lands on the query's
         timeline; a door reject closes the trace (the raised error
         carries ``.query_id``) and an admitted query's trace stays
-        open until its terminal transition."""
+        open until its terminal transition. On the profiler's clock the
+        submit is the host span ``dj.submit``, on the caller's
+        thread."""
         query_id = _mint_query_id()
-        with trace.query_ctx(query_id, tenant):
+        with host_span("submit"), trace.query_ctx(query_id, tenant):
             trace.span_begin("query")
             try:
                 ticket = self._admit(
@@ -980,7 +983,7 @@ class QueryScheduler:
         stage rights that should be resident are passed as
         PreparedSides in their JoinStage."""
         query_id = _mint_query_id()
-        with trace.query_ctx(query_id, tenant):
+        with host_span("submit"), trace.query_ctx(query_id, tenant):
             trace.span_begin("query")
             try:
                 ticket = self._admit_pipeline(
@@ -1452,13 +1455,15 @@ class QueryScheduler:
         )
 
     def _execute(self, group: list) -> None:
-        """Run one dispatched group to terminal states. Exceptions map
-        to typed DJErrors on the affected tickets; nothing escapes."""
+        """Run one dispatched group to terminal states, inside the host
+        span ``dj.run`` (the dispatching thread). Exceptions map to
+        typed DJErrors on the affected tickets; nothing escapes."""
         try:
-            if len(group) > 1:
-                self._execute_coalesced(group)
-            else:
-                self._execute_single(group[0])
+            with host_span("run"):
+                if len(group) > 1:
+                    self._execute_coalesced(group)
+                else:
+                    self._execute_single(group[0])
         except Exception as e:  # noqa: BLE001 - terminal-state guarantee
             err = self._typed(e)
             for t in group:

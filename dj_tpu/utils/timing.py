@@ -17,6 +17,22 @@ TPU-native equivalents here:
    visible in profiler traces — PhaseTimer times what the host can see
    (generation, compile, per-step dispatch+sync), which is also exactly
    what drivers report.
+
+Naming rule for what a profiler trace shows of the program:
+
+- device scopes are ``dj_<phase>`` (``annotate``, entered while a
+  module traces): the pipeline's ``dj_partition`` / ``dj_exchange`` /
+  ``dj_join`` / ``dj_concat``, and inside the local join ``dj_pack``,
+  ``dj_sort``, ``dj_scan``, ``dj_expand`` (with ``dj_expand_fallback``
+  on the XLA branch of an expansion kernel's fits check) and
+  ``dj_gather``. They land in the ops' HLO metadata and change nothing
+  the compiled program does.
+- host spans are ``dj.<stage>`` (``host_span``, entered at run time):
+  ``obs.roofline.phase``'s ``probe`` / ``build`` / ``dispatch`` /
+  ``sync`` / ``prep``, and the scheduler's ``submit`` (caller's thread)
+  and ``run`` (worker thread). They are profiler events only, never
+  name scopes, so a module traced inside ``dj.build`` carries no host
+  name in its metadata.
 """
 
 from __future__ import annotations
@@ -43,6 +59,15 @@ def annotate(name: str) -> Iterator[None]:
 
     with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
         yield
+
+
+def host_span(stage: str):
+    """The host span ``dj.<stage>`` on the profiler's clock (a
+    ``jax.profiler.TraceAnnotation``; about a microsecond when no
+    profiler runs)."""
+    import jax.profiler
+
+    return jax.profiler.TraceAnnotation(f"dj.{stage}")
 
 
 @contextlib.contextmanager

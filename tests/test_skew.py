@@ -564,8 +564,9 @@ def test_served_query_trace_has_phases_and_skew(obs_capture, monkeypatch):
     assert {"probe", "build", "dispatch", "sync", "run"} <= names, names
     assert all("roofline_frac" in e for e in phases)
     priced = [e for e in phases if e["roofline_frac"] is not None]
-    assert priced, "at least dispatch/run must carry a priced fraction"
-    assert any(e["kind"] == "wire" for e in priced)  # dispatch
+    assert priced, "at least run must carry a priced fraction"
+    # Dispatch returns before the device moves a byte: never priced.
+    assert all(e["phase"] != "dispatch" for e in priced)
     assert any(e["kind"] == "hbm" for e in priced)  # run
     # One skew event per odf batch, vector over the 8 destination
     # shards, totals covering every valid probe row.
