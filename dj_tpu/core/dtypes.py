@@ -36,6 +36,10 @@ class DType:
     name: str
     physical: Any
     kind: str
+    # Strings only: the most bytes a row holds (SQL CHAR(n)/VARCHAR(n)),
+    # 0 for unbounded. A static promise about the data, which string
+    # ops may plan by.
+    max_len: int = 0
 
     @property
     def itemsize(self) -> int:
@@ -80,6 +84,13 @@ _BY_NAME = {
         string,
     ]
 }
+
+
+def char(max_len: int) -> DType:
+    """A string column whose rows hold at most ``max_len`` bytes, as SQL
+    CHAR(n)/VARCHAR(n) declares (TPC-H's o_orderpriority is CHAR(15))."""
+    assert max_len >= 1, max_len
+    return DType(f"char{max_len}", np.uint8, "string", max_len)
 
 
 def by_name(name: str) -> DType:

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.timing import annotate
 from . import dtypes as dt
 from .search import interval_of_arange as _interval_of_arange
 
@@ -85,27 +86,34 @@ class StringColumn:
         duplicates rows the needed bytes can exceed it — pass a larger
         ``out_char_capacity``. Overflow is detectable: the returned
         offsets stay true, so ``offsets[-1] > chars.shape[0]`` signals
-        truncated chars.
+        truncated chars. Out-of-range indices give empty rows.
+
+        The chars move row by row, never byte by byte (``_take_chars``):
+        the temporaries are a few row-length vectors and one loop step's
+        buffers, not vectors as long as the output char capacity. A
+        ``char(n)`` column, n <= PIECE_BYTES, moves each row as one
+        piece; if a row of it is longer than n, the output's offsets
+        claim more than its chars hold, so it reads ``char_overflow``.
+        Runs under the device scope ``dj_strings``.
         """
-        sizes = self.sizes().at[indices].get(mode="fill", fill_value=0)
-        new_offsets = sizes_to_offsets(sizes)
-        starts = self.offsets.at[indices].get(mode="fill", fill_value=0)
-        # For each output byte position, find which output row it belongs to
-        # and its byte offset within the row, then read the source byte.
         cap = (
             self.chars.shape[0]
             if out_char_capacity is None
             else out_char_capacity
         )
-        pos = jnp.arange(cap, dtype=jnp.int32)
-        row = _interval_of_arange(new_offsets, cap, indices.shape[0])
-        within = pos - new_offsets[row]
-        src = starts[row] + within
-        valid = pos < new_offsets[-1]
-        chars = jnp.where(
-            valid, self.chars.at[src].get(mode="fill", fill_value=0), 0
-        ).astype(jnp.uint8)
-        return StringColumn(new_offsets, chars)
+        with annotate("dj_strings"):
+            sizes = self.sizes().at[indices].get(mode="fill", fill_value=0)
+            new_offsets = sizes_to_offsets(sizes)
+            whole = 0 < self.dtype.max_len <= PIECE_BYTES
+            chars = _take_chars(self.chars, self.offsets, indices,
+                                new_offsets, cap, whole)
+            if whole and self.size:
+                # A row longer than its type allows does not move whole:
+                # the last offset then claims more than the chars hold.
+                long = jnp.max(self.sizes()) > self.dtype.max_len
+                new_offsets = new_offsets.at[-1].max(
+                    jnp.where(long, cap + 1, 0))
+        return StringColumn(new_offsets, chars, self.dtype)
 
     def char_overflow(self) -> jax.Array:
         """True if the offsets claim more bytes than chars can hold
@@ -114,6 +122,233 @@ class StringColumn:
 
 
 AnyColumn = Column | StringColumn
+
+# StringColumn.take moves each row as pieces of at most PIECE_BYTES
+# bytes, and reads the source at random by piece, never by byte: the
+# source's pieces are unpacked into the rows of a [pieces, _WORDS]
+# uint32 table, one row gather fetches an output piece whole, and a
+# shift network packs a loop step's pieces back to back. (On a TPU v5e
+# such a row gather costs about 12 ns, a windowed gather of the same 16
+# bytes about a microsecond.) A row of a column typed ``char(n)``,
+# n <= PIECE_BYTES, is one piece.
+PIECE_BYTES = 16
+_WORDS = PIECE_BYTES // 4
+# Pieces per loop step: a step's buffers hold PIECE_BYTES x this many
+# bytes, whatever the output's char capacity.
+_TAKE_PIECES = 1 << 16
+
+
+class _Pieces:
+    """The pieces of a string column's rows (``offsets``), walked K at
+    a time. With ``whole_rows`` row r is piece r; else row r's bytes are
+    cut every PIECE_BYTES into pieces ``first[r]``, ``first[r] + 1``,
+    ..., numbered by a histogram over ``P``, a bound on the pieces that
+    start below ``limit`` bytes (each row has at most one piece shorter
+    than PIECE_BYTES). Only pieces starting below ``min(total, limit)``
+    are walked: a prefix."""
+
+    def __init__(self, offsets: jax.Array, limit: int, whole_rows: bool):
+        n = offsets.shape[0] - 1
+        self.offsets, self.whole = offsets, whole_rows
+        end = jnp.minimum(offsets[-1], limit)
+        rows = jnp.sum(offsets[:-1] < end, dtype=jnp.int32)
+        if whole_rows:
+            self.P, self.count = n, rows
+        else:
+            per_row = (jnp.diff(offsets) + PIECE_BYTES - 1) // PIECE_BYTES
+            self.first = sizes_to_offsets(per_row)
+            self.P = n + limit // PIECE_BYTES + 1
+            self.row_of = _interval_of_arange(self.first, self.P, n)
+            self.count = self.first[rows]
+        self.K = min(_TAKE_PIECES, self.P)
+
+    def steps(self) -> jax.Array:
+        return (self.count + self.K - 1) // self.K
+
+    def step(self, c):
+        """(first piece, rows, index within the row, byte offsets,
+        lengths) of step ``c``'s K pieces. The last step is clamped to
+        end at P: it repeats pieces an earlier step had, which every
+        caller writes again identically."""
+        K = self.K
+        p0 = jnp.minimum(c * K, self.P - K)
+        if self.whole:
+            o = jax.lax.dynamic_slice_in_dim(self.offsets, p0, K + 1)
+            r = p0 + jnp.arange(K, dtype=jnp.int32)
+            return p0, r, jnp.zeros_like(r), o[:-1], jnp.diff(o)
+        r = jax.lax.dynamic_slice_in_dim(self.row_of, p0, K)
+        k = p0 + jnp.arange(K, dtype=jnp.int32) - self.first[r]
+        at = self.offsets[r] + PIECE_BYTES * k
+        ln = jnp.clip(self.offsets[r + 1] - at, 0, PIECE_BYTES)
+        return p0, r, k, at, ln
+
+
+def _take_chars(
+    chars: jax.Array,
+    src_offsets: jax.Array,
+    indices: jax.Array,
+    offsets: jax.Array,
+    cap: int,
+    whole_rows: bool,
+) -> jax.Array:
+    """Output chars ``[cap]`` of a take: output row r holds the bytes
+    of source row ``indices[r]`` (``chars`` at ``src_offsets``) at
+    ``offsets[r]``; its size is ``offsets[r+1] - offsets[r]``, 0 for an
+    index out of range.
+
+    The source's pieces are unpacked into a word table (``_unpack``).
+    A ``fori_loop`` then walks the output's pieces, K a step: one row
+    gather of the table fetches them, ``_pack_pieces`` packs them and
+    ``_place`` writes them at the step's first offset. Steps run in
+    order and each writes zeros past its last byte, where the next
+    step's write lands."""
+    # zeros_like keeps the operand's varying mesh axes inside shard_map,
+    # which a loop's carry must match.
+    out = jnp.zeros_like(chars, shape=(cap,))
+    if indices.shape[0] == 0 or src_offsets.shape[0] < 2 or cap == 0:
+        return out
+    src = _Pieces(src_offsets, chars.shape[0], whole_rows)
+    words = _unpack(chars, src)
+    dst = _Pieces(offsets, cap, whole_rows)
+
+    def step(c, out):
+        p0, r, k, at, ln = dst.step(c)
+        if whole_rows:
+            piece = jax.lax.dynamic_slice_in_dim(indices, p0, dst.K)
+        else:
+            piece = src.first.at[indices[r]].get(
+                mode="fill", fill_value=src.P) + k
+        w = words.at[piece].get(mode="fill", fill_value=0)
+        return _place(out, _pack_pieces(_bytes(w), ln, at - at[0]), at[0])
+
+    return jax.lax.fori_loop(0, dst.steps(), step, out)
+
+
+def _unpack(chars: jax.Array, pieces: _Pieces) -> jax.Array:
+    """``[P, _WORDS]`` uint32: piece p's bytes, little-endian, zero past
+    its length. A step's K pieces are one contiguous run of the chars,
+    read with one slice; the run is spread back to the pieces' slots by
+    the inverse of ``_pack_pieces``'s network, whose displacements come
+    from running that network on the slots' own."""
+    K = pieces.K
+    W = K * PIECE_BYTES
+    if chars.shape[0] < W:
+        chars = jnp.pad(chars, (0, W - chars.shape[0]))
+    last = chars.shape[0] - W
+    stages = (W - 1).bit_length()
+    words = jnp.zeros_like(chars, jnp.uint32, shape=(pieces.P, _WORDS))
+
+    def step(c, words):
+        p0, _, _, at, ln = pieces.step(c)
+        base = at[0]
+        start = jnp.minimum(base, last)
+        run = jax.lax.dynamic_slice_in_dim(chars, start, W)
+        run = jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([run, jnp.zeros_like(run)]), base - start, W)
+        moved = _compact(_slots(ln, at - base), stages)
+        i = jnp.arange(W, dtype=jnp.int32)
+        x = jnp.where(i < at[-1] + ln[-1] - base,
+                      (moved & ~jnp.uint32(0xFF)) | run.astype(jnp.uint32),
+                      jnp.uint32(0))
+        slots = (_expand(x, stages) & 0xFF).reshape(K, PIECE_BYTES)
+        w = jnp.stack([
+            slots[:, 4 * i] | (slots[:, 4 * i + 1] << 8)
+            | (slots[:, 4 * i + 2] << 16) | (slots[:, 4 * i + 3] << 24)
+            for i in range(_WORDS)
+        ], axis=-1)
+        return jax.lax.dynamic_update_slice_in_dim(words, w, p0, 0)
+
+    return jax.lax.fori_loop(0, pieces.steps(), step, words)
+
+
+def _bytes(w: jax.Array) -> jax.Array:
+    """``[K, _WORDS]`` uint32 -> its ``[K, PIECE_BYTES]`` bytes, each in
+    a uint32 (a uint8 array of this shape inside the loop costs the TPU
+    compiler about a second per 700 pieces of K)."""
+    b = jnp.stack([(w >> (8 * i)) & 0xFF for i in range(4)], axis=-1)
+    return b.reshape(w.shape[0], PIECE_BYTES)
+
+
+def _slots(ln: jax.Array, local: jax.Array) -> jax.Array:
+    """Flat ``[K * PIECE_BYTES]`` uint32: at slot (q, j), j < ln[q], the
+    leftward move d = PB*q - local[q] of piece q's byte j, as d << 8;
+    0 elsewhere. ``local`` is the exclusive scan of ``ln``."""
+    K = ln.shape[0]
+    j = jax.lax.broadcasted_iota(jnp.int32, (K, PIECE_BYTES), 1)
+    q = jax.lax.broadcasted_iota(jnp.int32, (K, PIECE_BYTES), 0)
+    d = (PIECE_BYTES * q - local[:, None]).astype(jnp.uint32) << 8
+    return jnp.where(j < ln[:, None], d, jnp.uint32(0)).reshape(-1)
+
+
+def _compact(x: jax.Array, stages: int) -> jax.Array:
+    """Move each element of ``x`` (d << 8 | byte, 0 for none) left by
+    its d, one bit of d a stage, lowest first. Where d never decreases
+    along the elements while their targets rise, no two elements ever
+    share a slot (the compaction order of a shift network), so each
+    stage is one elementwise pass."""
+    return jax.lax.fori_loop(0, stages, lambda k, x: _stage(x, k, -1), x)
+
+
+def _expand(x: jax.Array, stages: int) -> jax.Array:
+    """The inverse of ``_compact``: its stages undone, highest first,
+    each element moving right by the bits of its d."""
+    return jax.lax.fori_loop(
+        0, stages, lambda k, x: _stage(x, stages - 1 - k, 1), x)
+
+
+def _stage(x: jax.Array, k, direction: int) -> jax.Array:
+    """One stage: elements whose d has bit ``k`` move 2**k slots in
+    ``direction`` (-1 left, 1 right). A loop over the stages, not
+    unrolled: one compiled body instead of one per stage."""
+    W = x.shape[0]
+    s = jnp.left_shift(jnp.int32(1), k)
+    zeros = jnp.zeros_like(x)
+    if direction < 0:
+        moved = jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([x, zeros]), s, W)
+    else:
+        moved = jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([zeros, x]), W - s, W)
+    bit = jnp.left_shift(jnp.uint32(1), (k + 8).astype(jnp.uint32))
+    return jnp.where((moved & bit) != 0, moved,
+                     jnp.where((x & bit) != 0, 0, x))
+
+
+def _pack_pieces(
+    pieces: jax.Array, ln: jax.Array, local: jax.Array
+) -> jax.Array:
+    """Pack bytes ``pieces[q, :ln[q]]`` (``[K, PIECE_BYTES]``, one a
+    uint32) to bytes ``[local[q], local[q] + ln[q])`` of a
+    ``[K * PIECE_BYTES]`` uint8 buffer, zeros past the last byte;
+    ``local`` is the exclusive scan of ``ln``. Byte j of piece q moves
+    left by d = PB*q - local[q], which never decreases along the
+    bytes."""
+    K = ln.shape[0]
+    j = jax.lax.broadcasted_iota(jnp.int32, (K, PIECE_BYTES), 1)
+    byte = jnp.where(j < ln[:, None], pieces, jnp.uint32(0))
+    x = _slots(ln, local) | byte.reshape(-1)
+    x = _compact(x, (K * PIECE_BYTES - 1).bit_length())
+    return (x & 0xFF).astype(jnp.uint8)
+
+
+def _place(out: jax.Array, packed: jax.Array, base) -> jax.Array:
+    """Write ``packed`` into ``out`` at ``base``, keeping what lies
+    before ``base`` and dropping what falls past the end of ``out``
+    (``dynamic_update_slice`` would clamp the start instead, shifting
+    the bytes)."""
+    W = min(packed.shape[0], out.shape[0])
+    start = jnp.clip(base, 0, out.shape[0] - W)
+    off = base - start
+    old = jax.lax.dynamic_slice_in_dim(out, start, W)
+    new = jax.lax.dynamic_slice_in_dim(
+        jnp.concatenate([jnp.zeros((W,), out.dtype), packed]),
+        jnp.clip(W - off, 0, packed.shape[0]), W,
+    )
+    i = jnp.arange(W, dtype=jnp.int32)
+    return jax.lax.dynamic_update_slice_in_dim(
+        out, jnp.where(i < off, old, new), start, 0
+    )
+
 
 def gather_rows(
     cols: Sequence[Column], idx: jax.Array

@@ -88,6 +88,13 @@ def inc(name: str, value: float = 1.0, /, **labels) -> None:
         _counters[k] = _counters.get(k, 0.0) + float(value)
 
 
+def string_chars_planned(site: str, char_bytes: int) -> None:
+    """Trace-time counter ``dj_string_char_bytes_planned_total``: the
+    char bytes the buffers of one string site hold (``site`` partition,
+    exchange or gather), counted once per traced module."""
+    inc("dj_string_char_bytes_planned_total", char_bytes, site=site)
+
+
 def inc_items(items) -> None:
     """Batched counter update under ONE lock acquisition: ``items`` is
     an iterable of (name, labels dict, value). The hot-path entry for

@@ -52,6 +52,7 @@ from ..core.dtypes import UINT_BY_SIZE
 from ..core.search import count_leq_arange
 from ..core.table import Column, StringColumn, Table
 from ..obs import recorder as obs
+from ..obs.metrics import string_chars_planned
 from ..utils.timing import annotate
 from . import hashing
 
@@ -1857,6 +1858,7 @@ def inner_join(
                     out_cols.append(_fill_column(c, out_capacity))
                 else:
                     cap = max(1, int(c.chars.shape[0] * char_out_factor))
+                    string_chars_planned("gather", cap)
                     out_cols.append(c.take(li_str, out_char_capacity=cap))
             else:
                 out_cols.append(left_out[i])
@@ -1868,6 +1870,7 @@ def inner_join(
                     out_cols.append(_fill_column(c, out_capacity))
                 else:
                     cap = max(1, int(c.chars.shape[0] * char_out_factor))
+                    string_chars_planned("gather", cap)
                     out_cols.append(c.take(rrow, out_char_capacity=cap))
             else:
                 out_cols.append(right_out[i])
@@ -2318,6 +2321,7 @@ def _gather_prepared_output(
                 out_cols.append(_fill_column(c, out_capacity))
             else:
                 cap = max(1, int(c.chars.shape[0] * char_out_factor))
+                string_chars_planned("gather", cap)
                 out_cols.append(c.take(li, out_char_capacity=cap))
         elif L == 0:
             out_cols.append(_fill_column(c, out_capacity))
@@ -2338,6 +2342,7 @@ def _gather_prepared_output(
                 out_cols.append(_fill_column(c, out_capacity))
             else:
                 cap = max(1, int(c.chars.shape[0] * char_out_factor))
+                string_chars_planned("gather", cap)
                 out_cols.append(c.take(rrow, out_char_capacity=cap))
         elif R == 0:
             out_cols.append(_fill_column(c, out_capacity))

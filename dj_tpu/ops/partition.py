@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.table import Column, StringColumn, Table
+from ..obs.metrics import string_chars_planned
 from . import hashing
 
 
@@ -145,6 +146,7 @@ def partition_by_ids(
     if strings:
         perm = sorted_ops[-1]
         for i, c in strings:
+            string_chars_planned("partition", c.chars.shape[0])
             out_cols[i] = c.take(perm)
     out = Table(tuple(out_cols), table.count())
     return out, offsets

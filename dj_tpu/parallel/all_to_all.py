@@ -54,6 +54,7 @@ from ..core.table import (
 from ..core.dtypes import UINT_BY_SIZE as _UINT_BY_SIZE
 from ..obs import recorder as obs
 from ..obs.bytemodel import buffer_bytes as _buffer_bytes
+from ..obs.metrics import string_chars_planned
 from ..utils.timing import annotate
 from .communicator import Communicator
 
@@ -268,8 +269,10 @@ def _single_peer_shuffle(
         sizes = _slice(col.sizes(), part_starts[0], out_capacity, row_mask)
         new_off = sizes_to_offsets(sizes)
         byte_start = col.offsets[part_starts[0]]
-        bpos = jnp.arange(cout, dtype=jnp.int32)
-        chars = _slice(col.chars, byte_start, cout, bpos < new_off[-1])
+        string_chars_planned("exchange", cout)
+        with annotate("dj_strings"):
+            bpos = jnp.arange(cout, dtype=jnp.int32)
+            chars = _slice(col.chars, byte_start, cout, bpos < new_off[-1])
         overflow = overflow | (new_off[-1] > cout)
         out_cols.append(StringColumn(new_off, chars, col.dtype))
     # No send buckets exist on the single-peer path, so every overflow
@@ -435,10 +438,12 @@ def shuffle_tables(
                                          cap_words, covf)))
         for t, i in string_cols:
             byte_starts, sent_bytes, covf, cbucket, cout = char_meta[(t, i)]
-            buffers.append(
-                bucketize(tables[t].columns[i].chars, byte_starts,
-                          sent_bytes, cbucket)
-            )
+            string_chars_planned("exchange", n * cbucket)
+            with annotate("dj_strings"):
+                buffers.append(
+                    bucketize(tables[t].columns[i].chars, byte_starts,
+                              sent_bytes, cbucket)
+                )
             metas.append(("chars", (t, i)))
 
     # Collective byte accounting (obs): everything here is STATIC —
@@ -553,7 +558,11 @@ def shuffle_tables(
             else:  # chars: offsets rebuilt from the received size vector
                 t, i = info
                 _, _, covf, _, cout = char_meta[(t, i)]
-                chars, btotal = compact(buf, recv_char_bytes[(t, i)], cout)
+                string_chars_planned("exchange", cout)
+                with annotate("dj_strings"):
+                    chars, btotal = compact(
+                        buf, recv_char_bytes[(t, i)], cout
+                    )
                 sizes = jnp.where(
                     jnp.arange(out_capacity[t], dtype=jnp.int32) < counts[t],
                     recv_sizes[(t, i)],
@@ -689,8 +698,11 @@ def broadcast_table(
             if kind != "chars":
                 continue
             cout = _char_out(i)
-            chars, btotal = compact(buf, counts_g[:, 1 + string_cols.index(i)],
-                                    cout)
+            string_chars_planned("exchange", cout)
+            with annotate("dj_strings"):
+                chars, btotal = compact(
+                    buf, counts_g[:, 1 + string_cols.index(i)], cout
+                )
             szs = jnp.where(
                 jnp.arange(out_capacity, dtype=jnp.int32) < out_count,
                 recv_sizes[i],

@@ -101,8 +101,14 @@ class JoinConfig:
     pre_shuffle_out_factor: output capacity multiplier for the
       inter-domain pre-shuffle stage.
     char_out_factor: join-output char capacity per string payload column
-      as a multiple of its input capacity (raise when the join
-      duplicates string rows).
+      as a multiple of that column's char capacity entering the local
+      join (for a build-side column, the received chars: n x its char
+      bucket). A foreign-key join repeats each build row's string once
+      per matching probe row, so size it as the mean fan-out (probe
+      rows per build row; TPC-H lineitem per order: 4) times the ratio
+      of the strings' mean length to their capacity's, plus slack; a
+      1.0 default holds only joins that duplicate no string bytes.
+      Truncation reports as ``char_overflow``.
     left_compression / right_compression: per-column compression options
       applied to the inter-domain (DCN-analog) pre-shuffle only — the
       intra-domain batched all-to-alls always run uncompressed, exactly

@@ -25,8 +25,10 @@ Naming rule for what a profiler trace shows of the program:
   ``dj_join`` / ``dj_concat``, and inside the local join ``dj_pack``,
   ``dj_sort``, ``dj_scan``, ``dj_expand`` (with ``dj_expand_fallback``
   on the XLA branch of an expansion kernel's fits check) and
-  ``dj_gather``. They land in the ops' HLO metadata and change nothing
-  the compiled program does.
+  ``dj_gather``; ``dj_strings`` around every string column's take,
+  reorder and char compaction, nested in the phase that runs it
+  (``StringColumn.take`` opens it itself). They land in the ops' HLO
+  metadata and change nothing the compiled program does.
 - host spans are ``dj.<stage>`` (``host_span``, entered at run time):
   ``obs.roofline.phase``'s ``probe`` / ``build`` / ``dispatch`` /
   ``sync`` / ``prep``, and the scheduler's ``submit`` (caller's thread)

@@ -1,10 +1,12 @@
 """What a profiler trace shows of the program (utils/timing.py's naming
 rule): the local join's phase scopes in the op metadata of each join
-body, and the host spans ``dj.<stage>`` on the profiler's clock, on the
-threads that run them, with obs disabled."""
+body, the string sites' ``dj_strings`` scope, and the host spans
+``dj.<stage>`` on the profiler's clock, on the threads that run them,
+with obs disabled."""
 
 import glob
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +65,38 @@ def test_local_join_phases_in_op_metadata(monkeypatch, body, plan):
     # The expansion kernel's fits check keeps its XLA branch, scoped so a
     # trace shows when it ran; the XLA plan has no such branch.
     assert ("dj_expand_fallback" in text) == (plan == "pallas")
+
+
+def test_string_sites_are_scoped_and_counted():
+    """A string payload's work is under ``dj_strings``, nested in the
+    partition, the exchange and the join's gather; the trace-time
+    counter holds the char bytes each site plans."""
+    from dj_tpu.obs import metrics
+
+    topo = dj_tpu.make_topology(devices=jax.devices()[:4])
+    keys = np.arange(256, dtype=np.int64)
+    build = T.Table((T.Column(jnp.asarray(keys), dj_tpu.dtypes.int64),
+                     T.from_strings([b"p%d" % k for k in keys])))
+    probe = T.from_arrays(keys[::-1].copy())
+    p, pc = dj_tpu.shard_table(topo, probe)
+    b, bc = dj_tpu.shard_table(topo, build)
+    config = dj_tpu.JoinConfig(bucket_factor=2.0, key_range=(0, 255))
+    was = metrics.enabled()
+    metrics.reset(reenable=True)
+    try:
+        text = jax.jit(
+            lambda p, pc, b, bc: dj_tpu.distributed_inner_join(
+                topo, p, pc, b, bc, [0], [0], config)
+        ).lower(p, pc, b, bc).as_text(debug_info=True)
+        planned = {site: metrics.counter_value(
+            "dj_string_char_bytes_planned_total", site=site)
+            for site in ("partition", "exchange", "gather")}
+    finally:
+        metrics.reset(reenable=was)
+    for outer in ("dj_partition", "dj_exchange", "dj_gather"):
+        # A scope path in an op's metadata: outer, then dj_strings.
+        assert re.search(rf'{outer}/([^/"]+/)*dj_strings', text), outer
+    assert all(v > 0 for v in planned.values()), planned
 
 
 def _trace(tmp_path, fn):
