@@ -44,8 +44,16 @@ exact, just slower.
 Correctness requires every window to fit in SPAN; the entry points
 check ``max_span`` (data-dependent) and `lax.cond` to the XLA
 histogram/gather otherwise, so skewed inputs stay exact. Tail slots
-(j >= csum[-1]) are UNSPECIFIED in both entry points — the two cond
+(j >= csum[-1]) are UNSPECIFIED in every entry point — the two cond
 branches fill them differently; callers mask with their valid count.
+So the fit is judged on the VALID slots only (``_window_starts``):
+entries with csum == csum[-1] rank no valid slot, and leaving them
+out keeps a padded table's zero-count tail (a bucketed shuffle's
+slack rows, ~10% of each side) from overflowing the tile that holds
+csum[-1] and sending the whole join to the fallback. Inside that
+tile the window still holds every entry a valid slot can rank; the
+tail entries past them compare False for every valid j, and the
+straddling group's walk is bounded by the buffer.
 
 Compiled-lowering status (round-4 AOT evidence for real v5e Mosaic,
 probe_mosaic_lower.py, measurements/r04_mosaic_lowering.txt):
@@ -87,6 +95,10 @@ from ..utils.timing import annotate
 # subtile. At the benchmark's shapes (S ~ 2e8 window entries over
 # out_cap ~ 5e7 slots) the mean window is ~4.05 x T_J, so both
 # geometries carry ~2x span headroom before the fallback triggers.
+# Windows are measured over the valid slots (j < csum[-1]): a padded
+# table's zero-count tail, 10-20M entries a chip behind a 4-chip
+# shuffle, all sorts into the last valid tile and would overflow any
+# span if it counted (see _window_starts).
 # SPAN is bounded above by XLA:TPU's default scoped-vmem budget: at
 # SPAN = 1M the kernel lowers but allocation fails (v5e AOT evidence,
 # measurements/r04_mosaic_lowering.txt) unless
@@ -445,12 +457,25 @@ def _run_pallas(
 
 
 def _window_starts(csum: jax.Array, n_out: int, t_j: int):
-    """(n_pad, starts, spans) for the aligned output tiling."""
+    """(n_pad, starts, spans) for the aligned output tiling.
+
+    ``starts[p] = #{csum < p*t_j}`` places tile p's window. ``spans[p]``
+    is the width the fit is judged on: the entries that tile p's VALID
+    slots (j < total = csum[-1]) can rank, ``#{csum < min((p+1)*t_j,
+    total)} - starts[p]``. Entries with csum == total (the zero-count
+    tail: a bucketed table's padding rows and the unmatched rows after
+    the last match) rank no valid slot, so they do not widen the tile
+    that holds total; tiles wholly past total read <= 0. Since
+    searchsorted is monotone, the clamp is one scalar search ``e``.
+    """
     n_pad = ((n_out + t_j - 1) // t_j) * t_j
     P = n_pad // t_j
     bounds = jnp.arange(P + 1, dtype=csum.dtype) * t_j
     starts = jnp.searchsorted(csum, bounds, side="left").astype(jnp.int32)
-    return n_pad, starts, starts[1:] - starts[:-1]
+    if csum.shape[0] == 0:
+        return n_pad, starts, starts[1:] - starts[:-1]
+    e = jnp.searchsorted(csum, csum[-1], side="left").astype(jnp.int32)
+    return n_pad, starts, jnp.minimum(starts[1:], e) - starts[:-1]
 
 
 def _pad32(x: jax.Array, span: int, fill) -> jax.Array:

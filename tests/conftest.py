@@ -110,3 +110,38 @@ def tiny_pallas_geometry(monkeypatch):
 
     yield apply
     _build_join_fn.cache_clear()
+
+
+@pytest.fixture
+def expand_fallback_runs(monkeypatch):
+    """Count the runs of the expansion kernels' XLA branch.
+
+    Every entry point of ``dj_tpu.ops.pallas_expand`` wraps its fits
+    ``lax.cond``'s XLA branch in ``_fallback``; this wraps it once more
+    with a debug callback, which fires only when the cond takes that
+    branch. Yields the list the callback appends to: empty after a run
+    means the Pallas branch ran everywhere. jit caches are cleared on
+    both sides so no trace made without the callback is reused, and
+    none made with it leaks to later tests.
+    """
+    import dj_tpu.ops.pallas_expand as px
+    from dj_tpu.parallel.dist_join import _build_join_fn
+
+    runs = []
+    real = px._fallback
+
+    def counted(xla_path):
+        inner = real(xla_path)
+
+        def run(operand):
+            jax.debug.callback(lambda: runs.append(1))
+            return inner(operand)
+
+        return run
+
+    monkeypatch.setattr(px, "_fallback", counted)
+    jax.clear_caches()
+    _build_join_fn.cache_clear()
+    yield runs
+    jax.clear_caches()
+    _build_join_fn.cache_clear()

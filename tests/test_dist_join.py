@@ -14,6 +14,7 @@ import pytest
 # unchanged.
 pytestmark = pytest.mark.heavy
 
+import jax
 import numpy as np
 import pytest
 
@@ -219,6 +220,31 @@ def test_distributed_join_pallas_expand(impl, tiny_pallas_geometry):
                    join_out_factor=8.0),
     )
     assert _sorted_rows(result, 3) == _np_oracle(lk, lp, rk, rp)
+
+
+def test_distributed_join_padded_shards_take_kernel(
+    tiny_pallas_geometry, expand_fallback_runs
+):
+    """bucket_factor slack leaves each shard's received tables with
+    more padding rows than the tiny SPAN2 (1024). The fit is judged on
+    the valid output slots, so the vmeta kernel still runs on every
+    shard, and the join is row-exact."""
+    tiny_pallas_geometry("pallas-vmeta-interpret")
+
+    rng = np.random.default_rng(43)
+    n_l, n_r = 4800, 400
+    lk = rng.integers(0, n_r, n_l, dtype=np.int64)
+    rk = rng.permutation(n_r).astype(np.int64)
+    lp = np.arange(n_l, dtype=np.int64)
+    rp = np.arange(n_r, dtype=np.int64) + 5000
+    result = _run_dist_join(
+        T.from_arrays(lk, lp), T.from_arrays(rk, rp), make_topology(),
+        JoinConfig(over_decom_factor=1, bucket_factor=4.0,
+                   join_out_factor=2.0),
+    )
+    jax.effects_barrier()
+    assert _sorted_rows(result, 3) == _np_oracle(lk, lp, rk, rp)
+    assert expand_fallback_runs == []
 
 
 @pytest.mark.parametrize(

@@ -367,3 +367,132 @@ def test_expand_values_fallback_on_wide_window():
     valid = np.arange(n_out) < total
     np.testing.assert_array_equal(np.asarray(got_stag)[valid], want_stag[valid])
     np.testing.assert_array_equal(np.asarray(got_rpos)[valid], want_rpos[valid])
+
+
+# ---------------------------------------------------------------------
+# The fit is judged on the valid slots (j < csum[-1]) only
+# ---------------------------------------------------------------------
+
+
+def _tail_case(case):
+    """(cnt, n_out): a csum shape that ends, as a padded table's merged
+    order does, in a zero-count tail longer than the span (1024)."""
+    rng = np.random.default_rng(31)
+    tail = np.zeros(1500, np.int64)
+    n_out = 2048
+    if case == "padded_tail":  # ~1 merged row an output: windows ~256
+        valid = rng.integers(0, 3, 1500)
+    elif case == "tile_boundary":  # total == 4 * t_j exactly
+        valid = rng.integers(0, 3, 1500)
+        csum = np.cumsum(valid)
+        i = int(np.searchsorted(csum, 1024))
+        valid[i] = 1024 - (csum[i - 1] if i else 0)
+        valid[i + 1 :] = 0
+        n_out = 1536
+    elif case == "zero_match":  # total == 0: no valid slot at all
+        valid = np.zeros(1500, np.int64)
+        n_out = 1024
+    else:  # "wide_valid": ~8 rows an output, windows ~2048 > span
+        valid = (rng.random(12_000) < 0.125).astype(np.int64)
+    return np.concatenate([valid.astype(np.int64), tail]), n_out
+
+
+def _run_entry(entry, cnt, n_out):
+    """(got, want): one entry point's outputs and their numpy oracle
+    (valid slots only are specified)."""
+    from dj_tpu.ops.pallas_expand import (
+        expand_carry, expand_gather, expand_values,
+    )
+
+    rng = np.random.default_rng(5)
+    S = len(cnt)
+    csum = np.cumsum(cnt).astype(np.int64)
+    a = rng.integers(-(2**31), 2**31 - 1, S, dtype=np.int64).astype(np.int32)
+    b = rng.integers(0, S, S).astype(np.int32)
+    j = np.arange(n_out)
+    src = np.searchsorted(csum, j, side="right")
+    srcc = np.clip(src, 0, S - 1)
+    rpos = b[srcc] + (j - (csum - cnt)[srcc])
+    jc, ja, jb = jnp.asarray(csum), jnp.asarray(a), jnp.asarray(b)
+    if entry == "ranks":
+        return (expand_ranks(jc, n_out, **GEO),), (src,)
+    if entry == "gather":
+        return expand_gather(jc, ja, jb, n_out, **GEO), (src, a[srcc], b[srcc])
+    if entry == "values":
+        got = expand_values(jc, jnp.asarray(cnt), ja, jb, n_out, **VGEO)
+        return got, (a[srcc], rpos)
+    got = expand_carry(jc, jnp.asarray(cnt), jb, (ja,), n_out, **VGEO)
+    return got, (rpos, a[srcc])
+
+
+@pytest.mark.parametrize(
+    "case", ["padded_tail", "tile_boundary", "zero_match", "wide_valid"]
+)
+@pytest.mark.parametrize("entry", ["ranks", "gather", "values", "carry"])
+def test_fit_judged_on_valid_slots(entry, case, expand_fallback_runs):
+    """A zero-count tail wider than the span no longer sends the
+    expansion to the XLA branch: the Pallas branch runs and the valid
+    slots are exact. Valid windows genuinely wider than the span
+    ("wide_valid") still fall back, and stay exact."""
+    from dj_tpu.ops.pallas_expand import _window_starts
+
+    cnt, n_out = _tail_case(case)
+    csum = np.cumsum(cnt)
+    total = int(csum[-1])
+    _, starts, judged = _window_starts(
+        jnp.asarray(csum.astype(np.int32)), n_out, GEO["t_j"]
+    )
+    # Judged over every slot, the tile that holds total takes in the
+    # whole tail, so every case here failed the fit before.
+    assert np.diff(np.asarray(starts)).max() > GEO["span"]
+    engages = case != "wide_valid"
+    assert (int(jnp.max(judged)) < GEO["span"]) == engages
+    got, want = _run_entry(entry, cnt, n_out)
+    got = [np.asarray(g) for g in got]
+    jax.effects_barrier()
+    assert (len(expand_fallback_runs) == 0) == engages
+    valid = np.arange(n_out) < total
+    for g, w in zip(got, want):
+        assert g.shape == (n_out,)
+        np.testing.assert_array_equal(g[valid], w[valid])
+
+
+def test_inner_join_padded_tables_take_kernel(
+    tiny_pallas_geometry, expand_fallback_runs
+):
+    """inner_join on tables whose padding rows (l_count < L, r_count <
+    R) outnumber the tiny SPAN2 (1024): the padding sorts to csum's
+    zero-count tail, so the vmeta kernel runs, and the join is
+    row-exact. The padding rows hold keys that would match."""
+    from dj_tpu.core import table as T
+    from dj_tpu.ops.join import inner_join
+
+    tiny_pallas_geometry("pallas-vmeta-interpret")
+    rng = np.random.default_rng(41)
+    L, l_count, R, r_count = 1500, 1000, 1200, 300
+    assert (L - l_count) + (R - r_count) > 1024
+    lk = rng.integers(0, r_count, L).astype(np.int64)
+    rk = rng.integers(0, r_count, R).astype(np.int64)
+    rk[:r_count] = rng.permutation(r_count)  # valid build keys unique
+    lp = np.arange(L, dtype=np.int64)
+    rp = np.arange(R, dtype=np.int64) + 10_000
+    result, total = inner_join(
+        T.from_arrays(lk, lp, valid_count=jnp.int32(l_count)),
+        T.from_arrays(rk, rp, valid_count=jnp.int32(r_count)),
+        [0], [0],
+        out_capacity=1280,
+    )
+    n = int(total)
+    got = sorted(
+        zip(*[np.asarray(result.columns[i].data)[:n].tolist()
+              for i in range(3)])
+    )
+    jax.effects_barrier()
+    want = sorted(
+        (int(lk[i]), int(lp[i]), int(rp[j]))
+        for i in range(l_count)
+        for j in range(r_count)
+        if lk[i] == rk[j]
+    )
+    assert n == l_count and got == want
+    assert expand_fallback_runs == []
