@@ -222,7 +222,7 @@ def _effective_plan():
     """The (scans, expand) implementations the pipeline will actually
     run — delegated to ops.join.effective_plan, which mirrors
     inner_join's full eligibility gating (packed-path requirements,
-    carry/vcarry degrades) rather than just reading the env. Recorded
+    vcarry/vfull degrades) rather than just reading the env. Recorded
     in the emitted JSON so the byte model is auditable (the A/B suites
     sweep exactly these flags — a hardcoded model would judge the
     XLA/hist paths against the fused kernels' cheaper byte counts).
@@ -238,9 +238,9 @@ def _effective_plan():
         import collections
 
         fallback = collections.namedtuple(
-            "JoinPlan", "scans expand packed carry sort"
+            "JoinPlan", "scans expand packed sort"
         )
-        return fallback("unknown", "unknown", True, False, "monolithic")
+        return fallback("unknown", "unknown", True, "monolithic")
 
 
 def _merge_impl():
@@ -631,7 +631,7 @@ def main():
             "roofline_frac": round(achieved_gbps / hbm_peak, 4),
             "plan": (
                 f"scans={plan.scans},expand={plan.expand},"
-                f"packed={int(plan.packed)},carry={int(plan.carry)},"
+                f"packed={int(plan.packed)},"
                 f"sort={getattr(plan, 'sort', 'monolithic')}"
             ),
         }

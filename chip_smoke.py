@@ -178,7 +178,7 @@ def _resolved_plan():
                           n_payload=1)
     rec = {
         "scans": plan.scans, "expand": plan.expand,
-        "packed": plan.packed, "carry": plan.carry, "sort": plan.sort,
+        "packed": plan.packed, "sort": plan.sort,
         "merge": resolve_merge_impl(), "probe_expand": resolve_probe_expand(),
     }
     for kernel in ("scans", "expand"):

@@ -598,7 +598,6 @@ def _default_trace_knobs() -> bool:
     as default)."""
     env = _os.environ
     for name, fallback in (("DJ_JOIN_SORT", "monolithic"),
-                           ("DJ_JOIN_CARRY", "0"),
                            ("DJ_JOIN_PACK", "1")):
         v = env.get(name)
         if v is not None and v != _knob_default(name, fallback):

@@ -120,13 +120,12 @@ def _k(name, default, kind, doc, cleanup, **kw) -> Knob:
 KNOBS: tuple[Knob, ...] = (
     # --- trace-affecting kernel/plan selection (the _env_key family) --
     _k("DJ_JOIN_EXPAND", None, "enum",
-       "expansion kernel: hist scatter vs the Pallas rank/value variants",
+       "expansion: hist scatter vs the Pallas delta-dot value kernels "
+       "(-interpret runs a kernel interpreted)",
        "trace", env_key=True,
-       choices=("hist", "pallas", "pallas-vmeta", "pallas-vcarry",
-                "pallas-fused", "pallas-join", "pallas-join-interpret")),
-    _k("DJ_JOIN_CARRY", "0", "bool",
-       "legacy stacked-gather payload carry variant", "trace",
-       env_key=True),
+       choices=("hist", "pallas-vmeta", "pallas-vcarry", "pallas-vfull",
+                "pallas-vmeta-interpret", "pallas-vcarry-interpret",
+                "pallas-vfull-interpret")),
     _k("DJ_JOIN_MERGE", None, "enum",
        "prepared-join merge tier: xla sort-merge, pallas kernel, or "
        "the zero-sort probe binary search", "trace", env_key=True,

@@ -253,12 +253,10 @@ def hbm_model_bytes(
     vfull = expand.startswith("pallas-vfull")
     vcarry = expand.startswith("pallas-vcarry") or vfull
     # Merged sort: ~log2(S) merge passes, r+w per pass. Packed = one
-    # 8 B u64 operand; unpacked = int64 key + int32 tag (12 B); carry /
-    # vcarry additionally ride one union u64 payload slot per payload
-    # column (the bench tables have one non-key column each).
-    sort_width = (8 if plan.packed else 12) + (
-        8 if (vcarry or plan.carry) else 0
-    )
+    # 8 B u64 operand; unpacked = int64 key + int32 tag (12 B); vcarry
+    # additionally rides one union u64 payload slot per payload column
+    # (the bench tables have one non-key column each).
+    sort_width = (8 if plan.packed else 12) + (8 if vcarry else 0)
     if prepared and merge_impl.startswith("pallas"):
         # Left-only sort at bl depth + ONE merge-path pass over the two
         # sorted operands (read both + write the merged S).
@@ -297,7 +295,6 @@ def hbm_model_bytes(
         # (4r+4w), two int32 cummaxes (8r+8w), cnt elementwise
         # (8r+4w), int32 csum (4r+4w) — separate HBM round trips.
         total += odf * 56 * s
-    joinmode = expand.startswith("pallas-join")
     if expand.startswith("pallas-vmeta") or vcarry:
         # Fused expansion kernel: four int32 window reads over the
         # merged length + two int32 outputs per slot (vcarry reads the
@@ -313,22 +310,6 @@ def hbm_model_bytes(
         else:
             total += odf * ((16 + 4 * pay_planes) * s
                             + (8 + 4 * pay_planes) * bs.out_cap)
-    elif expand.startswith("pallas"):
-        # Merge-path ranks family (pallas / pallas-fused /
-        # pallas-join): one linear walk over csum (4 B/elem) plus
-        # int32 outputs — src alone (4 B), src+stag_j+rstart_j when
-        # fused (12 B), or stag_j+rtag in join mode (8 B, no src/t
-        # arrays exist on that path); non-fused, non-join modes add
-        # the t scan (8 B/out) and the 16 B meta-word gather at src.
-        if joinmode:
-            kernel_out = 8
-        elif expand.startswith("pallas-fused"):
-            kernel_out = 12
-        else:
-            kernel_out = 4
-        total += odf * (4 * s + kernel_out * bs.out_cap)
-        if not joinmode and not expand.startswith("pallas-fused"):
-            total += odf * (8 + 16) * bs.out_cap
     else:
         # hist: scatter-add histogram (lowered by XLA:TPU as a hidden
         # full-size sort over out_cap keys, ARCHITECTURE.md) + cumsum
@@ -346,10 +327,6 @@ def hbm_model_bytes(
         # ONE stacked (key, right payload) gather per match + 24 B of
         # output writes (left payloads stream out of the kernel).
         total += matches * (16 + 24)
-    elif joinmode:
-        # rtag came out of the kernel: left pack (16 B) + right pack
-        # (8 B) reads + 24 B output writes per match.
-        total += matches * (16 + 8 + 24)
     else:
         # Output gathers: right tag (4 B) + left pack (16 B) + right
         # pack (8 B) reads plus 24 B of output writes per match (the

@@ -29,12 +29,13 @@ random memory as few times as possible:
 3. Duplicate expansion: which merged position produces output j, plus
    the per-slot metadata/values AT that position — on TPU one
    delta-dot Pallas kernel with no output-sized gathers
-   (pallas_expand.expand_values / expand_carry, DJ_JOIN_EXPAND),
+   (pallas_expand, DJ_JOIN_EXPAND: vmeta by default, vcarry or vfull),
    else histogram + cumsum + meta gather.
-4. Output materialization: indirect modes gather packed rows per
+4. Output materialization: hist and vmeta gather packed rows per
    table (stacked multi-column gathers amortize the per-row latency);
    vcarry replaces them with kernel-expanded left values and ONE
-   stacked (key, right values) gather at the matched ref positions.
+   stacked (key, right values) gather at the matched ref positions,
+   and vfull resolves those in the kernel too.
 """
 
 from __future__ import annotations
@@ -47,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import knobs
 from ..core import dtypes as dt
 from ..core.dtypes import UINT_BY_SIZE
 from ..core.search import count_leq_arange
@@ -85,8 +87,7 @@ def _u64_from_planes(lo32: jax.Array, hi32: jax.Array) -> jax.Array:
 
 def _max_run(cnt: jax.Array, run_start: jax.Array, S: int) -> jax.Array:
     """Longest matched run's ref span: bounds how far below its query a
-    matched ref can sit (the margin-walk eligibility bound shared by
-    the vfull and pallas-join expansion modes)."""
+    matched ref can sit (vfull's margin-walk eligibility bound)."""
     pos = jnp.arange(S, dtype=jnp.int32)
     return jnp.max(
         jnp.where(cnt > 0, pos - run_start, 0), initial=0
@@ -964,7 +965,7 @@ def _verify_string_pairs(
 def _union_slots(l_carry, r_fixed, L: int, R: int) -> list:
     """Union u64 sort operands: slot j holds the right payload j on
     ref rows and the left payload j on query rows (zero-filled where
-    one side has fewer columns). Shared by carry and vcarry."""
+    one side has fewer columns), the sort operands of vcarry and vfull."""
     zeros = jnp.zeros((1,), jnp.uint64)
     slots = []
     for j in range(max(len(l_carry), len(r_fixed))):
@@ -1053,14 +1054,12 @@ def resolve_probe_expand() -> str:
 class JoinPlan(NamedTuple):
     """The kernel plan a join will run: resolved scans / expansion
     implementations plus the sort-shaping flags (packed single-u64
-    operand vs unpacked; payloads riding the sort in carry mode; the
-    packed operand's sort strategy)."""
+    operand vs unpacked; the packed operand's sort strategy)."""
 
     scans: str   # "pallas[-interpret]" (fused kernel) or "xla"
-    expand: str  # "pallas-vmeta" / "pallas-vcarry" / "pallas[-fused/
-                 # -join]" / "hist" (+ "-interpret")
+    expand: str  # "hist" / "pallas-vmeta" / "pallas-vcarry" /
+                 # "pallas-vfull" (+ "-interpret")
     packed: bool  # single-u64 packed merged sort eligible
-    carry: bool   # payloads ride the sort as union slots
     sort: str = "monolithic"  # "monolithic" lax.sort or "bucketed"
                               # two-pass (packed single-operand only)
 
@@ -1070,49 +1069,48 @@ def effective_plan(
     single_int_key: bool = True,
     has_strings: bool = False,
     n_payload: int = 1,
-    carry_payloads: Optional[bool] = None,
     multi_key_packed: bool = False,
 ) -> JoinPlan:
     """Resolve the kernel plan for a join of the given shape under the
     current env + platform. THE single source of the eligibility gates
-    (packed path requires x64 + DJ_JOIN_PACK, carry mode forces the
-    src-indirect expansion, vcarry degrades to vmeta when ineligible):
-    inner_join consumes this resolver, and bench.py's byte model labels
-    runs with it, so the two can never drift.
+    (packed path requires x64 + DJ_JOIN_PACK, vcarry/vfull degrade to
+    vmeta when ineligible): inner_join consumes this resolver, and
+    bench.py's byte model labels runs with it, so the two can never
+    drift. A DJ_JOIN_EXPAND value outside the knob's choices raises
+    ValueError.
 
     ``n_payload`` = max non-key fixed-width columns on either side
-    (vcarry's operand-count gate); ``carry_payloads`` mirrors
-    inner_join's parameter (None = DJ_JOIN_CARRY env).
+    (vcarry's operand-count gate).
     ``multi_key_packed`` = the caller statically determined (declared
     or probed key ranges, plan_key_pack) that a multi-column int key
     packs into the single-u64 word — such joins ride the packed
-    machinery (incl. the fused scan kernel) but never carry/vcarry
+    machinery (incl. the fused scan kernel) but never vcarry/vfull
     (those reconstruct the key from the sorted word, a single-key
     decode).
     """
-    if carry_payloads is None:
-        carry_payloads = os.environ.get("DJ_JOIN_CARRY", "0") == "1"
-    carry = bool(carry_payloads) and single_int_key
     use_pack = (
         (single_int_key or multi_key_packed)
-        and not carry  # carry's branch sorts (vals, tag, *slots) unpacked
         and os.environ.get("DJ_JOIN_PACK", "1") == "1"
         and jnp.zeros((), jnp.int64).dtype.itemsize == 8  # x64 live
     )
     scans = os.environ.get("DJ_JOIN_SCANS", "pallas" if _on_tpu() else "xla")
-    # The fused scan kernel reads the packed sorted operand; carry mode
-    # and unpacked sorts fall back to the XLA chain.
-    if not (use_pack and not carry and scans.startswith("pallas")):
+    # The fused scan kernel reads the packed sorted operand; unpacked
+    # sorts fall back to the XLA chain.
+    if not (use_pack and scans.startswith("pallas")):
         scans = "xla"
     default_expand = TPU_DEFAULT_EXPAND if _on_tpu() else "hist"
     expand = os.environ.get("DJ_JOIN_EXPAND", default_expand)
+    choices = knobs.REGISTRY["DJ_JOIN_EXPAND"].choices
+    if expand not in choices:
+        raise ValueError(
+            f"DJ_JOIN_EXPAND={expand!r} is not one of {', '.join(choices)}"
+        )
     interp = "-interpret" if expand.endswith("-interpret") else ""
     if (
         expand.startswith("pallas-vcarry")
         or expand.startswith("pallas-vfull")
     ) and not (
-        not carry
-        and single_int_key
+        single_int_key
         and use_pack
         and not has_strings
         # n_payload=4 exhausts VMEM in the cond's XLA fallback branch
@@ -1120,20 +1118,15 @@ def effective_plan(
         and n_payload <= 3
     ):
         expand = "pallas-vmeta" + interp
-    if carry and expand.split("-interpret")[0] not in ("hist", "pallas"):
-        # carry mode resolves rows via src indirection; the fused
-        # expansion kernels are "not carry"-gated, and a pallas-* value
-        # falls through to the expand_ranks branch.
-        expand = ("pallas" + interp) if expand.startswith("pallas") else "hist"
     sort = os.environ.get("DJ_JOIN_SORT", "monolithic")
     if sort != "bucketed" or not use_pack or (
         expand.startswith("pallas-vcarry") or expand.startswith("pallas-vfull")
     ):
         # The bucketed two-pass sort applies to the SINGLE-operand
-        # packed sort only; carry/vcarry ride payload slots through a
+        # packed sort only; vcarry/vfull ride payload slots through a
         # variadic sort that stays monolithic.
         sort = "monolithic"
-    return JoinPlan(scans, expand, use_pack, carry, sort)
+    return JoinPlan(scans, expand, use_pack, sort)
 
 
 _warned_unverified_string_keys = False
@@ -1190,7 +1183,6 @@ def inner_join(
     right_on: Sequence[int],
     out_capacity: Optional[int] = None,
     char_out_factor: float = 1.0,
-    carry_payloads: Optional[bool] = None,
     verify_string_keys: Optional[bool] = None,
     return_flags: bool = False,
     key_range=None,
@@ -1257,19 +1249,6 @@ def inner_join(
     skipped (its flag would be unobservable, and a once-per-process
     RuntimeWarning says so) and collision odds are as documented in
     string_surrogate64.
-
-    ``carry_payloads`` picks between two equivalent data-movement plans
-    (single-int-key joins only; measured on the real chip via
-    DJ_JOIN_CARRY, see ARCHITECTURE.md):
-      False ("indirect"): sort (key, tag) only; resolve output rows via
-        tag indirection — 12 B/elem of sort operands, 4 output-sized
-        gathers (meta, right tag, left rows, right rows).
-      True ("carry"): additionally carry every fixed-width payload
-        column through the merged sort as a union slot (query rows hold
-        left values, ref rows right values) — wider sort operands, but
-        only 2 output-sized gathers (gathers cost per ROW on TPU, not
-        per byte). Strings still resolve via tag indirection.
-      None: DJ_JOIN_CARRY env override, else False.
     """
     if len(left_on) != len(right_on):
         raise ValueError(
@@ -1389,11 +1368,10 @@ def inner_join(
     # --- ONE merged sort: refs (right rows) first, one int32 tag ------
     # Stability puts equal-key refs before equal-key left rows, so each
     # key run is laid out [refs..., left rows...] and a left row's
-    # matches sit contiguously at its run's start. In carry mode the
+    # matches sit contiguously at its run's start. In vcarry mode the
     # sort additionally carries one union u64 slot per payload column
     # (ref rows hold right values, query rows left values). Multi-column
     # keys sort all key columns variadically in one pass instead.
-    spay: list[jax.Array] = []
     boundary = None
     run_start = None
     if single:
@@ -1457,10 +1435,8 @@ def inner_join(
         single_int_key=single,
         has_strings=has_strings,
         n_payload=n_pay,
-        carry_payloads=carry_payloads,
         multi_key_packed=mk_packed_avail,
     )
-    carry = plan.carry
     use_pack = plan.packed
     scans_impl = plan.scans
     scan_fused = scans_impl.startswith("pallas")
@@ -1499,19 +1475,9 @@ def inner_join(
         boundary, stag = _multi_key_merged_sort(
             left, right, left_on, right_on
         )
-    elif carry:
-        # Union slots: left fixed columns EXCLUDING the key (the key is
-        # recovered from the sorted key vector itself) vs right payload
-        # columns.
-        with annotate("dj_pack"):
-            slots = _union_slots(l_carry, r_fixed, L, R)
-        with annotate("dj_sort"):
-            sorted_ops = jax.lax.sort(
-                tuple([vals, tag] + slots), num_keys=1, is_stable=True
-            )
-        svals, stag = sorted_ops[0], sorted_ops[1]
-        spay = list(sorted_ops[2:])
     elif vcarry:
+        # Union slots: left fixed columns EXCLUDING the key (the key is
+        # recovered from the sorted word) vs right payload columns.
         with annotate("dj_pack"):
             slots = _union_slots(l_carry, r_fixed, L, R)
         stag, run_start, cnt, csum, key_su64, sslots = _packed_merged_sort(
@@ -1574,49 +1540,29 @@ def inner_join(
         # int32-clamped).
         total = jnp.sum(cnt.astype(jnp.int64)) if S else jnp.int64(0)
 
-    # --- expansion metadata: which merged position produces output j --
-    # Three exact implementations of src[j] = #{csum <= j} (csum is
-    # sorted, which is all any of them requires; see pallas_expand.py
-    # for the kernels' cost model):
-    #   hist: XLA scatter-add histogram + cumsum.
-    #   pallas: merge-path Pallas kernel for the ranks.
-    #   pallas-fused: ranks AND the meta-word gather in one kernel
-    #     (indirect mode only).
-    #   pallas-join: the whole expansion — ranks, within-run offset,
-    #     and both metadata gathers — in one kernel pass (indirect
-    #     mode only); no src/t arrays exist at all on this path.
+    # --- expansion: which merged position produces output j, and the
+    # values at that position ------------------------------------------
+    # Exact implementations of src[j] = #{csum <= j} (csum is sorted,
+    # which is all any of them requires; see pallas_expand.py for the
+    # kernels' cost model):
+    #   hist: XLA scatter-add histogram + cumsum, then one meta-word
+    #     gather; the default off the TPU (compiled Mosaic kernels are
+    #     TPU-only).
+    #   pallas-vmeta: expand_values, one delta-dot kernel emitting
+    #     (stag_j, rpos) with no output-sized gathers. The TPU default:
+    #     7.95 s vs 9.18 s for merge-path ranks + XLA gathers at the
+    #     100M headline (round 4), hardware-verified row-exact.
+    #   pallas-vcarry / pallas-vfull: the payloads ride the sort (see
+    #     the pre-sort section, where expand_impl was resolved because
+    #     it changes what the sort carries); the kernel expands them at
+    #     src, and vfull resolves the right side in-kernel too.
     #   "-interpret" suffixes run the kernels interpreted (CPU tests).
-    # Default: "pallas" on TPU, measured 387 ms vs the histogram's
-    # 746 ms at the benchmark's odf=4 expansion shapes on a v5e
-    # (measurements/r04_phase_odf4.out; XLA:TPU lowers the histogram's
-    # scatter-add as a hidden full-size sort, ARCHITECTURE.md).
-    # Round-4 session 2 promoted "pallas-vmeta" (expand_values: the
-    # whole expansion incl. the meta resolution, no output-sized
-    # gathers): 7.95 s vs 9.18 s at the 100M headline, hardware-
-    # verified row-exact. "hist" elsewhere (compiled Mosaic kernels
-    # are TPU-only). "pallas-vcarry" additionally rides the payloads
-    # through the sort (see the pre-sort section; expand_impl was
-    # resolved there because it changes what the sort carries).
     with annotate("dj_expand"):
-        fused = not carry and expand_impl.startswith("pallas-fused")
-        joinmode = not carry and expand_impl.startswith("pallas-join")
-        # "pallas-vmeta": the COMPILED fused expansion (delta-dot value
-        # expansion, pallas_expand.expand_values) — ranks, t, and the
-        # (stag, run_start) meta gather collapse into one kernel emitting
-        # (stag_j, rpos) with no output-sized gathers.
-        vmeta = not carry and expand_impl.startswith("pallas-vmeta")
+        vmeta = expand_impl.startswith("pallas-vmeta")
 
         j32 = jnp.arange(out_capacity, dtype=jnp.int32)
         valid_out = jnp.arange(out_capacity, dtype=jnp.int64) < total
 
-        # One word gather resolves the per-slot metadata: (stag, run_start)
-        # as two packed int32. Carry mode widens the same gather with the
-        # sorted key + payload slots instead of issuing per-table gathers.
-        # The Pallas kernels gather the two int32 planes directly (Mosaic
-        # has no 64-bit types), so they skip the u64 packing entirely.
-        stag_j = rstart_j = rtag_direct = None
-        src = t = rpos_direct = None
-        lpay_planes = None
         if vcarry:
             pay_planes = []
             for sl in sslots:
@@ -1665,44 +1611,15 @@ def inner_join(
             stag_j, rpos_direct = expand_values(
                 csum, cnt, stag, run_start, out_capacity, interpret=interp
             )
-        elif joinmode:
-            from .pallas_expand import expand_join
-
-            stag_j, rtag_direct = expand_join(
-                csum, stag, run_start, _max_run(cnt, run_start, S),
-                out_capacity, interpret=interp,
-            )
-        elif fused:
-            from .pallas_expand import expand_gather
-
-            src, stag_j, rstart_j = expand_gather(
-                csum, stag, run_start, out_capacity, interpret=interp
-            )
-            src = jnp.clip(src, 0, S - 1)
-        elif expand_impl.startswith("pallas"):
-            from .pallas_expand import expand_ranks
-
-            src = jnp.clip(
-                expand_ranks(csum, out_capacity, interpret=interp), 0, S - 1
-            )
         else:
             src = jnp.clip(count_leq_arange(csum, out_capacity), 0, S - 1)
-        if not joinmode and not vmeta and not vcarry:
             # Which match within the run: output slots of one query are
             # consecutive, so t = j - (first j with this src) — recovered
             # from src's own run boundaries by one scan instead of
             # gathering csum_ex.
             t = j32 - jax.lax.cummax(jnp.where(_run_starts(src), j32, -1))
-
-        if carry:
-            meta = jax.lax.bitcast_convert_type(
-                jnp.stack([stag, run_start], axis=-1), jnp.uint64
-            )
-            packed = jnp.stack([meta, _to_u64(svals)] + spay, axis=-1)
-            rows = packed.at[src].get(mode="fill", fill_value=0)
-            m32 = jax.lax.bitcast_convert_type(rows[:, 0], jnp.int32)
-            stag_j, rstart_j = m32[:, 0], m32[:, 1]
-        elif not fused and not joinmode and not vmeta and not vcarry:
+            # One word gather resolves the per-slot metadata:
+            # (stag, run_start) as two packed int32.
             meta = jax.lax.bitcast_convert_type(
                 jnp.stack([stag, run_start], axis=-1), jnp.uint64
             )
@@ -1712,7 +1629,7 @@ def inner_join(
             stag_j, rstart_j = m32[:, 0], m32[:, 1]
     with annotate("dj_gather"):
         li = None if vcarry else jnp.where(valid_out, stag_j, L)
-        if joinmode or vfull:
+        if vfull:
             rpos = None  # vfull resolved the right side in-kernel
         elif vmeta or vcarry:
             rpos = jnp.where(valid_out, rpos_direct, S)
@@ -1790,61 +1707,37 @@ def inner_join(
         left_out: dict[int, Column] = {}
         right_out: dict[int, Column] = {}
         li_str = li
-        rrow = None
-        if carry:
-            # Second gather of the SAME pack at the matched refs' merged
-            # positions: payload slots hold the right values there.
-            rrows = packed.at[rpos].get(mode="fill", fill_value=0)
-            key_bits = jnp.where(valid_out, rows[:, 1], 0)
-            kcol = left.columns[left_on[0]]
-            left_out[left_on[0]] = Column(
-                _from_u64(key_bits, kcol.dtype.physical), kcol.dtype
-            )
-            for k, (ci, c) in enumerate(l_carry):
-                bits = jnp.where(valid_out, rows[:, 2 + k], 0)
-                left_out[ci] = Column(_from_u64(bits, c.dtype.physical), c.dtype)
-            for k, (ci, c) in enumerate(r_fixed):
-                bits = jnp.where(valid_out, rrows[:, 2 + k], 0)
-                right_out[ci] = Column(_from_u64(bits, c.dtype.physical), c.dtype)
-            if has_strings:
-                rm32 = jax.lax.bitcast_convert_type(rrows[:, 0], jnp.int32)
-                rrow = jnp.where(valid_out, rm32[:, 0] - jnp.int32(L), R)
-        else:
-            # Right row id: the tag at the matched ref's merged position
-            # (already resolved in-kernel on the pallas-join path).
-            if joinmode:
-                rtag = rtag_direct
+        # Right row id: the tag at the matched ref's merged position.
+        rtag = stag.at[rpos].get(mode="fill", fill_value=L)
+        rrow = jnp.where(valid_out, rtag - jnp.int32(L), R)
+        # capacity-0 tables: gathers from a 0-row operand are
+        # structurally invalid in XLA; the join result is necessarily
+        # all-fill (total == 0), so emit zeros directly (cudf accepts
+        # empty tables: upstream src/distributed_join.cpp:76-82).
+        if l_fixed:
+            if L == 0:
+                lrows = jnp.zeros((out_capacity, len(l_fixed)), jnp.uint64)
             else:
-                rtag = stag.at[rpos].get(mode="fill", fill_value=L)
-            rrow = jnp.where(valid_out, rtag - jnp.int32(L), R)
-            # capacity-0 tables: gathers from a 0-row operand are
-            # structurally invalid in XLA; the join result is necessarily
-            # all-fill (total == 0), so emit zeros directly (cudf accepts
-            # empty tables: upstream src/distributed_join.cpp:76-82).
-            if l_fixed:
-                if L == 0:
-                    lrows = jnp.zeros((out_capacity, len(l_fixed)), jnp.uint64)
-                else:
-                    l_pack = jnp.stack(
-                        [_to_u64(c.data) for _, c in l_fixed], axis=-1
-                    )
-                    lrows = l_pack.at[li].get(mode="fill", fill_value=0)
-                for k, (ci, c) in enumerate(l_fixed):
-                    left_out[ci] = Column(
-                        _from_u64(lrows[:, k], c.dtype.physical), c.dtype
-                    )
-            if r_fixed:
-                if R == 0:
-                    rrows = jnp.zeros((out_capacity, len(r_fixed)), jnp.uint64)
-                else:
-                    r_pack = jnp.stack(
-                        [_to_u64(c.data) for _, c in r_fixed], axis=-1
-                    )
-                    rrows = r_pack.at[rrow].get(mode="fill", fill_value=0)
-                for k, (i, c) in enumerate(r_fixed):
-                    right_out[i] = Column(
-                        _from_u64(rrows[:, k], c.dtype.physical), c.dtype
-                    )
+                l_pack = jnp.stack(
+                    [_to_u64(c.data) for _, c in l_fixed], axis=-1
+                )
+                lrows = l_pack.at[li].get(mode="fill", fill_value=0)
+            for k, (ci, c) in enumerate(l_fixed):
+                left_out[ci] = Column(
+                    _from_u64(lrows[:, k], c.dtype.physical), c.dtype
+                )
+        if r_fixed:
+            if R == 0:
+                rrows = jnp.zeros((out_capacity, len(r_fixed)), jnp.uint64)
+            else:
+                r_pack = jnp.stack(
+                    [_to_u64(c.data) for _, c in r_fixed], axis=-1
+                )
+                rrows = r_pack.at[rrow].get(mode="fill", fill_value=0)
+            for k, (i, c) in enumerate(r_fixed):
+                right_out[i] = Column(
+                    _from_u64(rrows[:, k], c.dtype.physical), c.dtype
+                )
 
         for i, c in enumerate(left.columns):
             if i in l_drop:
@@ -1904,25 +1797,22 @@ def inner_join(
 def prepared_effective_plan(
     *, has_strings: bool = False, n_payload: int = 1
 ) -> JoinPlan:
-    """Kernel plan for a PREPARED join: always packed, never carry —
-    the carry/vcarry/vfull families reshape what the SORT carries, and
-    the prepared build side's sort already happened. Scans/expansion
-    resolve exactly like the regular packed single-key path (vcarry and
-    vfull degrade to vmeta; fused/join interpret-only modes degrade
-    too, since the prepared path keeps the indirect gather family)."""
+    """Kernel plan for a PREPARED join: always packed, and vcarry/vfull
+    degrade to vmeta — they reshape what the SORT carries, and the
+    prepared build side's sort already happened. Scans/expansion
+    otherwise resolve exactly like the regular packed single-key
+    path."""
     base = effective_plan(
         single_int_key=True,
         has_strings=has_strings,
         n_payload=n_payload,
-        carry_payloads=False,
     )
     expand = base.expand
-    interp = "-interpret" if expand.endswith("-interpret") else ""
-    family = expand.split("-interpret")[0]
-    if family not in ("hist", "pallas", "pallas-vmeta"):
+    if not expand.startswith("hist"):
+        interp = "-interpret" if expand.endswith("-interpret") else ""
         expand = "pallas-vmeta" + interp
     scans = base.scans if base.scans.startswith("pallas") else "xla"
-    return JoinPlan(scans, expand, True, False, base.sort)
+    return JoinPlan(scans, expand, True, base.sort)
 
 
 def _anchored_pack_word(
@@ -2158,7 +2048,7 @@ def inner_join_prepared(
 
     Scans and expansion ride the regular packed machinery
     (prepared_effective_plan): fused Pallas scans or the XLA chain,
-    vmeta / merge-path-ranks / histogram expansion — and the right
+    vmeta or histogram expansion — and the right
     payload gathers hit the SORTED resident table directly (the
     prepared words' tags are sorted ranks).
 
@@ -2253,17 +2143,7 @@ def inner_join_prepared(
             )
             rpos = jnp.where(valid_out, rpos_direct, S)
         else:
-            if expand_impl.startswith("pallas"):
-                from .pallas_expand import expand_ranks
-
-                src = jnp.clip(
-                    expand_ranks(csum, out_capacity, interpret=interp),
-                    0, S - 1,
-                )
-            else:
-                src = jnp.clip(
-                    count_leq_arange(csum, out_capacity), 0, S - 1
-                )
+            src = jnp.clip(count_leq_arange(csum, out_capacity), 0, S - 1)
             t = j32 - jax.lax.cummax(jnp.where(_run_starts(src), j32, -1))
             meta = jax.lax.bitcast_convert_type(
                 jnp.stack([stag, run_start], axis=-1), jnp.uint64

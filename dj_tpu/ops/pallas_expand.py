@@ -9,14 +9,19 @@ out_cap-sized random HBM gather; TPU scatters and gathers pay a fixed
 per-ELEMENT cost (ARCHITECTURE.md "phase economics"), which makes these
 the largest non-sort phases at the benchmark's S ~ 2e8.
 
-One kernel factory serves two entry points:
+Four entry points, each a Pallas kernel behind a `lax.cond` fit check
+with the exact XLA formulation as its other branch:
 
 - ``expand_ranks``: the ranks alone (drop-in for count_leq_arange on
-  sorted csum).
-- ``expand_gather``: ranks AND the two int32 metadata planes gathered
-  at them in the same pass (drop-in for the rank + `.at[src].get()`
-  pair). Metadata rides as two int32 planes because Mosaic does not
-  lower 64-bit types — callers pass (stag, run_start) directly.
+  sorted csum); the probe tier's expansion on TPU.
+- ``expand_values`` (DJ_JOIN_EXPAND=pallas-vmeta, the TPU default):
+  ranks, the within-run offset and the (stag, run_start) values at
+  src in one delta-dot pass, no output-sized gathers
+  (_make_vexpand_kernel).
+- ``expand_carry`` (pallas-vcarry): the same kernel expanding the
+  payload planes that ride the sort.
+- ``expand_vfull`` (pallas-vfull): vcarry plus the right side resolved
+  at rpos in-kernel (_make_vfull_kernel).
 
 Method (a merge-path partition of "merge a sorted array with arange"):
 
@@ -24,16 +29,15 @@ Method (a merge-path partition of "merge a sorted array with arange"):
 - Host-graph side, ``jnp.searchsorted`` finds each tile's window
   ``starts[p] = #{csum < p*T_J}`` (P+1 binary searches — fine; it is
   the PER-ELEMENT searchsorted that is banned, see core/search.py).
-- Each program DMAs csum[starts[p] : starts[p]+SPAN] (and, fused, the
-  matching metadata windows) from HBM into VMEM. csum is padded with
-  int32-max sentinels so overruns are safe, and window entries beyond
-  the tile's value range compare False, so no masking is needed.
+- Each program DMAs csum[starts[p] : starts[p]+SPAN] (and the value
+  kernels their matching value windows) from HBM into VMEM. csum is
+  padded with int32-max sentinels so overruns are safe, and entries
+  beyond the tile's value range compare False, so no masking is needed.
 - A block two-pointer walks the tile's LANE-wide j-subtiles: whole
   BLK-entry blocks below the subtile are consumed into a scalar
   ``base`` (initialized to starts[p] — the entries before the window);
   the straddling blocks are counted exactly by a (BLK x LANE)
-  compare-reduce on the VPU. Fused, the window-local ranks then index
-  the metadata planes with an in-VMEM ``jnp.take``.
+  compare-reduce on the VPU.
 
 Cost model: compare work ~ (S/BLK + n_out/LANE) straddle pairs x
 BLK*LANE VPU ops when csum is value-dense (the join's case: csum
@@ -55,21 +59,10 @@ tile the window still holds every entry a valid slot can rank; the
 tail entries past them compare False for every valid j, and the
 straddling group's walk is bounded by the buffer.
 
-Compiled-lowering status (round-4 AOT evidence for real v5e Mosaic,
-probe_mosaic_lower.py, measurements/r04_mosaic_lowering.txt):
-
-- ``expand_ranks`` COMPILES (see _make_ranks_kernel for the lowering
-  constraints it is shaped around), and so does the full
-  ``inner_join`` with DJ_JOIN_EXPAND=pallas — including under
-  shard_map with the vma checker at its default.
-- ``expand_gather`` / ``expand_join`` are INTERPRET-ONLY: their
-  in-kernel metadata gathers need arbitrary in-VMEM gathers, and the
-  TPU ISA has none (Mosaic's lax.gather rule lowers exactly one
-  shape: per-lane tpu.dynamic_gather on a 2-D operand). That is an
-  architectural answer, not a missing rule: on TPU, output-sized
-  gathers belong OUTSIDE the kernel where XLA emits HBM gather loops
-  — which is precisely the "pallas" (ranks-only) mode. The fused
-  modes remain as interpret-mode references for the cost model.
+Every kernel here compiles for a v5e. Values at src are expanded by a
+matmul, never by an in-kernel gather: the TPU ISA has no arbitrary
+in-VMEM gather (Mosaic lowers only a per-lane dynamic_gather on a 2-D
+operand), so output-sized gathers that remain run outside the kernels.
 
 Reference analogue: the gather-map materialization inside cudf's join
 as used per batch (/root/reference/src/distributed_join.cpp:71-83) —
@@ -114,9 +107,9 @@ LANE = 128
 
 # Default MXU dot precision for the exact delta-dot kernels. HIGHEST is
 # hardware-verified row-exact; "high" (3-pass bf16, ~half the MXU cost)
-# may replace it ONLY via scripts/hw/promote.py after the on-chip
-# row-exact gate (the MXU default-precision lesson: interpret mode can
-# never catch a precision break).
+# may replace it ONLY after a paired chip run through the benchmark
+# shows it row-exact and faster (the MXU default-precision lesson:
+# interpret mode can never catch a precision break).
 DEFAULT_PRECISION = "highest"
 
 
@@ -131,167 +124,6 @@ def _fallback(xla_path):
             return xla_path(operand)
 
     return run
-
-
-def _make_kernel(
-    t_j: int, span: int, blk: int, lane: int, mode: str, margin: int = 0
-):
-    """Kernel factory for the INTERPRET-ONLY fused modes (ranks mode
-    lives in _make_ranks_kernel, reshaped for compiled Mosaic — the
-    in-kernel gathers below have no TPU ISA equivalent, see module
-    docstring "Compiled-lowering status").
-
-    mode="meta":  out (src[j], lo[src'], hi[src']).
-    mode="join":  out (lo[src'], lo[rpos']) — the join's (stag_j, rtag):
-      the window additionally extends ``margin`` entries BELOW starts[p]
-      so matched refs of runs straddling the window's left edge are
-      resident; t = j - csum[src-1] comes straight from the csum window
-      (the first output of merged row i is csum[i-1]), so no scan or
-      carry is needed; rpos = run_start (hi plane) + t.
-    """
-    assert mode in ("meta", "join"), mode
-    span_m = span + (margin if mode == "join" else 0)
-    nblk = span_m // blk
-    assert span_m % blk == 0
-
-    def kernel(starts_ref, csum_hbm, *rest):
-        if mode == "meta":
-            lo_hbm, hi_hbm, src_ref, lo_ref, hi_ref = rest[:5]
-            buf, lo_buf, hi_buf, sems = rest[5:]
-        else:
-            lo_hbm, hi_hbm, stag_ref, rtag_ref = rest[:4]
-            buf, lo_buf, hi_buf, sems = rest[4:]
-
-        p = pl.program_id(0)
-        start = starts_ref[p]
-        # Join mode reads below the window for left-straddling runs.
-        start2 = jnp.maximum(start - margin, 0) if mode == "join" else start
-
-        # Window DMA(s): HBM -> VMEM, dynamic start, static size.
-        d0 = pltpu.make_async_copy(
-            csum_hbm.at[pl.ds(start2, span_m)], buf, sems.at[0]
-        )
-        d0.start()
-        d1 = pltpu.make_async_copy(
-            lo_hbm.at[pl.ds(start2, span_m)], lo_buf, sems.at[1]
-        )
-        d2 = pltpu.make_async_copy(
-            hi_hbm.at[pl.ds(start2, span_m)], hi_buf, sems.at[2]
-        )
-        d1.start()
-        d2.start()
-        d1.wait()
-        d2.wait()
-        d0.wait()
-
-        # csum is SORTED, so a block's max is its last element — read it
-        # straight from the ref.
-        if mode == "join":
-            csum_val = buf[:]
-        lo_val = lo_buf[:]
-        hi_val = hi_buf[:]
-        j0 = p * t_j
-
-        def subtile(jb, carry):
-            i_blk, base = carry
-            jmin = j0 + jb * lane
-            jmax = jmin + (lane - 1)
-
-            # Consume whole blocks entirely <= jmin: every entry counts
-            # for every j in this and all later subtiles.
-            def adv_cond(c):
-                ib, _ = c
-                # Clamp: logical_and does not short-circuit, so the
-                # read must stay in-bounds even at ib == nblk.
-                ibc = jnp.minimum(ib, nblk - 1)
-                return jnp.logical_and(
-                    ib < nblk, buf[(ibc + 1) * blk - 1] <= jmin
-                )
-
-            def adv_body(c):
-                ib, b = c
-                return ib + 1, b + blk
-
-            i_blk, base = jax.lax.while_loop(
-                adv_cond, adv_body, (i_blk, base)
-            )
-
-            # Straddling blocks: exact count by compare-reduce. A block
-            # contributes iff its min (first entry, sorted) <= jmax.
-            jvec = jmin + jax.lax.broadcasted_iota(jnp.int32, (1, lane), 1)
-
-            def cmp_cond(c):
-                k, _ = c
-                kc = jnp.minimum(k, nblk - 1)  # see adv_cond
-                return jnp.logical_and(k < nblk, buf[kc * blk] <= jmax)
-
-            def cmp_body(c):
-                k, acc = c
-                b = buf[pl.ds(k * blk, blk)].reshape(blk, 1)
-                acc = acc + jnp.sum(
-                    (b <= jvec).astype(jnp.int32),
-                    axis=0,
-                    keepdims=True,
-                    dtype=jnp.int32,
-                )
-                return k + 1, acc
-
-            _, acc = jax.lax.while_loop(
-                cmp_cond, cmp_body, (i_blk, jnp.zeros((1, lane), jnp.int32))
-            )
-            src = (base + acc).reshape(lane)  # global rank
-            # Window-local gather index; clips cover the j >= total
-            # tail (unspecified, masked by the caller).
-            # int32 clip bounds: python-int bounds promote to int64
-            # under x64, which Mosaic cannot lower (see fori note).
-            local = jnp.clip(
-                src - start2, jnp.int32(0), jnp.int32(span_m - 1)
-            )
-            off = jb * lane
-            if mode == "meta":
-                src_ref[pl.ds(off, lane)] = src
-                lo_ref[pl.ds(off, lane)] = jnp.take(lo_val, local, axis=0)
-                hi_ref[pl.ds(off, lane)] = jnp.take(hi_val, local, axis=0)
-            else:  # join
-                jv = jvec.reshape(lane)
-                # Match offset within the run: merged row i's first
-                # output slot is csum[i-1] (0 for i == 0).
-                csum_ex = jnp.where(
-                    src > 0,
-                    jnp.take(
-                        csum_val,
-                        jnp.clip(
-                            local - 1, jnp.int32(0), jnp.int32(span_m - 1)
-                        ),
-                        axis=0,
-                    ),
-                    jnp.int32(0),
-                )
-                t = jv - csum_ex
-                run_start = jnp.take(hi_val, local, axis=0)
-                rpos_local = jnp.clip(
-                    run_start + t - start2,
-                    jnp.int32(0),
-                    jnp.int32(span_m - 1),
-                )
-                stag_ref[pl.ds(off, lane)] = jnp.take(
-                    lo_val, local, axis=0
-                )
-                rtag_ref[pl.ds(off, lane)] = jnp.take(
-                    lo_val, rpos_local, axis=0
-                )
-            return i_blk, base
-
-        # int32 loop bounds: python-int bounds trace an int64 induction
-        # variable under x64, and int64 arithmetic cannot lower in
-        # Mosaic (its convert rule recurses) — interpret mode never
-        # noticed (round-4 AOT lowering probe, probe_mosaic_lower.py).
-        jax.lax.fori_loop(
-            jnp.int32(0), jnp.int32(t_j // lane), subtile,
-            (jnp.int32(0), start2),
-        )
-
-    return kernel
 
 
 def _make_ranks_kernel(t_j: int, span: int, blk: int, lane: int):
@@ -400,8 +232,8 @@ def _make_ranks_kernel(t_j: int, span: int, blk: int, lane: int):
     return kernel
 
 
-def _run_pallas(
-    arrays_padded,  # (csum32,) or (csum32, lo, hi) — length S + pad
+def _run_ranks(
+    padded,  # csum32, sentinel-padded by span + blk
     starts,
     n_pad: int,
     t_j: int,
@@ -409,51 +241,31 @@ def _run_pallas(
     blk: int,
     lane: int,
     interpret: bool,
-    mode: str = None,
-    margin: int = 0,
 ):
-    if mode is None:
-        mode = "meta" if len(arrays_padded) == 3 else "ranks"
-    n_out_arrays = {"ranks": 1, "meta": 3, "join": 2}[mode]
-    span_m = span + (margin if mode == "join" else 0)
     # Inside shard_map (the production pipeline) avals carry a `vma`
     # (varying-over-mesh-axes) set and check_vma=True requires outputs
     # to declare theirs; inherit the inputs'.
-    vma = jax.typeof(arrays_padded[0]).vma
-    out_block = pl.BlockSpec((t_j,), lambda p, starts: (p,))
-    if mode == "ranks":
-        # Mosaic-lowerable kernel: aligned window + 2-D accumulator
-        # (see _make_ranks_kernel; buffer carries one alignment block).
-        kernel = _make_ranks_kernel(t_j, span, blk, lane)
-        scratch = [
-            pltpu.VMEM((span + blk,), jnp.int32),
-            pltpu.VMEM((t_j // lane, lane), jnp.int32),
-            pltpu.SemaphoreType.DMA,
-        ]
-    else:
-        kernel = _make_kernel(t_j, span, blk, lane, mode, margin)
-        scratch = [pltpu.VMEM((span_m,), jnp.int32)] * len(arrays_padded) + [
-            pltpu.SemaphoreType.DMA((3 if len(arrays_padded) == 3 else 1,))
-        ]
+    vma = jax.typeof(padded).vma
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_pad // t_j,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(arrays_padded),
-        out_specs=tuple([out_block] * n_out_arrays)
-        if n_out_arrays > 1
-        else out_block,
-        scratch_shapes=scratch,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((t_j,), lambda p, starts: (p,)),
+        # Aligned window (one extra alignment block) + the 2-D
+        # accumulator, see _make_ranks_kernel.
+        scratch_shapes=[
+            pltpu.VMEM((span + blk,), jnp.int32),
+            pltpu.VMEM((t_j // lane, lane), jnp.int32),
+            pltpu.SemaphoreType.DMA,
+        ],
     )
-    out_shape = jax.ShapeDtypeStruct((n_pad,), jnp.int32, vma=vma)
     return pl.pallas_call(
-        kernel,
-        out_shape=tuple([out_shape] * n_out_arrays)
-        if n_out_arrays > 1
-        else out_shape,
+        _make_ranks_kernel(t_j, span, blk, lane),
+        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.int32, vma=vma),
         grid_spec=grid_spec,
         interpret=interpret,
-        name=f"expand_{mode}",
-    )(starts, *arrays_padded)
+        name="expand_ranks",
+    )(starts, padded)
 
 
 def _window_starts(csum: jax.Array, n_out: int, t_j: int):
@@ -534,89 +346,14 @@ def _expand_ranks_jit(csum, n_out, t_j, span, blk, lane, interpret):
         # branch so the histogram fallback never pays the copy. The
         # extra blk covers the aligned-down DMA window.
         padded = _pad32(_csum32(csum), span + blk, 2**31 - 1)
-        out = _run_pallas(
-            (padded,), starts, n_pad, t_j, span, blk, lane, interpret
+        out = _run_ranks(
+            padded, starts, n_pad, t_j, span, blk, lane, interpret
         )
         return out[:n_out]
 
     @_fallback
     def xla_path(_):
         return count_leq_arange(csum, n_out)
-
-    return jax.lax.cond(fits, pallas_path, xla_path, None)
-
-
-def expand_gather(
-    csum: jax.Array,
-    meta_lo: jax.Array,
-    meta_hi: jax.Array,
-    n_out: int,
-    t_j: int | None = None,
-    span: int | None = None,
-    blk: int | None = None,
-    lane: int | None = None,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Fused (src, meta_lo[src'], meta_hi[src']) with
-    src' = clip(src, 0, S-1), for src[j] = #{i : csum[i] <= j}.
-
-    Drop-in for the rank + two `.at[src'].get()` gathers for SORTED
-    csum and int32 metadata planes — sequential window DMAs and in-VMEM
-    takes instead of an S-scatter plus out_cap-sized random HBM
-    gathers. Falls back to exactly the XLA formulation under `lax.cond`
-    when a window overflows the span. Tail slots (j >= csum[-1]) are
-    UNSPECIFIED (the branches differ there); callers must mask them.
-    """
-    geo = (
-        T_J2 if t_j is None else t_j,
-        SPAN2 if span is None else span,
-        BLK if blk is None else blk,
-        LANE if lane is None else lane,
-    )
-    return _expand_gather_jit(csum, meta_lo, meta_hi, n_out, *geo, interpret)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_out", "t_j", "span", "blk", "lane", "interpret"),
-)
-def _expand_gather_jit(
-    csum, meta_lo, meta_hi, n_out, t_j, span, blk, lane, interpret
-):
-    from ..core.search import count_leq_arange
-
-    S = csum.shape[0]
-    assert meta_lo.shape == (S,) and meta_lo.dtype == jnp.int32
-    assert meta_hi.shape == (S,) and meta_hi.dtype == jnp.int32
-    empty = jnp.zeros((0,), jnp.int32)
-    if n_out == 0:
-        return empty, empty, empty
-    assert n_out < 2**31 - 1, "int32 rank/value domain"
-    assert span % blk == 0 and t_j % lane == 0
-    n_pad, starts, spans = _window_starts(csum, n_out, t_j)
-    # STRICT: the gather index can reach the window's span exactly, so
-    # require span_p < span (one slot of slack), unlike expand_ranks.
-    fits = jnp.max(spans) < span
-
-    def pallas_path(_):
-        padded = _pad32(_csum32(csum), span, 2**31 - 1)
-        lo_p = _pad32(meta_lo, span, 0)
-        hi_p = _pad32(meta_hi, span, 0)
-        src, lo, hi = _run_pallas(
-            (padded, lo_p, hi_p), starts, n_pad, t_j, span, blk, lane,
-            interpret,
-        )
-        return src[:n_out], lo[:n_out], hi[:n_out]
-
-    @_fallback
-    def xla_path(_):
-        src = count_leq_arange(csum, n_out)
-        clipped = jnp.clip(src, 0, S - 1)
-        return (
-            src,
-            meta_lo.at[clipped].get(mode="fill", fill_value=0),
-            meta_hi.at[clipped].get(mode="fill", fill_value=0),
-        )
 
     return jax.lax.cond(fits, pallas_path, xla_path, None)
 
@@ -632,9 +369,8 @@ def _make_vexpand_kernel(
     """COMPILED fused expansion: ranks + N-value expansion, no gathers.
 
     Replaces {expand_ranks + the t-scan + output-sized metadata
-    gathers} with one kernel. The in-VMEM gather that kept the old
-    fused modes interpret-only is eliminated by an algebraic identity
-    + an exact MXU dot:
+    gathers} with one kernel. An in-VMEM gather, which the TPU ISA
+    lacks, is replaced by an algebraic identity + an exact MXU dot:
 
       For SORTED csum, ``w <= src[j]``  <=>  ``csum_ex[w] <= j``
       (src[j] = #{csum <= j}; the w-th smallest is <= j iff the count
@@ -1274,12 +1010,6 @@ def _make_vfull_kernel(
     return kernel
 
 
-# Margin of window entries DMA'd below starts[p] in join mode: covers
-# matched refs of runs straddling a window's left edge. Runs longer
-# than this fall back to the XLA path (max_run is checked).
-MARGIN = 16_384
-
-
 # vfull margin blocks below each window: bounds max_run (the longest
 # matched run's ref span); 2 blocks cover unique-key and dup-heavy
 # benchmark workloads, while a pathological run falls back to the XLA
@@ -1420,99 +1150,5 @@ def _expand_vfull_jit(
             p.at[rpos].get(mode="fill", fill_value=0) for p in pay_planes
         )
         return lp + kj + rp
-
-    return jax.lax.cond(fits, pallas_path, xla_path, None)
-
-
-def expand_join(
-    csum: jax.Array,
-    stag: jax.Array,
-    run_start: jax.Array,
-    max_run: jax.Array,
-    n_out: int,
-    t_j: int | None = None,
-    span: int | None = None,
-    blk: int | None = None,
-    lane: int | None = None,
-    margin: int | None = None,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Fully-fused expansion: (stag_j, rtag) per output slot.
-
-    Equivalent (for valid slots j < csum[-1]) to the XLA chain
-    ``src = count_leq_arange(csum, n_out); t = j - csum[src-1];
-    stag_j = stag[src]; rtag = stag[run_start[src] + t]`` — the
-    rank-compute, the within-run offset, and BOTH metadata gathers in
-    one kernel pass. ``max_run`` must bound pos - run_start over rows
-    with matches (the caller computes it in one reduce); windows extend
-    ``margin`` entries left so straddling runs' refs are resident, and
-    ``max_run >= margin`` (or a window overflow) falls back to the XLA
-    chain under `lax.cond`. Tail slots are unspecified; callers mask.
-    """
-    geo = (
-        T_J2 if t_j is None else t_j,
-        SPAN2 if span is None else span,
-        BLK if blk is None else blk,
-        LANE if lane is None else lane,
-        MARGIN if margin is None else margin,
-    )
-    return _expand_join_jit(csum, stag, run_start, max_run, n_out, *geo,
-                            interpret)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "n_out", "t_j", "span", "blk", "lane", "margin", "interpret"
-    ),
-)
-def _expand_join_jit(
-    csum, stag, run_start, max_run, n_out, t_j, span, blk, lane, margin,
-    interpret,
-):
-    from ..core.search import count_leq_arange
-
-    S = csum.shape[0]
-    assert stag.shape == (S,) and stag.dtype == jnp.int32
-    assert run_start.shape == (S,) and run_start.dtype == jnp.int32
-    empty = jnp.zeros((0,), jnp.int32)
-    if n_out == 0:
-        return empty, empty
-    assert n_out < 2**31 - 1, "int32 rank/value domain"
-    assert (span + margin) % blk == 0 and t_j % lane == 0
-    n_pad, starts, spans = _window_starts(csum, n_out, t_j)
-    fits = jnp.logical_and(
-        jnp.max(spans) < span, max_run < margin
-    )
-
-    def pallas_path(_):
-        pad = span + margin
-        padded = _pad32(_csum32(csum), pad, 2**31 - 1)
-        lo_p = _pad32(stag, pad, 0)
-        hi_p = _pad32(run_start, pad, 0)
-        stag_j, rtag = _run_pallas(
-            (padded, lo_p, hi_p), starts, n_pad, t_j, span, blk, lane,
-            interpret, mode="join", margin=margin,
-        )
-        return stag_j[:n_out], rtag[:n_out]
-
-    @_fallback
-    def xla_path(_):
-        src = jnp.clip(count_leq_arange(csum, n_out), 0, S - 1)
-        j32 = jnp.arange(n_out, dtype=jnp.int32)
-        csum_ex = jnp.where(
-            src > 0,
-            _csum32(csum).at[jnp.maximum(src - 1, 0)].get(
-                mode="fill", fill_value=0
-            ),
-            0,
-        )
-        t = j32 - csum_ex
-        stag_j = stag.at[src].get(mode="fill", fill_value=0)
-        rs = run_start.at[src].get(mode="fill", fill_value=0)
-        rtag = stag.at[jnp.clip(rs + t, 0, S - 1)].get(
-            mode="fill", fill_value=0
-        )
-        return stag_j, rtag
 
     return jax.lax.cond(fits, pallas_path, xla_path, None)

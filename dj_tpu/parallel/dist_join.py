@@ -848,8 +848,9 @@ def _build_join_fn(
         # varying-mesh-axes checker (jax suggests check_vma=False as the
         # workaround); DJ_SHARDMAP_CHECK_VMA=0 disables it for those
         # runs (env_key keeps the cache honest). COMPILED Mosaic needs
-        # no knob: the 8-dev join with DJ_JOIN_EXPAND=pallas AOT-
-        # compiles for v5e with the checker at this default (round 4).
+        # no knob: the 4-chip join step with the TPU plan
+        # (DJ_JOIN_EXPAND=pallas-vmeta) AOT-compiles for v5e with the
+        # checker at this default (djbench/aot_tpch.py).
         check_vma=(env_key[_TRACE_ENV_VARS.index("DJ_SHARDMAP_CHECK_VMA")]
                    or "1") == "1",
     )

@@ -92,7 +92,7 @@ def tiny_pallas_geometry(monkeypatch):
     trace time and is NOT part of the join build-cache key, so a trace
     made with tiny tiles must not leak to later callers).
 
-    Usage: ``tiny_pallas_geometry("pallas-join-interpret")`` — applies
+    Usage: ``tiny_pallas_geometry("pallas-vmeta-interpret")`` — applies
     the geometry patches and the env knobs for the given impl.
     """
     import dj_tpu.ops.pallas_expand as px
@@ -104,7 +104,6 @@ def tiny_pallas_geometry(monkeypatch):
         monkeypatch.setattr(px, "T_J2", 256)
         monkeypatch.setattr(px, "SPAN2", 1024)
         monkeypatch.setattr(px, "BLK", 64)
-        monkeypatch.setattr(px, "MARGIN", 256)
         monkeypatch.setenv("DJ_JOIN_EXPAND", impl)
         monkeypatch.setenv("DJ_SHARDMAP_CHECK_VMA", "0")
 

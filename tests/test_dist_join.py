@@ -199,7 +199,8 @@ def test_duplicate_build_keys():
 
 @pytest.mark.parametrize(
     "impl",
-    ["pallas-interpret", "pallas-fused-interpret", "pallas-join-interpret"],
+    ["pallas-vmeta-interpret", "pallas-vcarry-interpret",
+     "pallas-vfull-interpret"],
 )
 def test_distributed_join_pallas_expand(impl, tiny_pallas_geometry):
     """The Pallas expansion paths inside the full shard_map'd pipeline

@@ -118,6 +118,16 @@ def test_effective_plan_multi_key_packed(monkeypatch):
     assert not plan.packed and plan.scans == "xla"
 
 
+def test_unknown_expand_value_raises(monkeypatch):
+    """A DJ_JOIN_EXPAND value outside the knob's choices (here the
+    deleted "pallas-fused") raises, naming the choices, instead of
+    running the hist plan in silence."""
+    monkeypatch.setenv("DJ_JOIN_EXPAND", "pallas-fused")
+    t = T.from_arrays(np.arange(8, dtype=np.int64))
+    with pytest.raises(ValueError, match="pallas-vmeta"):
+        inner_join(t, t, [0], [0])
+
+
 # ---------------------------------------------------------------------
 # packed multi-key joins vs the multi-key oracle
 # ---------------------------------------------------------------------

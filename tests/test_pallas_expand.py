@@ -1,7 +1,10 @@
-"""expand_ranks (Pallas merge-path expansion) vs the histogram oracle.
+"""The Pallas expansion kernels (ops/pallas_expand.py) vs their oracles.
 
-Runs the kernel in interpreter mode with shrunken tile geometry; the
-contract is exact equality with count_leq_arange for sorted csum.
+Runs the kernels in interpreter mode with shrunken tile geometry. The
+contract: expand_ranks equals count_leq_arange for sorted csum, and
+expand_values / expand_carry / expand_vfull equal the numpy values at
+src (and, for vfull, at rpos) on every valid slot, whichever branch of
+their fit check runs.
 """
 
 import numpy as np
@@ -84,169 +87,18 @@ def test_n_out_zero():
     assert got.shape == (0,)
 
 
-def _check_fused(csum, n_out):
-    from dj_tpu.ops.pallas_expand import expand_gather
-
-    S = len(csum)
-    lo = (np.arange(S) * 7 + 3).astype(np.int32)
-    hi = (np.arange(S) * 13 + 1).astype(np.int32)
-    src, glo, ghi = expand_gather(
-        jnp.asarray(csum), jnp.asarray(lo), jnp.asarray(hi), n_out, **GEO
-    )
-    src, glo, ghi = np.asarray(src), np.asarray(glo), np.asarray(ghi)
-    want_src = _oracle(csum, n_out)
-    clipped = np.clip(want_src, 0, S - 1)
-    total = int(csum[-1]) if S else 0
-    valid = np.arange(n_out) < total
-    np.testing.assert_array_equal(src[valid], want_src[valid])
-    np.testing.assert_array_equal(glo[valid], lo[clipped][valid])
-    np.testing.assert_array_equal(ghi[valid], hi[clipped][valid])
-
-
-def test_fused_uniform_dense():
-    rng = np.random.default_rng(4)
-    cnt = rng.integers(0, 3, 3000)
-    csum = np.cumsum(cnt).astype(np.int64)
-    _check_fused(csum, 1024)
-    _check_fused(csum, 1000)
-
-
-def test_fused_giant_run_and_skew_fallback():
-    csum = np.concatenate(
-        [np.zeros(100, np.int64), np.full(50, 700, np.int64)]
-    )
-    _check_fused(csum, 512)
-    # skew: window overflow -> XLA fallback branch
-    csum2 = np.concatenate(
-        [np.zeros(3000, np.int64), np.arange(100, dtype=np.int64) + 5]
-    )
-    _check_fused(csum2, 256)
-
-
 @pytest.mark.parametrize(
-    "impl", ["pallas-fused-interpret", "pallas-join-interpret"]
+    "impl",
+    ["pallas-vmeta-interpret", "pallas-vcarry-interpret",
+     "pallas-vfull-interpret"],
 )
-def test_inner_join_pallas_fused_integration(impl, tiny_pallas_geometry):
+def test_inner_join_pallas_expand_integration(impl, tiny_pallas_geometry):
+    """inner_join's Pallas expansion branches end to end (shrunken
+    geometry so interpret mode stays fast)."""
     from dj_tpu.core import table as T
     from dj_tpu.ops.join import inner_join
 
     tiny_pallas_geometry(impl)
-
-    rng = np.random.default_rng(11)
-    lk = rng.integers(0, 60, 400).astype(np.int64)
-    rk = rng.integers(0, 60, 50).astype(np.int64)
-    lp = np.arange(400, dtype=np.int64)
-    rp = np.arange(50, dtype=np.int64) + 100
-    result, total = inner_join(
-        T.from_arrays(lk, lp), T.from_arrays(rk, rp), [0], [0],
-        out_capacity=2048,
-    )
-    n = int(total)
-    got = sorted(
-        zip(
-            np.asarray(result.columns[0].data)[:n].tolist(),
-            np.asarray(result.columns[1].data)[:n].tolist(),
-            np.asarray(result.columns[2].data)[:n].tolist(),
-        )
-    )
-    want = sorted(
-        (int(k), int(p), int(q))
-        for k, p in zip(lk, lp)
-        for k2, q in zip(rk, rp)
-        if k == k2
-    )
-    assert got == want
-
-
-def _check_join_mode(csum, stag, run_start, n_out, margin=256):
-    """expand_join vs the straight XLA chain oracle."""
-    from dj_tpu.ops.pallas_expand import expand_join
-
-    S = len(csum)
-    max_run = 0
-    prev = 0
-    for i in range(S):
-        if csum[i] > prev:  # cnt > 0
-            max_run = max(max_run, i - run_start[i])
-        prev = csum[i]
-    got_stag, got_rtag = expand_join(
-        jnp.asarray(csum),
-        jnp.asarray(stag, dtype=jnp.int32),
-        jnp.asarray(run_start, dtype=jnp.int32),
-        jnp.int32(max_run),
-        n_out,
-        t_j=256, span=1024, blk=64, lane=128, margin=margin,
-        interpret=True,
-    )
-    got_stag, got_rtag = np.asarray(got_stag), np.asarray(got_rtag)
-    src = _oracle(csum, n_out)
-    clipped = np.clip(src, 0, S - 1)
-    csum_ex = np.where(src > 0, np.asarray(csum)[np.maximum(src - 1, 0)], 0)
-    t = np.arange(n_out) - csum_ex
-    rpos = np.clip(np.asarray(run_start)[clipped] + t, 0, S - 1)
-    total = int(csum[-1]) if S else 0
-    valid = np.arange(n_out) < total
-    np.testing.assert_array_equal(got_stag[valid], stag[clipped][valid])
-    np.testing.assert_array_equal(got_rtag[valid], stag[rpos][valid])
-
-
-def test_join_mode_duplicate_runs():
-    """Runs with several refs and several queries: t>0 slots must pick
-    successive refs from the run start."""
-    # merged layout per run: [refs..., queries...]; stag = merged tag.
-    # run A: 2 refs + 2 queries (each query matches both refs),
-    # run B: 1 ref + 1 query, run C: 3 queries, 0 refs (cnt=0).
-    run_lens = [(2, 2), (1, 1), (0, 3)]
-    csum, stag, run_start = [], [], []
-    pos = 0
-    out_total = 0
-    for nref, nq in run_lens:
-        start = pos
-        for r in range(nref):
-            csum.append(out_total)
-            stag.append(1000 + pos)  # "ref tag" = 1000+merged pos
-            run_start.append(start)
-            pos += 1
-        for q in range(nq):
-            out_total += nref
-            csum.append(out_total)
-            stag.append(pos)  # "query tag" = merged pos
-            run_start.append(start)
-            pos += 1
-    csum = np.asarray(csum, np.int64)
-    stag = np.asarray(stag, np.int32)
-    run_start = np.asarray(run_start, np.int32)
-    _check_join_mode(csum, stag, run_start, 256)
-
-
-def test_join_mode_random():
-    rng = np.random.default_rng(23)
-    S = 2000
-    cnt = rng.integers(0, 3, S) * (rng.random(S) < 0.4)
-    csum = np.cumsum(cnt).astype(np.int64)
-    stag = rng.integers(0, 10000, S).astype(np.int32)
-    # synthetic run_start: nondecreasing positions within 8 of i
-    run_start = (np.arange(S) - rng.integers(0, 8, S)).clip(0).astype(np.int32)
-    _check_join_mode(csum, stag, run_start, 768)
-
-
-def test_join_mode_margin_fallback():
-    """max_run >= margin forces the XLA branch; results identical."""
-    S = 600
-    cnt = np.ones(S, np.int64)
-    csum = np.cumsum(cnt)
-    stag = (np.arange(S) * 3).astype(np.int32)
-    run_start = np.zeros(S, np.int32)  # one giant run
-    _check_join_mode(csum, stag, run_start, 512, margin=64)
-
-
-def test_inner_join_pallas_expand_integration(tiny_pallas_geometry):
-    """inner_join's DJ_JOIN_EXPAND=pallas-interpret branch end to end
-    (shrunken geometry so interpret mode stays fast)."""
-    from dj_tpu.core import table as T
-    from dj_tpu.ops.join import inner_join
-
-    tiny_pallas_geometry("pallas-interpret")
 
     rng = np.random.default_rng(7)
     lk = rng.integers(0, 80, 500).astype(np.int64)
@@ -369,6 +221,110 @@ def test_expand_values_fallback_on_wide_window():
     np.testing.assert_array_equal(np.asarray(got_rpos)[valid], want_rpos[valid])
 
 
+def _vfull_run(cnt, run_start, n_out, margin_blocks=2):
+    """(got, want): expand_vfull's (lpay.., klo, khi, rpay..) on one
+    u64 payload (two int32 planes) and random key planes, and their
+    numpy oracle: payloads at src, key and payloads at rpos."""
+    from dj_tpu.ops.pallas_expand import expand_vfull
+
+    rng = np.random.default_rng(13)
+    S = len(cnt)
+    cnt = np.asarray(cnt, np.int64)
+    run_start = np.asarray(run_start, np.int32)
+    csum = np.cumsum(cnt)
+    p0, p1, klo, khi = (
+        rng.integers(-(2**31), 2**31 - 1, S, dtype=np.int64).astype(np.int32)
+        for _ in range(4)
+    )
+    max_run = int(np.max(np.where(cnt > 0, np.arange(S) - run_start, 0)))
+    got = expand_vfull(
+        jnp.asarray(csum), jnp.asarray(cnt), jnp.asarray(run_start),
+        (jnp.asarray(p0), jnp.asarray(p1)), jnp.asarray(klo),
+        jnp.asarray(khi), jnp.int32(max_run), n_out,
+        margin_blocks=margin_blocks, **GEO,
+    )
+    j = np.arange(n_out)
+    srcc = np.clip(np.searchsorted(csum, j, side="right"), 0, S - 1)
+    rpos = np.clip(run_start[srcc] + (j - (csum - cnt)[srcc]), 0, S - 1)
+    want = (p0[srcc], p1[srcc], klo[rpos], khi[rpos], p0[rpos], p1[rpos])
+    return got, want
+
+
+def _merged_runs(run_lens):
+    """(cnt, run_start) of a merged order laid out [refs..., queries...]
+    per key run: every query of a run matches each of its refs."""
+    cnt, run_start = [], []
+    for nref, nq in run_lens:
+        start = len(cnt)
+        cnt += [0] * nref + [nref] * nq
+        run_start += [start] * (nref + nq)
+    return np.asarray(cnt, np.int64), np.asarray(run_start, np.int32)
+
+
+def _expansion_case(case):
+    """(entry, cnt, run_start, n_out, kwargs, engages) for one csum
+    shape: uniform dense counts, one giant run, skew wider than the
+    window, merged duplicate runs, random short runs, and a run longer
+    than vfull's margin."""
+    if case == "uniform_dense":
+        cnt = np.random.default_rng(4).integers(0, 3, 3000)
+        return "values", cnt, None, 1000, {}, True
+    if case == "giant_run":  # one row produces every output
+        cnt = np.zeros(150, np.int64)
+        cnt[100] = 700
+        return "values", cnt, None, 512, {}, True
+    if case == "skew_fallback":  # 3000 entries share one value window
+        cnt = np.concatenate([np.zeros(3000, np.int64), [5], np.ones(99)])
+        return "values", cnt, None, 256, {}, False
+    if case == "duplicate_runs":  # refs more than a block below queries
+        cnt, run_start = _merged_runs([(70, 2), (3, 4)] * 12)
+        return "vfull", cnt, run_start, 2048, {}, True
+    if case == "random_runs":
+        rng = np.random.default_rng(23)
+        cnt = rng.integers(0, 3, 2000) * (rng.random(2000) < 0.4)
+        return "values", cnt, None, 768, {}, True
+    # "margin_fallback": one run of 600 matched rows against a 64-entry
+    # margin (one block) sends vfull to the XLA branch.
+    cnt, run_start = _merged_runs([(1, 599)])
+    return "vfull", cnt, run_start, 512, {"margin_blocks": 1}, False
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["uniform_dense", "giant_run", "skew_fallback", "duplicate_runs",
+     "random_runs", "margin_fallback"],
+)
+def test_value_expansion_oracle(case, expand_fallback_runs):
+    """expand_values / expand_vfull vs their numpy oracles on the
+    valid slots, and the fit check takes the branch the shape calls
+    for."""
+    from dj_tpu.ops.pallas_expand import expand_values
+
+    entry, cnt, run_start, n_out, kw, engages = _expansion_case(case)
+    S = len(cnt)
+    total = int(np.sum(cnt))
+    if entry == "values":
+        rng = np.random.default_rng(5)
+        stag = rng.integers(0, 10_000, S).astype(np.int32)
+        # synthetic run_start: nondecreasing positions within 8 of i
+        run_start = (np.arange(S) - rng.integers(0, 8, S)).clip(0)
+        run_start = run_start.astype(np.int32)
+        want_stag, want_rpos, _ = _values_oracle(cnt, stag, run_start, n_out)
+        got = expand_values(
+            jnp.asarray(np.cumsum(cnt)), jnp.asarray(cnt), jnp.asarray(stag),
+            jnp.asarray(run_start), n_out, **VGEO,
+        )
+        want = (want_stag, want_rpos)
+    else:
+        got, want = _vfull_run(cnt, run_start, n_out, **kw)
+    got = [np.asarray(g) for g in got]
+    jax.effects_barrier()
+    assert (len(expand_fallback_runs) == 0) == engages
+    valid = np.arange(n_out) < total
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g[valid], w[valid])
+
+
 # ---------------------------------------------------------------------
 # The fit is judged on the valid slots (j < csum[-1]) only
 # ---------------------------------------------------------------------
@@ -400,9 +356,7 @@ def _tail_case(case):
 def _run_entry(entry, cnt, n_out):
     """(got, want): one entry point's outputs and their numpy oracle
     (valid slots only are specified)."""
-    from dj_tpu.ops.pallas_expand import (
-        expand_carry, expand_gather, expand_values,
-    )
+    from dj_tpu.ops.pallas_expand import expand_carry, expand_values
 
     rng = np.random.default_rng(5)
     S = len(cnt)
@@ -416,8 +370,10 @@ def _run_entry(entry, cnt, n_out):
     jc, ja, jb = jnp.asarray(csum), jnp.asarray(a), jnp.asarray(b)
     if entry == "ranks":
         return (expand_ranks(jc, n_out, **GEO),), (src,)
-    if entry == "gather":
-        return expand_gather(jc, ja, jb, n_out, **GEO), (src, a[srcc], b[srcc])
+    if entry == "vfull":
+        # Each row's matched refs sit just below it, as in a merged
+        # order: run_start = i - cnt keeps every run inside the margin.
+        return _vfull_run(cnt, np.maximum(np.arange(S) - cnt, 0), n_out)
     if entry == "values":
         got = expand_values(jc, jnp.asarray(cnt), ja, jb, n_out, **VGEO)
         return got, (a[srcc], rpos)
@@ -428,7 +384,7 @@ def _run_entry(entry, cnt, n_out):
 @pytest.mark.parametrize(
     "case", ["padded_tail", "tile_boundary", "zero_match", "wide_valid"]
 )
-@pytest.mark.parametrize("entry", ["ranks", "gather", "values", "carry"])
+@pytest.mark.parametrize("entry", ["ranks", "vfull", "values", "carry"])
 def test_fit_judged_on_valid_slots(entry, case, expand_fallback_runs):
     """A zero-count tail wider than the span no longer sends the
     expansion to the XLA branch: the Pallas branch runs and the valid

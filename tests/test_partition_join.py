@@ -1,6 +1,7 @@
 """Unit tests: hash_partition contract and local inner_join vs numpy oracle."""
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from dj_tpu.core import table as T
@@ -311,10 +312,12 @@ def test_string_column_take():
     assert T.to_strings(taken) == [b"gamma", b"alpha", b"d"]
 
 
-def test_inner_join_carry_equals_indirect():
-    """The two data-movement plans must produce identical results
-    (including duplicates, valid-count masking, and mixed payload
-    widths)."""
+def test_inner_join_tpu_plan_equals_cpu_plan(
+    tiny_pallas_geometry, expand_fallback_runs
+):
+    """The CPU plan (hist expansion) and the TPU plan (the vmeta
+    kernel, interpreted) must produce identical results (including
+    duplicates, valid-count masking, and mixed payload widths)."""
     rng = np.random.default_rng(21)
     lk = rng.integers(0, 300, 900).astype(np.int64)
     rk = rng.integers(0, 300, 700).astype(np.int64)
@@ -324,10 +327,11 @@ def test_inner_join_carry_equals_indirect():
     right = T.from_arrays(
         rk, rng.integers(0, 7, 700).astype(np.int16)
     ).with_count(jnp.int32(650))
-    a, ta = inner_join(left, right, [0], [0], out_capacity=4096,
-                       carry_payloads=False)
-    b, tb = inner_join(left, right, [0], [0], out_capacity=4096,
-                       carry_payloads=True)
+    a, ta = inner_join(left, right, [0], [0], out_capacity=4096)
+    tiny_pallas_geometry("pallas-vmeta-interpret")
+    b, tb = inner_join(left, right, [0], [0], out_capacity=4096)
+    jax.effects_barrier()
+    assert expand_fallback_runs == []  # the kernel ran, not its fallback
     assert int(ta) == int(tb)
     n = int(ta)
     for i in range(4):

@@ -97,7 +97,7 @@ def test_shuffle_on_string_payload():
 @pytest.mark.parametrize(
     "odf,intra,expand",
     [(1, None, None), (2, None, None), (1, 4, None),
-     (2, None, "pallas-join-interpret")],
+     (2, None, "pallas-vmeta-interpret")],
 )
 def test_distributed_join_string_payload(
     odf, intra, expand, tiny_pallas_geometry
