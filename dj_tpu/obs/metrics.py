@@ -95,6 +95,13 @@ def string_chars_planned(site: str, char_bytes: int) -> None:
     inc("dj_string_char_bytes_planned_total", char_bytes, site=site)
 
 
+def join_key_from_expansion() -> None:
+    """Trace-time counter ``dj_join_key_from_expansion_total``: one per
+    traced local join whose output key came out of the expansion
+    kernel instead of a gather of the left table."""
+    inc("dj_join_key_from_expansion_total")
+
+
 def inc_items(items) -> None:
     """Batched counter update under ONE lock acquisition: ``items`` is
     an iterable of (name, labels dict, value). The hot-path entry for

@@ -54,7 +54,7 @@ from ..core.dtypes import UINT_BY_SIZE
 from ..core.search import count_leq_arange
 from ..core.table import Column, StringColumn, Table
 from ..obs import recorder as obs
-from ..obs.metrics import string_chars_planned
+from ..obs.metrics import join_key_from_expansion, string_chars_planned
 from ..utils.timing import annotate
 from . import hashing
 
@@ -371,6 +371,33 @@ def _from_unsigned_order(u: jax.Array, dtype) -> jax.Array:
     return jax.lax.bitcast_convert_type(bits, d)
 
 
+def _unsigned_zero(physical) -> jax.Array:
+    """The unsigned-order image of 0 for a physical key dtype: the
+    padding that decodes invalid output slots to 0 (a raw-0 image would
+    decode to the dtype minimum)."""
+    d = jnp.dtype(physical)
+    if jnp.issubdtype(d, jnp.signedinteger):
+        return jnp.uint64(1) << jnp.uint64(8 * d.itemsize - 1)
+    return jnp.uint64(0)
+
+
+def _key_planes(sp: jax.Array, tag_bits: int, n: int) -> tuple:
+    """The sorted words' relative keys, ``sp >> tag_bits``, as ``n``
+    int32 planes: the low 32 bits alone (n = 1, for a relative key of
+    at most 32 bits) or the low and high halves (n = 2). Padding words
+    give garbage that no valid output slot reads."""
+    rel = sp >> jnp.uint64(tag_bits)
+    lo = jax.lax.bitcast_convert_type(
+        (rel & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32), jnp.int32
+    )
+    if n == 1:
+        return (lo,)
+    hi = jax.lax.bitcast_convert_type(
+        (rel >> jnp.uint64(32)).astype(jnp.uint32), jnp.int32
+    )
+    return (lo, hi)
+
+
 def _bucket_ids(p: jax.Array, kbits: int, word_bits: int) -> jax.Array:
     """Range-bucket id per word: the top ``kbits`` of the word's
     OCCUPIED width (valid packed words are < 2^word_bits — bucketing on
@@ -516,6 +543,7 @@ def _pack_sort_core(
     carry_ops: tuple = (),
     kmin=None,
     rel_bits: Optional[int] = None,
+    key_planes: int = 0,
 ):
     """Sort ``(rel << tag_bits) | refs-first-tag`` and derive the match
     machinery's inputs — the packed branch shared by the single-key and
@@ -530,7 +558,10 @@ def _pack_sort_core(
     `_packed_merged_sort`: (boundary, stag) bare, the int32 scan
     quadruple under ``scans_impl``, extended by (key_su64, sorted_ops)
     under ``carry_ops`` (vcarry; ``kmin`` recovers the absolute
-    unsigned-order key from the sorted word).
+    unsigned-order key from the sorted word). ``key_planes`` (1 or 2,
+    with ``scans_impl``) extends the quadruple by the sorted relative
+    key as int32 planes (_key_planes) and the uint64 base that turns
+    it back into the unsigned-order key (``kmin``, else 0).
     """
     S = L + R
     mask = jnp.uint64((1 << tag_bits) - 1)
@@ -595,7 +626,12 @@ def _pack_sort_core(
     )
     sp = _sort_packed(p, word_bits)
     if scans_impl is not None:
-        return _scans_from_sp(sp)
+        if not key_planes:
+            return _scans_from_sp(sp)
+        with annotate("dj_scan"):
+            planes = _key_planes(sp, tag_bits, key_planes)
+        kbase = kmin if kmin is not None else jnp.uint64(0)
+        return _scans_from_sp(sp) + (planes, kbase)
     with annotate("dj_scan"):
         boundary = _run_starts(sp >> tag_bits)
         return boundary, _decode(sp)
@@ -607,6 +643,7 @@ def _packed_merged_sort(
     carry_ops: tuple = (),
     static_fit: Optional[bool] = None,
     rel_bits: Optional[int] = None,
+    key_planes: int = 0,
 ):
     """Merged sort as ONE uint64 operand: (key - min) << tag_bits | tag.
 
@@ -653,6 +690,11 @@ def _packed_merged_sort(
     sorts (packed, *ops) variadically — packed words are distinct, so
     no stability is needed; the fallback sorts (vals, tag, *ops)
     stably.
+
+    ``key_planes`` (1 or 2; requires scans_impl and a statically packed
+    sort) extends the quadruple by (planes, kbase) as _pack_sort_core
+    gives them: the sorted key is kbase + the planes' relative key, in
+    unsigned-order image.
     """
     S = L + R
     tag_bits = max(1, int(S).bit_length())  # 2^tag_bits - 1 >= S
@@ -671,11 +713,16 @@ def _packed_merged_sort(
         return _pack_sort_core(
             rel, valid, L, R, l_count, r_count, tag_bits,
             scans_impl=scans_impl, carry_ops=carry_ops, kmin=kmin,
-            rel_bits=rb,
+            rel_bits=rb, key_planes=key_planes,
         )
 
     assert not carry_ops or scans_impl is not None
     key_bits = 8 * vals.dtype.itemsize
+    assert not key_planes or (
+        scans_impl is not None
+        and not carry_ops
+        and (key_bits + tag_bits <= 64 or static_fit is True)
+    )
     if key_bits + tag_bits <= 64:
         # No minimum subtraction on this path, so the declared width
         # does NOT bound rel — the physical key width does.
@@ -1447,6 +1494,24 @@ def inner_join(
     # family flag for everything the two share.
     vfull = expand_impl.startswith("pallas-vfull")
     vcarry = expand_impl.startswith("pallas-vcarry") or vfull
+    # A left side that outputs nothing but its join key: the query row
+    # at src IS output slot j's left row, so the vmeta kernel expands
+    # the sorted relative key in place of stag and the output key needs
+    # no left-table gather. One int32 plane for a relative key of at
+    # most 32 bits, two above; the width is known only when the pack is
+    # static (the dynamic fit cond keeps the gather).
+    key_planes = 0
+    if (
+        single
+        and scan_fused
+        and expand_impl.startswith("pallas-vmeta")
+        and [i for i in range(left.num_columns) if i not in l_drop]
+        == [left_on[0]]
+    ):
+        if 8 * vals.dtype.itemsize + max(1, int(S).bit_length()) <= 64:
+            key_planes = 1  # a key of at most 32 bits, packed whole
+        elif static_fit is True:
+            key_planes = 1 if sk_rel_bits <= 32 else 2
     pack_ovf = jnp.bool_(False)
     mk_packed = mk_packed_avail and use_pack
     if not single and mk_packed:
@@ -1486,10 +1551,14 @@ def inner_join(
             static_fit=static_fit, rel_bits=sk_rel_bits,
         )
     elif scan_fused:
-        stag, run_start, cnt, csum = _packed_merged_sort(
+        sorted_out = _packed_merged_sort(
             vals, L, R, l_count, r_count, scans_impl=scans_impl,
             static_fit=static_fit, rel_bits=sk_rel_bits,
+            key_planes=key_planes,
         )
+        stag, run_start, cnt, csum = sorted_out[:4]
+        if key_planes:
+            rel_planes, kbase = sorted_out[4:]
     elif use_pack:
         boundary, stag = _packed_merged_sort(
             vals, L, R, l_count, r_count, static_fit=static_fit,
@@ -1526,6 +1595,12 @@ def inner_join(
                 ukmin = jnp.min(jnp.where(uvalid, ukey_c, ones64))
                 ukmax = jnp.max(jnp.where(uvalid, ukey_c, jnp.uint64(0)))
                 fits_dyn = (ukmax - ukmin) < jnp.uint64((1 << (64 - tb)) - 1)
+                if key_planes == 1:
+                    # The output key rides one 32-bit plane: a wider
+                    # span than declared would lose its high bits.
+                    fits_dyn = fits_dyn & (
+                        (ukmax - ukmin) < jnp.uint64(1 << 32)
+                    )
                 pack_ovf = (~fits_dyn) & (l_count > 0) & (r_count > 0)
 
     # --- match ranges from scans (all in merged order, no scatters) ---
@@ -1608,9 +1683,11 @@ def inner_join(
         elif vmeta:
             from .pallas_expand import expand_values
 
-            stag_j, rpos_direct = expand_values(
-                csum, cnt, stag, run_start, out_capacity, interpret=interp
+            vals_j, rpos_direct = expand_values(
+                csum, cnt, rel_planes if key_planes else (stag,),
+                run_start, out_capacity, interpret=interp,
             )
+            stag_j = None if key_planes else vals_j[0]
         else:
             src = jnp.clip(count_leq_arange(csum, out_capacity), 0, S - 1)
             # Which match within the run: output slots of one query are
@@ -1628,7 +1705,7 @@ def inner_join(
             )
             stag_j, rstart_j = m32[:, 0], m32[:, 1]
     with annotate("dj_gather"):
-        li = None if vcarry else jnp.where(valid_out, stag_j, L)
+        li = None if vcarry or key_planes else jnp.where(valid_out, stag_j, L)
         if vfull:
             rpos = None  # vfull resolved the right side in-kernel
         elif vmeta or vcarry:
@@ -1649,14 +1726,8 @@ def inner_join(
                 rrows = rstack.at[rpos].get(mode="fill", fill_value=0)
             kcol = left.columns[left_on[0]]
             # Pad with the unsigned-order image of 0 so invalid slots decode
-            # to 0 like every other mode (a raw-0 image would decode to the
-            # dtype minimum — an inconsistent padding convention).
-            kphys = jnp.dtype(kcol.dtype.physical)
-            kzero = (
-                jnp.uint64(1) << jnp.uint64(8 * kphys.itemsize - 1)
-                if jnp.issubdtype(kphys, jnp.signedinteger)
-                else jnp.uint64(0)
-            )
+            # to 0 like every other mode.
+            kzero = _unsigned_zero(kcol.dtype.physical)
             if vfull:
                 key_raw = _u64_from_planes(key_j_planes[0], key_j_planes[1])
             else:
@@ -1714,7 +1785,25 @@ def inner_join(
         # structurally invalid in XLA; the join result is necessarily
         # all-fill (total == 0), so emit zeros directly (cudf accepts
         # empty tables: upstream src/distributed_join.cpp:76-82).
-        if l_fixed:
+        if key_planes:
+            # The output key is the kernel's expanded relative key plus
+            # the pack base; invalid slots decode to 0.
+            kcol = left.columns[left_on[0]]
+            if key_planes == 1:
+                rel_j = jax.lax.bitcast_convert_type(
+                    vals_j[0], jnp.uint32
+                ).astype(jnp.uint64)
+            else:
+                rel_j = _u64_from_planes(vals_j[0], vals_j[1])
+            key_bits = jnp.where(
+                valid_out, rel_j + kbase, _unsigned_zero(kcol.dtype.physical)
+            )
+            left_out[left_on[0]] = Column(
+                _from_unsigned_order(key_bits, kcol.dtype.physical),
+                kcol.dtype,
+            )
+            join_key_from_expansion()
+        elif l_fixed:
             if L == 0:
                 lrows = jnp.zeros((out_capacity, len(l_fixed)), jnp.uint64)
             else:
@@ -2138,8 +2227,9 @@ def inner_join_prepared(
         if expand_impl.startswith("pallas-vmeta"):
             from .pallas_expand import expand_values
 
-            stag_j, rpos_direct = expand_values(
-                csum, cnt, stag, run_start, out_capacity, interpret=interp
+            (stag_j,), rpos_direct = expand_values(
+                csum, cnt, (stag,), run_start, out_capacity,
+                interpret=interp,
             )
             rpos = jnp.where(valid_out, rpos_direct, S)
         else:
@@ -2372,14 +2462,14 @@ def inner_join_probe(
         elif probe_expand.startswith("pallas"):
             from .pallas_expand import expand_values
 
-            # The fused offsets kernel: with stag = row ids and
-            # run_start = 0, expand_values' (stag_j, rpos) outputs ARE
-            # (src, t) — src and the segment offset in one merge-path
-            # pass, falling back to the exact XLA formulation under its
-            # own lax.cond on window overflow.
-            src, t = expand_values(
+            # The fused offsets kernel: with the row ids as its one
+            # plane and run_start = 0, expand_values' ((ids_j,), rpos)
+            # outputs ARE (src, t) — src and the segment offset in one
+            # merge-path pass, falling back to the exact XLA formulation
+            # under its own lax.cond on window overflow.
+            (src,), t = expand_values(
                 csum, cnt,
-                jnp.arange(L, dtype=jnp.int32),
+                (jnp.arange(L, dtype=jnp.int32),),
                 jnp.zeros((L,), jnp.int32),
                 out_capacity,
                 interpret=probe_expand.endswith("-interpret"),

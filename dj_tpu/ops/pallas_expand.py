@@ -15,9 +15,9 @@ with the exact XLA formulation as its other branch:
 - ``expand_ranks``: the ranks alone (drop-in for count_leq_arange on
   sorted csum); the probe tier's expansion on TPU.
 - ``expand_values`` (DJ_JOIN_EXPAND=pallas-vmeta, the TPU default):
-  ranks, the within-run offset and the (stag, run_start) values at
-  src in one delta-dot pass, no output-sized gathers
-  (_make_vexpand_kernel).
+  ranks, the within-run offset and the caller's value planes (stag, or
+  the sorted key for a key-only left side) at src in one delta-dot
+  pass, no output-sized gathers (_make_vexpand_kernel).
 - ``expand_carry`` (pallas-vcarry): the same kernel expanding the
   payload planes that ride the sort.
 - ``expand_vfull`` (pallas-vfull): vcarry plus the right side resolved
@@ -391,7 +391,8 @@ def _make_vexpand_kernel(
     ``valp[w] = run_start[w] - csum_ex[w]`` so that
     rpos[j] = run_start[src] + (j - csum_ex[src]) = j + valp[src] —
     the kernel's first output; arrays 1.. are generic values (vmeta:
-    stag; vcarry: carried payload planes) emitted as further outputs.
+    stag or the sorted key planes; vcarry: carried payload planes)
+    emitted as further outputs.
 
     Mosaic constraints inherited from _make_ranks_kernel: blk-aligned
     window DMAs and scalar reads (csum_ex is a separate HBM input
@@ -617,10 +618,27 @@ def _run_vexpand(
     return tuple(o[:n_out] for o in outs)
 
 
+def _vexpand_geometry(n_planes, t_j, span, blk, lane) -> tuple:
+    """(t_j, span, blk, lane) of a vexpand call expanding ``n_planes``
+    value planes; arguments left None take the defaults. VMEM scales
+    with the windows (csum, csum_ex, valp and the planes, span + blk
+    int32 each) and the output blocks: at the SPAN2 geometry one plane
+    fits, two need 16.29 MB of the 16 MB scoped budget (v5e AOT,
+    tests/test_chip_compile.py), so more planes take the T_J/SPAN
+    geometry, which fits but falls back on windows half as wide."""
+    wide = n_planes > 1
+    return (
+        (T_J if wide else T_J2) if t_j is None else t_j,
+        (SPAN if wide else SPAN2) if span is None else span,
+        BLK if blk is None else blk,
+        LANE if lane is None else lane,
+    )
+
+
 def expand_values(
     csum: jax.Array,
     cnt: jax.Array,
-    stag: jax.Array,
+    vals: tuple,
     run_start: jax.Array,
     n_out: int,
     t_j: int | None = None,
@@ -628,23 +646,21 @@ def expand_values(
     blk: int | None = None,
     lane: int | None = None,
     interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Fused (stag_j, rpos): stag_j = stag[src'], rpos = run_start[src']
-    + (j - csum_ex[src']) for src[j] = #{i : csum[i] <= j}, src' =
-    clip(src, 0, S-1) — the whole indirect-mode expansion except the
-    right-tag resolution, with NO output-sized gathers (see
-    _make_vexpand_kernel). csum must be the int32-clamped inclusive
-    match-count cumsum and ``cnt`` its per-position increments
-    (csum_ex = csum - cnt). Falls back to the exact XLA formulation
-    under `lax.cond` when a window overflows the span. Tail slots
-    (j >= csum[-1]) are UNSPECIFIED; callers must mask them.
+) -> tuple[tuple, jax.Array]:
+    """Fused (vals_j, rpos): vals_j = tuple(v[src'] for v in vals),
+    rpos = run_start[src'] + (j - csum_ex[src']) for src[j] = #{i :
+    csum[i] <= j}, src' = clip(src, 0, S-1) — the whole indirect-mode
+    expansion except the right-tag resolution, with NO output-sized
+    gathers (see _make_vexpand_kernel). ``vals`` holds one or two int32
+    planes over the merged order: stag, or the sorted relative key
+    when the left side carries nothing but its key. csum must be the
+    int32-clamped inclusive match-count cumsum and ``cnt`` its
+    per-position increments (csum_ex = csum - cnt). Falls back to the
+    exact XLA formulation under `lax.cond` when a window overflows the
+    span. Tail slots (j >= csum[-1]) are UNSPECIFIED; callers must mask
+    them.
     """
-    geo = (
-        T_J2 if t_j is None else t_j,
-        SPAN2 if span is None else span,
-        BLK if blk is None else blk,
-        LANE if lane is None else lane,
-    )
+    geo = _vexpand_geometry(len(vals), t_j, span, blk, lane)
     # Read OUTSIDE the jit and pass as a static argument: an env read
     # at trace time inside the cached function would be silently
     # ignored on a mid-process flip (jit caches key on static args,
@@ -652,7 +668,8 @@ def expand_values(
     # wrong thing.
     precision = os.environ.get("DJ_VMETA_PRECISION", DEFAULT_PRECISION)
     return _expand_values_jit(
-        csum, cnt, stag, run_start, n_out, *geo, precision, interpret
+        csum, cnt, tuple(vals), run_start, n_out, *geo, precision,
+        interpret,
     )
 
 
@@ -663,17 +680,19 @@ def expand_values(
     ),
 )
 def _expand_values_jit(
-    csum, cnt, stag, run_start, n_out, t_j, span, blk, lane, precision,
+    csum, cnt, vals, run_start, n_out, t_j, span, blk, lane, precision,
     interpret,
 ):
     from ..core.search import count_leq_arange
 
     S = csum.shape[0]
-    assert stag.shape == (S,) and stag.dtype == jnp.int32
+    assert 1 <= len(vals) <= 2, len(vals)
+    for v in vals:
+        assert v.shape == (S,) and v.dtype == jnp.int32, (v.shape, v.dtype)
     assert run_start.shape == (S,) and run_start.dtype == jnp.int32
     empty = jnp.zeros((0,), jnp.int32)
     if n_out == 0:
-        return empty, empty
+        return (empty,) * len(vals), empty
     assert n_out < 2**31 - 1, "int32 rank/value domain"
     assert span % blk == 0 and t_j % lane == 0
     csum32 = _csum32(csum)
@@ -682,20 +701,20 @@ def _expand_values_jit(
     fits = jnp.max(spans) < span
 
     def pallas_path(_):
-        rpos, stag_j = _run_vexpand(
-            csum32, csum_ex, run_start, (stag,), n_out, n_pad, starts,
+        rpos, *vals_j = _run_vexpand(
+            csum32, csum_ex, run_start, vals, n_out, n_pad, starts,
             t_j, span, blk, lane, precision, interpret,
         )
-        return stag_j, rpos
+        return tuple(vals_j), rpos
 
     @_fallback
     def xla_path(_):
         src = jnp.clip(count_leq_arange(csum32, n_out), 0, S - 1)
-        stag_j = stag.at[src].get(mode="fill", fill_value=0)
+        vals_j = tuple(v.at[src].get(mode="fill", fill_value=0) for v in vals)
         rstart_j = run_start.at[src].get(mode="fill", fill_value=0)
         csx_j = csum_ex.at[src].get(mode="fill", fill_value=0)
         j32 = jnp.arange(n_out, dtype=jnp.int32)
-        return stag_j, rstart_j + (j32 - csx_j)
+        return vals_j, rstart_j + (j32 - csx_j)
 
     return jax.lax.cond(fits, pallas_path, xla_path, None)
 
@@ -721,18 +740,7 @@ def expand_carry(
     right-tag, and right-payload output gathers all disappear. Same
     int32/window/tail contracts as expand_values.
     """
-    # VMEM scales with the window count (2 + 1 + len(pay_planes)
-    # buffers of span+blk int32): the SPAN2 geometry exhausts VMEM
-    # beyond one u64 payload (3 planes), so wider carries halve the
-    # span — more fits-fallbacks on sparse windows, but they COMPILE
-    # (v5e AOT evidence, probe_scan_lower.py vcarry_pay* cases).
-    wide = len(pay_planes) > 3
-    geo = (
-        (T_J if wide else T_J2) if t_j is None else t_j,
-        (SPAN if wide else SPAN2) if span is None else span,
-        BLK if blk is None else blk,
-        LANE if lane is None else lane,
-    )
+    geo = _vexpand_geometry(len(pay_planes), t_j, span, blk, lane)
     precision = os.environ.get("DJ_VMETA_PRECISION", DEFAULT_PRECISION)
     return _expand_carry_jit(
         csum, cnt, run_start, tuple(pay_planes), n_out, *geo, precision,

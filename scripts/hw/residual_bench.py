@@ -346,7 +346,7 @@ def expand_values_S():
     run_start = jnp.arange(S, dtype=jnp.int32)
     _bench(
         "expand_values_S",
-        lambda c, n, s, r: expand_values(c, n, s, r, OUT),
+        lambda c, n, s, r: expand_values(c, n, (s,), r, OUT),
         csum, cnt, stag, run_start,
     )
 

@@ -77,9 +77,20 @@ def _case_expand_values(sh):
     from dj_tpu.ops.pallas_expand import expand_values
 
     def f(csum, cnt, stag, run_start):
-        return expand_values(csum, cnt, stag, run_start, OUT_CAP)
+        return expand_values(csum, cnt, (stag,), run_start, OUT_CAP)
 
     return f, tuple(_sds(sh, S, jnp.int32) for _ in range(4))
+
+
+def _case_expand_key_planes(sh):
+    # A key-only left side with a relative key over 32 bits: the sorted
+    # key's two int32 planes in place of stag (five windows in VMEM).
+    from dj_tpu.ops.pallas_expand import expand_values
+
+    def f(csum, cnt, lo, hi, run_start):
+        return expand_values(csum, cnt, (lo, hi), run_start, OUT_CAP)
+
+    return f, tuple(_sds(sh, S, jnp.int32) for _ in range(5))
 
 
 def _case_probe_expand_values(sh):
@@ -88,7 +99,7 @@ def _case_probe_expand_values(sh):
 
     def f(csum, cnt):
         return expand_values(
-            csum, cnt, jnp.arange(Q_ROWS, dtype=jnp.int32),
+            csum, cnt, (jnp.arange(Q_ROWS, dtype=jnp.int32),),
             jnp.zeros((Q_ROWS,), jnp.int32), Q_OUT_CAP,
         )
 
@@ -116,6 +127,7 @@ def _case_merge_sorted_u64(sh):
 @pytest.mark.parametrize("case", [
     _case_join_scans,
     _case_expand_values,
+    _case_expand_key_planes,
     _case_probe_expand_values,
     _case_probe_expand_ranks,
     _case_merge_sorted_u64,

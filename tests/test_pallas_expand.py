@@ -158,10 +158,10 @@ def test_expand_values_vs_oracle(seed):
     run_start = rng.integers(0, S, S).astype(np.int32)
     n_out = 1024
     want_stag, want_rpos, total = _values_oracle(cnt, stag, run_start, n_out)
-    got_stag, got_rpos = expand_values(
+    (got_stag,), got_rpos = expand_values(
         jnp.asarray(np.cumsum(cnt).astype(np.int64)),
         jnp.asarray(cnt),
-        jnp.asarray(stag),
+        (jnp.asarray(stag),),
         jnp.asarray(run_start),
         n_out,
         **VGEO,
@@ -184,10 +184,10 @@ def test_expand_values_dense_runs():
     run_start = rng.integers(0, S, S).astype(np.int32)
     n_out = 1536
     want_stag, want_rpos, total = _values_oracle(cnt, stag, run_start, n_out)
-    got_stag, got_rpos = expand_values(
+    (got_stag,), got_rpos = expand_values(
         jnp.asarray(np.cumsum(cnt).astype(np.int64)),
         jnp.asarray(cnt),
-        jnp.asarray(stag),
+        (jnp.asarray(stag),),
         jnp.asarray(run_start),
         n_out,
         **VGEO,
@@ -208,10 +208,10 @@ def test_expand_values_fallback_on_wide_window():
     run_start = np.arange(S, dtype=np.int32)[::-1].copy()
     n_out = 512
     want_stag, want_rpos, total = _values_oracle(cnt, stag, run_start, n_out)
-    got_stag, got_rpos = expand_values(
+    (got_stag,), got_rpos = expand_values(
         jnp.asarray(np.cumsum(cnt).astype(np.int64)),
         jnp.asarray(cnt),
-        jnp.asarray(stag),
+        (jnp.asarray(stag),),
         jnp.asarray(run_start),
         n_out,
         **VGEO,
@@ -310,10 +310,11 @@ def test_value_expansion_oracle(case, expand_fallback_runs):
         run_start = (np.arange(S) - rng.integers(0, 8, S)).clip(0)
         run_start = run_start.astype(np.int32)
         want_stag, want_rpos, _ = _values_oracle(cnt, stag, run_start, n_out)
-        got = expand_values(
-            jnp.asarray(np.cumsum(cnt)), jnp.asarray(cnt), jnp.asarray(stag),
-            jnp.asarray(run_start), n_out, **VGEO,
+        (got_stag,), got_rpos = expand_values(
+            jnp.asarray(np.cumsum(cnt)), jnp.asarray(cnt),
+            (jnp.asarray(stag),), jnp.asarray(run_start), n_out, **VGEO,
         )
+        got = (got_stag, got_rpos)
         want = (want_stag, want_rpos)
     else:
         got, want = _vfull_run(cnt, run_start, n_out, **kw)
@@ -375,8 +376,10 @@ def _run_entry(entry, cnt, n_out):
         # order: run_start = i - cnt keeps every run inside the margin.
         return _vfull_run(cnt, np.maximum(np.arange(S) - cnt, 0), n_out)
     if entry == "values":
-        got = expand_values(jc, jnp.asarray(cnt), ja, jb, n_out, **VGEO)
-        return got, (a[srcc], rpos)
+        (got_a,), got_rpos = expand_values(
+            jc, jnp.asarray(cnt), (ja,), jb, n_out, **VGEO
+        )
+        return (got_a, got_rpos), (a[srcc], rpos)
     got = expand_carry(jc, jnp.asarray(cnt), jb, (ja,), n_out, **VGEO)
     return got, (rpos, a[srcc])
 
@@ -452,3 +455,140 @@ def test_inner_join_padded_tables_take_kernel(
     )
     assert n == l_count and got == want
     assert expand_fallback_runs == []
+
+
+# ---------------------------------------------------------------------
+# A key-only left side takes its output key from the expansion kernel
+# ---------------------------------------------------------------------
+
+
+def _key_only_case(case):
+    """(left, right, key_range, planes): a left side of the join key
+    alone (``row_id_payload``: the key and a row id) against a right
+    side of key and payload, and the int32 planes the key rides through
+    the kernel (0: it does not; the left table is gathered)."""
+    from dj_tpu.core import table as T
+
+    rng = np.random.default_rng(17)
+    keys = rng.permutation(np.arange(300, dtype=np.int64)) * 3 + 5
+    lk = keys[rng.integers(0, 300, 900)]
+    rk = keys[rng.integers(0, 300, 700)]  # duplicate right keys
+    lc, rc, key_range, planes = None, None, (0, 1 << 20), 1
+    if case == "two_planes":  # a relative key over 32 bits
+        keys = rng.integers(-(1 << 40), 1 << 40, 300)
+        lk = keys[rng.integers(0, 300, 900)]
+        rk = keys[rng.integers(0, 300, 700)]
+        key_range, planes = (-(1 << 40), 1 << 40), 2
+    elif case == "int32_key":  # packs without a range: 32 bits, signed
+        lk, rk = (lk - 450).astype(np.int32), (rk - 450).astype(np.int32)
+        key_range = None
+    elif case == "masked":  # padding rows hold keys that would match
+        lc, rc = 850, 650
+    elif case == "many_to_many":  # a few keys, long runs on both sides
+        lk = keys[rng.integers(0, 6, 60)]
+        rk = keys[rng.integers(0, 6, 300)]
+    elif case == "zero_match":
+        rk = rk + 1
+    elif case == "empty_left":
+        lk = lk[:0]
+    left_cols = (lk,) if case != "row_id_payload" else (
+        lk, np.arange(lk.size, dtype=np.int64))
+    planes = 0 if case == "row_id_payload" else planes
+    left = T.from_arrays(*left_cols)
+    right = T.from_arrays(rk, np.arange(rk.size, dtype=np.int64) + 10_000)
+    if lc is not None:
+        left, right = left.with_count(jnp.int32(lc)), right.with_count(
+            jnp.int32(rc))
+    return left, right, key_range, planes
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from _eqns(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["one_plane", "two_planes", "int32_key", "masked", "many_to_many",
+     "zero_match", "empty_left", "row_id_payload"],
+)
+def test_key_only_left_takes_key_from_expansion(
+    case, tiny_pallas_geometry, expand_fallback_runs, monkeypatch
+):
+    """The TPU plan (fused scans, vmeta kernel, interpreted) equals the
+    CPU plan (hist) row for row. A key-only left side's output key
+    comes out of the kernel, as one int32 plane or two, and no gather
+    of the left table is traced; a left side with a row id keeps the
+    stag plane and the left-table gather."""
+    from dj_tpu.obs import metrics
+    from dj_tpu.ops.join import inner_join
+
+    left, right, key_range, planes = _key_only_case(case)
+
+    def join(l, r):
+        return inner_join(l, r, [0], [0], out_capacity=4096,
+                          key_range=key_range, return_flags=True)
+
+    a, ta, fa = join(left, right)
+    tiny_pallas_geometry("pallas-vmeta-interpret")
+    monkeypatch.setenv("DJ_JOIN_SCANS", "pallas-interpret")
+    was = metrics.enabled()
+    metrics.reset(reenable=True)
+    try:
+        b, tb, fb = join(left, right)
+        jax.effects_barrier()
+        moved = metrics.counter_value("dj_join_key_from_expansion_total")
+    finally:
+        metrics.reset(reenable=was)
+    assert expand_fallback_runs == []  # the kernel ran, not its fallback
+    assert moved == (1 if planes else 0)
+    assert int(ta) == int(tb)
+    if case == "zero_match" or case == "empty_left":
+        assert int(tb) == 0
+    else:
+        assert 0 < int(tb) <= 4096  # no capacity overflow
+    assert {k: bool(v) for k, v in fb.items()} == {
+        k: bool(v) for k, v in fa.items()} == {
+        "surrogate_collision": False, "pack_range_overflow": False}
+    for ca, cb in zip(a.columns, b.columns, strict=True):
+        assert ca.dtype == cb.dtype
+        np.testing.assert_array_equal(np.asarray(ca.data),
+                                      np.asarray(cb.data))
+    eqns = list(_eqns(jax.make_jaxpr(join)(left, right).jaxpr))
+    (vexpand,) = [e for e in eqns if e.primitive.name == "pallas_call"
+                  and e.params["name"] == "vexpand"]
+    assert len(vexpand.outvars) == 1 + max(planes, 1)  # rpos + planes
+    left_gathers = [e for e in eqns if e.primitive.name == "gather"
+                    and e.invars[0].aval.dtype == jnp.uint64
+                    and e.invars[0].aval.shape[0] == left.capacity]
+    assert bool(left_gathers) == (not planes and left.capacity > 0)
+
+
+def test_key_only_left_understated_span_flags(
+    tiny_pallas_geometry, monkeypatch
+):
+    """A declared key range of at most 32 bits sends the output key
+    through one int32 plane. Keys that span more than 32 bits despite
+    it raise ``pack_range_overflow`` (the output is then unspecified,
+    as for any understated span), where the gathered key would have
+    hidden the understatement."""
+    from dj_tpu.core import table as T
+    from dj_tpu.ops.join import inner_join
+
+    tiny_pallas_geometry("pallas-vmeta-interpret")
+    monkeypatch.setenv("DJ_JOIN_SCANS", "pallas-interpret")
+    keys = np.arange(200, dtype=np.int64) << 30  # a 38-bit span
+    left, right = T.from_arrays(keys), T.from_arrays(keys, keys)
+    flags = [
+        inner_join(left, right, [0], [0], out_capacity=256,
+                   key_range=kr, return_flags=True)[2]
+        for kr in ((0, 1 << 20), (0, 1 << 40))
+    ]
+    assert [bool(f["pack_range_overflow"]) for f in flags] == [True, False]

@@ -316,3 +316,61 @@ def test_join_char_overflow_detected():
     )
     assert not bool(out2.columns[1].char_overflow())
     assert T.to_strings(out2.columns[1], 64) == [b"x" * 100] * 64
+
+
+def test_distributed_key_only_probe_char15_build_on_kernel_plan(
+    tiny_pallas_geometry, expand_fallback_runs, monkeypatch
+):
+    """TPC-H's lineitem(l_orderkey) joins orders(o_orderkey,
+    o_orderpriority CHAR(15)) at test size on the 8-device mesh, on the
+    TPU plan interpreted: the fused scans and the vmeta kernel, which
+    gives the output key of the key-only probe side. Every row's key and
+    priority bytes equal a plain join's, a tenth of the probe rows
+    match nothing, and padding is masked."""
+    from dj_tpu.obs import metrics
+
+    priorities = [b"1-URGENT", b"2-HIGH", b"3-MEDIUM", b"4-NOT SPECIFIED",
+                  b"5-LOW"]
+    tiny_pallas_geometry("pallas-vmeta-interpret")
+    monkeypatch.setenv("DJ_JOIN_SCANS", "pallas-interpret")
+    rng = np.random.default_rng(29)
+    i = np.arange(1, 1601, dtype=np.int64)
+    okeys = ((i >> 3) << 5) | (i & 7)  # dbgen's sparse orderkeys
+    prio = rng.integers(0, 5, okeys.size)
+    lkeys = np.repeat(okeys, rng.integers(1, 8, okeys.size))
+    miss = rng.random(lkeys.size) < 0.1
+    lkeys = rng.permutation(np.where(miss, lkeys + 8, lkeys))
+    orders = T.Table((
+        T.Column(jnp.asarray(okeys), dj_tpu.dtypes.int64),
+        T.StringColumn(*(lambda c: (c.offsets, c.chars))(
+            T.from_strings([priorities[p] for p in prio])),
+            dj_tpu.dtypes.char(15)),
+    ))
+    lineitem = T.Table((T.Column(jnp.asarray(lkeys), dj_tpu.dtypes.int64),))
+    topo = dj_tpu.make_topology()
+    assert topo.world_size == 8
+    l_sh, lc = dj_tpu.shard_table(topo, lineitem)
+    o_sh, oc = dj_tpu.shard_table(topo, orders)
+    config = dj_tpu.JoinConfig(bucket_factor=2.0, join_out_factor=2.0,
+                               char_out_factor=5.0,
+                               key_range=(0, int(okeys.max()) + 8))
+    was = metrics.enabled()
+    metrics.reset(reenable=True)
+    try:
+        out, counts, info = dj_tpu.distributed_inner_join(
+            topo, l_sh, lc, o_sh, oc, [0], [0], config)
+        jax.effects_barrier()
+        moved = metrics.counter_value("dj_join_key_from_expansion_total")
+    finally:
+        metrics.reset(reenable=was)
+    assert moved >= 1 and expand_fallback_runs == []
+    for k, v in info.items():
+        assert not np.asarray(v).any(), k
+    host = dj_tpu.unshard_table(out, counts)
+    n = int(np.asarray(counts).sum())
+    by_key = dict(zip(okeys.tolist(), (priorities[p] for p in prio)))
+    want = sorted((k, by_key[k]) for k in lkeys.tolist() if k in by_key)
+    assert n == len(want) == int((~miss).sum())
+    got = sorted(zip(np.asarray(host.columns[0].data)[:n].tolist(),
+                     T.to_strings(host.columns[1], n)))
+    assert got == want
