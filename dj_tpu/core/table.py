@@ -76,7 +76,10 @@ class StringColumn:
         return jnp.diff(self.offsets)
 
     def take(
-        self, indices: jax.Array, out_char_capacity: Optional[int] = None
+        self,
+        indices: jax.Array,
+        out_char_capacity: Optional[int] = None,
+        source: Optional["StringPieces"] = None,
     ) -> "StringColumn":
         """Gather rows by index, rebuilding offsets by inclusive scan.
 
@@ -95,6 +98,12 @@ class StringColumn:
         piece; if a row of it is longer than n, the output's offsets
         claim more than its chars hold, so it reads ``char_overflow``.
         Runs under the device scope ``dj_strings``.
+
+        ``source``, this column's own ``pieces()`` built once, stands in
+        for the unpack of every source row: a column taken again and
+        again (a prepared side's resident payload) then costs a take
+        its output rows alone, and reads the source's offsets only at
+        ``indices``.
         """
         cap = (
             self.chars.shape[0]
@@ -102,23 +111,74 @@ class StringColumn:
             else out_char_capacity
         )
         with annotate("dj_strings"):
-            sizes = self.sizes().at[indices].get(mode="fill", fill_value=0)
+            if source is None:
+                sizes = self.sizes().at[indices].get(
+                    mode="fill", fill_value=0)
+            else:
+                lo = self.offsets.at[indices].get(mode="fill", fill_value=0)
+                hi = self.offsets.at[indices + 1].get(
+                    mode="fill", fill_value=0)
+                sizes = jnp.where((indices >= 0) & (indices < self.size),
+                                  hi - lo, 0)
             new_offsets = sizes_to_offsets(sizes)
-            whole = 0 < self.dtype.max_len <= PIECE_BYTES
+            whole = self._whole_rows()
             chars = _take_chars(self.chars, self.offsets, indices,
-                                new_offsets, cap, whole)
+                                new_offsets, cap, whole, source)
             if whole and self.size:
                 # A row longer than its type allows does not move whole:
                 # the last offset then claims more than the chars hold.
-                long = jnp.max(self.sizes()) > self.dtype.max_len
+                long = (jnp.max(self.sizes()) > self.dtype.max_len
+                        if source is None else jnp.any(source.long))
                 new_offsets = new_offsets.at[-1].max(
                     jnp.where(long, cap + 1, 0))
         return StringColumn(new_offsets, chars, self.dtype)
+
+    def _whole_rows(self) -> bool:
+        """Whether each row moves as one piece (``char(n)``, n <=
+        PIECE_BYTES)."""
+        return 0 < self.dtype.max_len <= PIECE_BYTES
+
+    def pieces(self) -> "StringPieces":
+        """This column's take source (``take(source=...)``): its rows'
+        pieces unpacked into the word table every take would build."""
+        whole = self._whole_rows()
+        if self.size == 0:
+            return StringPieces(
+                jnp.zeros((0, _WORDS), jnp.uint32),
+                None if whole else jnp.zeros((1,), jnp.int32),
+                jnp.zeros((1,), bool))
+        with annotate("dj_strings"):
+            src = _Pieces(self.offsets, self.chars.shape[0], whole)
+            words = _unpack(self.chars, src)
+            long = (jnp.max(self.sizes()) > self.dtype.max_len if whole
+                    else jnp.zeros_like(self.offsets[0], bool))
+        return StringPieces(words, None if whole else src.first, long[None])
 
     def char_overflow(self) -> jax.Array:
         """True if the offsets claim more bytes than chars can hold
         (the detectable truncation described in ``take``)."""
         return self.offsets[-1] > self.chars.shape[0]
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class StringPieces:
+    """A string column's rows as ``StringColumn.take`` reads them,
+    unpacked once (``StringColumn.pieces``). ``words`` ``[pieces,
+    _WORDS]`` uint32: each piece's bytes, little-endian, zero past its
+    length. ``first`` ``[rows + 1]`` int32: each row's first piece,
+    None where a row is one piece (``char(n)``, n <= PIECE_BYTES).
+    ``long`` ``[1]`` bool: some row is longer than its ``char(n)``
+    allows (the take then reads ``char_overflow``)."""
+
+    words: jax.Array
+    first: Optional[jax.Array]
+    long: jax.Array
+
+    @property
+    def nbytes(self) -> int:
+        n = self.words.size * 4 + self.long.size
+        return n + (0 if self.first is None else self.first.size * 4)
 
 
 AnyColumn = Column | StringColumn
@@ -190,13 +250,15 @@ def _take_chars(
     offsets: jax.Array,
     cap: int,
     whole_rows: bool,
+    source: Optional[StringPieces] = None,
 ) -> jax.Array:
     """Output chars ``[cap]`` of a take: output row r holds the bytes
     of source row ``indices[r]`` (``chars`` at ``src_offsets``) at
     ``offsets[r]``; its size is ``offsets[r+1] - offsets[r]``, 0 for an
     index out of range.
 
-    The source's pieces are unpacked into a word table (``_unpack``).
+    The source's pieces are unpacked into a word table (``_unpack``),
+    or read from ``source``, where that table was built before.
     A ``fori_loop`` then walks the output's pieces, K a step: one row
     gather of the table fetches them, ``_pack_pieces`` packs them and
     ``_place`` writes them at the step's first offset. Steps run in
@@ -207,8 +269,12 @@ def _take_chars(
     out = jnp.zeros_like(chars, shape=(cap,))
     if indices.shape[0] == 0 or src_offsets.shape[0] < 2 or cap == 0:
         return out
-    src = _Pieces(src_offsets, chars.shape[0], whole_rows)
-    words = _unpack(chars, src)
+    if source is None:
+        src = _Pieces(src_offsets, chars.shape[0], whole_rows)
+        words, first = _unpack(chars, src), (None if whole_rows
+                                             else src.first)
+    else:
+        words, first = source.words, source.first
     dst = _Pieces(offsets, cap, whole_rows)
 
     def step(c, out):
@@ -216,8 +282,8 @@ def _take_chars(
         if whole_rows:
             piece = jax.lax.dynamic_slice_in_dim(indices, p0, dst.K)
         else:
-            piece = src.first.at[indices[r]].get(
-                mode="fill", fill_value=src.P) + k
+            piece = first.at[indices[r]].get(
+                mode="fill", fill_value=words.shape[0]) + k
         w = words.at[piece].get(mode="fill", fill_value=0)
         return _place(out, _pack_pieces(_bytes(w), ln, at - at[0]), at[0])
 

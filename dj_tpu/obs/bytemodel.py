@@ -40,8 +40,9 @@ def buffer_bytes(shape, itemsize: int) -> int:
 
 def prepared_side_bytes(prepared) -> int:
     """Exact resident HBM footprint of one PreparedSide's device
-    arrays (sorted packed words + sorted payload tables + counts,
-    summed over every odf batch, GLOBAL across the mesh).
+    arrays (sorted packed words + sorted payload tables + counts + the
+    string payloads' piece tables, summed over every odf batch, GLOBAL
+    across the mesh).
 
     The companion of :func:`hbm_model_bytes` on the residency side:
     where the traffic model prices what a query MOVES, this prices
@@ -53,7 +54,7 @@ def prepared_side_bytes(prepared) -> int:
     import-free of the parallel layer.
     """
     total = 0
-    for words, ptab, pcnt in prepared.batches:
+    for words, ptab, pcnt, pieces in prepared.batches:
         total += buffer_bytes(words.shape, words.dtype.itemsize)
         for c in ptab.columns:
             if hasattr(c, "chars"):
@@ -62,7 +63,30 @@ def prepared_side_bytes(prepared) -> int:
             else:
                 total += buffer_bytes(c.data.shape, c.data.dtype.itemsize)
         total += buffer_bytes(pcnt.shape, pcnt.dtype.itemsize)
+        total += sum(p.nbytes for p in pieces if p is not None)
     return total
+
+
+def prepared_string_bytes(prepared, out_rows: int, char_out_factor: float,
+                          world: int = 1) -> int:
+    """Per-shard HBM traffic of one prepared query's string payload
+    output, ``out_rows`` output slots a shard: each slot reads its
+    resident piece (16 B) and its two source offsets and writes its
+    offset, and the output chars are written whole. A ``char(n)``
+    column's chars are ``out_rows x n`` bytes, the most its rows can
+    hold; an unbounded one's ``char_out_factor`` x its resident chars.
+    The resident piece tables are priced once, by
+    :func:`prepared_side_bytes`, never per query."""
+    total = 0
+    for c in prepared.batches[0][1].columns:
+        if not hasattr(c, "chars"):
+            continue
+        max_len = getattr(c.dtype, "max_len", 0)
+        chars = (out_rows * max_len if max_len > 0
+                 else int(c.chars.shape[0] // max(1, world)
+                          * char_out_factor))
+        total += out_rows * (16 + 3 * 4) + chars
+    return total * len(prepared.batches)
 
 
 def replicated_table_bytes(table) -> int:

@@ -95,6 +95,21 @@ def string_chars_planned(site: str, char_bytes: int) -> None:
     inc("dj_string_char_bytes_planned_total", char_bytes, site=site)
 
 
+def prepared_string_pieces(piece_bytes: int) -> None:
+    """Trace-time counter ``dj_prepared_string_piece_bytes_total``: the
+    bytes of the string payload piece tables (``StringColumn.pieces``)
+    one shard of a traced prepare keeps resident."""
+    inc("dj_prepared_string_piece_bytes_total", piece_bytes)
+
+
+def string_take_resident() -> None:
+    """Trace-time counter ``dj_string_take_resident_total``: one per
+    string take of a traced prepared query that read the resident
+    piece table instead of unpacking the resident rows (one per query
+    module of one batch and one string payload)."""
+    inc("dj_string_take_resident_total")
+
+
 def join_key_from_expansion() -> None:
     """Trace-time counter ``dj_join_key_from_expansion_total``: one per
     traced local join whose output key came out of the expansion

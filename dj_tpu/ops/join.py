@@ -54,7 +54,11 @@ from ..core.dtypes import UINT_BY_SIZE
 from ..core.search import count_leq_arange
 from ..core.table import Column, StringColumn, Table
 from ..obs import recorder as obs
-from ..obs.metrics import join_key_from_expansion, string_chars_planned
+from ..obs.metrics import (
+    join_key_from_expansion,
+    string_chars_planned,
+    string_take_resident,
+)
 from ..utils.timing import annotate
 from . import hashing
 
@@ -2117,6 +2121,7 @@ def inner_join_prepared(
     out_capacity: int,
     char_out_factor: float = 1.0,
     merge_impl: Optional[str] = None,
+    right_pieces: Optional[tuple] = None,
 ) -> tuple[Table, jax.Array, dict]:
     """Per-batch inner join of a fresh probe batch against a PREPARED
     build batch (prepare_packed_batch's output).
@@ -2139,7 +2144,10 @@ def inner_join_prepared(
     (prepared_effective_plan): fused Pallas scans or the XLA chain,
     vmeta or histogram expansion — and the right
     payload gathers hit the SORTED resident table directly (the
-    prepared words' tags are sorted ranks).
+    prepared words' tags are sorted ranks). ``right_pieces``, one
+    entry per payload column (``StringColumn.pieces`` of a string
+    column, built at prepare; None otherwise), is what a string
+    payload's take reads (``_gather_prepared_output``).
 
     Returns (result, total, flags) with result = all left columns +
     the prepared payload columns; flags carries
@@ -2162,7 +2170,7 @@ def inner_join_prepared(
     if merge_impl.startswith("probe"):
         return inner_join_probe(
             left, left_on, pwords, right_payload, plan, out_capacity,
-            char_out_factor,
+            char_out_factor, right_pieces,
         )
     l_count = left.count()
     r_count = right_payload.count()
@@ -2252,7 +2260,7 @@ def inner_join_prepared(
 
         out_cols = _gather_prepared_output(
             left, right_payload, li, rrow, L, R, out_capacity,
-            char_out_factor,
+            char_out_factor, right_pieces,
         )
         count = jnp.minimum(total, out_capacity).astype(jnp.int32)
     return Table(tuple(out_cols), count), total, flags
@@ -2267,14 +2275,29 @@ def _gather_prepared_output(
     R: int,
     out_capacity: int,
     char_out_factor: float,
+    right_pieces: Optional[tuple] = None,
 ) -> list:
     """Output materialization shared by the prepared merge tiers:
     gather all left columns at ``li`` (left row ids, padding = L) and
     every prepared payload column at ``rrow`` (sorted ranks in the
     resident table, padding = R); capacity-0 sides emit all-fill
     columns directly (gathers from 0-row operands are structurally
-    invalid in XLA, same as inner_join's guards)."""
+    invalid in XLA, same as inner_join's guards).
+
+    A string payload with an entry in ``right_pieces`` is taken from
+    that resident piece table, so the query never unpacks the resident
+    rows. A ``char(n)`` output holds ``out_capacity x n`` chars, all
+    its rows can need; other strings ``char_out_factor`` x their
+    source's chars."""
     from ..core.table import gather_rows
+
+    def char_cap(c):
+        if c.dtype.max_len > 0:
+            cap = out_capacity * c.dtype.max_len
+        else:
+            cap = int(c.chars.shape[0] * char_out_factor)
+        string_chars_planned("gather", max(1, cap))
+        return max(1, cap)
 
     out_cols: list = []
     l_fixed = [
@@ -2290,9 +2313,7 @@ def _gather_prepared_output(
             if L == 0:
                 out_cols.append(_fill_column(c, out_capacity))
             else:
-                cap = max(1, int(c.chars.shape[0] * char_out_factor))
-                string_chars_planned("gather", cap)
-                out_cols.append(c.take(li, out_char_capacity=cap))
+                out_cols.append(c.take(li, out_char_capacity=char_cap(c)))
         elif L == 0:
             out_cols.append(_fill_column(c, out_capacity))
         else:
@@ -2311,9 +2332,11 @@ def _gather_prepared_output(
             if R == 0:
                 out_cols.append(_fill_column(c, out_capacity))
             else:
-                cap = max(1, int(c.chars.shape[0] * char_out_factor))
-                string_chars_planned("gather", cap)
-                out_cols.append(c.take(rrow, out_char_capacity=cap))
+                source = right_pieces[i] if right_pieces else None
+                if source is not None:
+                    string_take_resident()
+                out_cols.append(c.take(rrow, out_char_capacity=char_cap(c),
+                                       source=source))
         elif R == 0:
             out_cols.append(_fill_column(c, out_capacity))
         else:
@@ -2329,6 +2352,7 @@ def inner_join_probe(
     plan: PreparedPackPlan,
     out_capacity: int,
     char_out_factor: float = 1.0,
+    right_pieces: Optional[tuple] = None,
 ) -> tuple[Table, jax.Array, dict]:
     """Per-batch PROBE-tier join against a prepared build batch: zero
     sorts of ANY size in the traced module (``DJ_JOIN_MERGE=probe``).
@@ -2515,7 +2539,8 @@ def inner_join_probe(
             )
 
         out_cols = _gather_prepared_output(
-            left, right_payload, li, rrow, L, R, out_capacity, char_out_factor
+            left, right_payload, li, rrow, L, R, out_capacity,
+            char_out_factor, right_pieces,
         )
         count = jnp.minimum(total, out_capacity).astype(jnp.int32)
     return Table(tuple(out_cols), count), total, flags

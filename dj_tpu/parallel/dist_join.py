@@ -48,6 +48,7 @@ from ..obs import recorder as obs
 from ..obs import roofline as obs_roofline
 from ..obs import skew as obs_skew
 from ..obs.bytemodel import replicated_table_bytes
+from ..obs.metrics import prepared_string_pieces
 from ..resilience import errors as resil
 from ..resilience import faults
 from ..resilience import heal as heal_engine
@@ -108,7 +109,9 @@ class JoinConfig:
       rows per build row; TPC-H lineitem per order: 4) times the ratio
       of the strings' mean length to their capacity's, plus slack; a
       1.0 default holds only joins that duplicate no string bytes.
-      Truncation reports as ``char_overflow``.
+      Truncation reports as ``char_overflow``. A prepared query's
+      ``char(n)`` output needs no factor: it holds its output rows x n
+      chars, all its rows can need.
     left_compression / right_compression: per-column compression options
       applied to the inter-domain (DCN-analog) pre-shuffle only — the
       intra-domain batched all-to-alls always run uncompressed, exactly
@@ -1470,11 +1473,12 @@ class PreparedSide:
     repeated joins (prepare_join_side).
 
     ``batches`` holds, per odf batch, (sorted packed words, sorted
-    payload table leaves, valid counts) as GLOBAL row-sharded device
-    arrays — resident on the mesh, fed straight back into every query's
-    shard_map. ``key_range``/``plan`` pin the anchored pack contract
-    every probe side must satisfy; ``sizing``/``n`` pin the batch
-    geometry the words' tag field was built for. ``right``/
+    payload table leaves, valid counts, piece tables) as GLOBAL
+    row-sharded device arrays — resident on the mesh, fed straight
+    back into every query's shard_map (``_resident_batch``).
+    ``key_range``/``plan`` pin the anchored pack contract every probe
+    side must satisfy; ``sizing``/``n`` pin the batch geometry the
+    words' tag field was built for. ``right``/
     ``right_counts`` keep the source references so the auto wrapper can
     re-prepare on a plan mismatch.
 
@@ -1504,6 +1508,24 @@ class PreparedSide:
     tier: str = plan_adapt.TIER_SHUFFLE
     salt: tuple = ()
     salt_replicas: int = 1
+
+
+def _resident_batch(words: jax.Array, payload: Table) -> tuple:
+    """One resident batch of a PreparedSide, per shard: (sorted packed
+    words, payload columns, valid count [1], piece tables). Each string
+    payload column's take source (``StringColumn.pieces``) is built
+    here, once, under ``dj_prepare``, so a query's take reads it instead
+    of unpacking every resident row; the tuple holds None for a
+    fixed-width column."""
+    with annotate("dj_prepare"):
+        pieces = tuple(
+            c.pieces() if isinstance(c, StringColumn) else None
+            for c in payload.columns
+        )
+    held = sum(p.nbytes for p in pieces if p is not None)
+    if held:
+        prepared_string_pieces(held)
+    return (words, payload.with_count(None), payload.count()[None], pieces)
 
 
 def _main_group_sizing(
@@ -1624,9 +1646,7 @@ def _build_prepare_fn(
                     r_batch, right_on, plan
                 )
             range_bad = range_bad | ~okb
-            outs.append(
-                (words, payload.with_count(None), payload.count()[None])
-            )
+            outs.append(_resident_batch(words, payload))
         flags["shuffle_overflow"] = shuffle_ovf
         flags["prep_range_violation"] = range_bad
         flag_vec = jnp.stack(
@@ -1920,9 +1940,7 @@ def _build_bc_prepare_fn(
                 for k in _prep_flag_keys(config)
             ]
         )
-        return (
-            (words, payload.with_count(None), payload.count()[None]),
-        ), flag_vec[None]
+        return (_resident_batch(words, payload),), flag_vec[None]
 
     return jax.jit(run)
 
@@ -2024,9 +2042,7 @@ def _build_salted_prepare_fn(
                     r_batch, right_on, plan
                 )
             range_bad = range_bad | ~okb
-            outs.append(
-                (words, payload.with_count(None), payload.count()[None])
-            )
+            outs.append(_resident_batch(words, payload))
         flags = {
             "shuffle_overflow": shuffle_ovf,
             "prep_range_violation": range_bad,
@@ -2498,13 +2514,14 @@ def _build_prepared_query_fn(
             prefetch = _exchange_batch(b + 1) if b + 1 < odf else None
             l_batch, ovf = inflight
             shuffle_ovf = shuffle_ovf | ovf
-            words_b, ptab_b, pcnt_b = batches[b]
+            words_b, ptab_b, pcnt_b, pieces_b = batches[b]
             rt = ptab_b.with_count(pcnt_b[0])
             with annotate("dj_join"):
                 result, total, jflags = inner_join_prepared(
                     l_batch, left_on, words_b, rt, plan,
                     out_capacity=out_cap,
                     char_out_factor=config.char_out_factor,
+                    right_pieces=pieces_b,
                 )
             join_ovf = join_ovf | (total > out_cap)
             mismatch = mismatch | jflags["prepared_plan_mismatch"]
@@ -2569,13 +2586,14 @@ def _build_bc_prepared_query_fn(
     )
     def run(left_shard: Table, lc, batches):
         lt = left_shard.with_count(lc[0])
-        words_b, ptab_b, pcnt_b = batches[0]
+        words_b, ptab_b, pcnt_b, pieces_b = batches[0]
         rt = ptab_b.with_count(pcnt_b[0])
         with annotate("dj_join"):
             result, total, jflags = inner_join_prepared(
                 lt, left_on, words_b, rt, plan,
                 out_capacity=out_cap,
                 char_out_factor=config.char_out_factor,
+                right_pieces=pieces_b,
             )
         char_ovf = jnp.bool_(False)
         for col in result.columns:
@@ -2667,13 +2685,14 @@ def _build_salted_prepared_query_fn(
             prefetch = _exchange_batch(b + 1) if b + 1 < odf else None
             l_batch, ovf = inflight
             shuffle_ovf = shuffle_ovf | ovf
-            words_b, ptab_b, pcnt_b = batches[b]
+            words_b, ptab_b, pcnt_b, pieces_b = batches[b]
             rt = ptab_b.with_count(pcnt_b[0])
             with annotate("dj_join"):
                 result, total, jflags = inner_join_prepared(
                     l_batch, left_on, words_b, rt, plan,
                     out_capacity=out_cap,
                     char_out_factor=config.char_out_factor,
+                    right_pieces=pieces_b,
                 )
             join_ovf = join_ovf | (total > out_cap)
             mismatch = mismatch | jflags["prepared_plan_mismatch"]
@@ -3146,7 +3165,7 @@ def _build_coalesced_query_fn(
         inflight = _exchange_batch(0)
         for b in range(odf):
             prefetch = _exchange_batch(b + 1) if b + 1 < odf else None
-            words_b, ptab_b, pcnt_b = batches[b]
+            words_b, ptab_b, pcnt_b, pieces_b = batches[b]
             rt = ptab_b.with_count(pcnt_b[0])
             for q in range(k_queries):
                 l_batch, ovf = inflight[q]
@@ -3156,6 +3175,7 @@ def _build_coalesced_query_fn(
                         l_batch, left_on, words_b, rt, plan,
                         out_capacity=out_cap,
                         char_out_factor=config.char_out_factor,
+                        right_pieces=pieces_b,
                     )
                 join_ovf[q] = join_ovf[q] | (total > out_cap)
                 mismatch[q] = (
@@ -3223,7 +3243,7 @@ def _build_bc_coalesced_query_fn(
                    or "1") == "1",
     )
     def run(left_shards, lcs, batches):
-        words_b, ptab_b, pcnt_b = batches[0]
+        words_b, ptab_b, pcnt_b, pieces_b = batches[0]
         rt = ptab_b.with_count(pcnt_b[0])
         outs, counts, flag_vecs = [], [], []
         for q in range(k_queries):
@@ -3233,6 +3253,7 @@ def _build_bc_coalesced_query_fn(
                     lt, left_on, words_b, rt, plan,
                     out_capacity=out_cap,
                     char_out_factor=config.char_out_factor,
+                    right_pieces=pieces_b,
                 )
             char_ovf = jnp.bool_(False)
             for col in result.columns:
@@ -3895,13 +3916,11 @@ def _build_append_merge_fn(
                     a_words, right_on, plan,
                 )
             )
+            resident = _resident_batch(new_words, new_payload)
         flag_vec = jnp.stack(
             [jnp.float32(a_ovf), jnp.float32(append_ovf), jnp.float32(~ok)]
         )
-        return (
-            (new_words, new_payload.with_count(None), new_count[None]),
-            flag_vec[None],
-        )
+        return resident, flag_vec[None]
 
     return jax.jit(run)
 
@@ -4085,11 +4104,10 @@ def append_to_prepared(
             prepared.sizing.br, env,
         )
         run = _cached_build(_build_append_merge_fn, *build_args)
-        (words, ptab, pcnt), flag_mat = _run_accounted(
+        new_batches[b], flag_mat = _run_accounted(
             ("append_merge",) + build_args + (_table_sig(rows),),
-            run, rows, rows_counts, *prepared.batches[b],
+            run, rows, rows_counts, *prepared.batches[b][:3],
         )
-        new_batches[b] = (words, ptab, pcnt)
         fm = np.asarray(flag_mat)  # dj: host-sync-ok (overflow flags gate the heal loop)
         for i, k in enumerate(_APPEND_FLAG_KEYS):
             flags[k] = flags[k] | (fm[:, i] != 0)

@@ -73,6 +73,28 @@ class Forecast:
     # bench_trend's grouping key carry it so autotuned latencies never
     # trend-compare against hand-tuned medians.
     autotuned: bool = False
+    # A prepared query's string payload output (bytemodel.
+    # prepared_string_bytes), beside the fixed-width model.
+    string_bytes: int = 0
+
+
+def _prepared_string_query_bytes(topology, cfg, rows, match_rows,
+                                 prepared) -> int:
+    """Per-shard traffic of a prepared query's string payload output,
+    at the output capacity the dispatch sizes (``match_rows`` where the
+    query's sizing no longer fits the side, which then re-prepares)."""
+    from ..obs.bytemodel import prepared_string_bytes
+    from ..parallel.dist_join import (
+        PreparedPlanMismatch,
+        _prepared_query_sizing,
+    )
+
+    try:
+        out_rows = _prepared_query_sizing(topology, cfg, rows, prepared)[3]
+    except PreparedPlanMismatch:
+        out_rows = match_rows
+    return prepared_string_bytes(prepared, out_rows, cfg.char_out_factor,
+                                 topology.world_size)
 
 
 def _effective_config(config, entry: Optional[dict]):
@@ -224,6 +246,12 @@ def forecast(
         world=w,
         salt_replicas=replicas,
     )
+    string_bytes = (
+        _prepared_string_query_bytes(
+            topology, cfg, rows, int(rows * match_factor), right)
+        if prepared else 0
+    )
+    total += string_bytes
     factors = {
         f: getattr(cfg, f)
         for f in (
@@ -246,6 +274,7 @@ def forecast(
         world=int(w),
         salt_replicas=int(replicas),
         autotuned=autotuned,
+        string_bytes=int(string_bytes),
     )
 
 
@@ -463,4 +492,5 @@ def reprice(fc: Forecast, config) -> float:
             world=max(1, fc.world),
             salt_replicas=max(1, replicas),
         )
+        + fc.string_bytes
     )
